@@ -367,7 +367,11 @@ def main() -> None:
                          "this run wrote is >= ACC (the CI perf gate)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
     from . import common, tables
+
+    enable_compile_cache()
 
     if args.list:
         print("\n".join(tables.ALL))

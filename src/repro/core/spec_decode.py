@@ -667,6 +667,10 @@ class SpecDecoder:
         return min(self.row_slack(i) for i in range(len(self.tree)))
 
     # -- jitted primitives ------------------------------------------------
+    # Every step and prefill takes the weights as its first ARGUMENT,
+    # ``params = (target, draft)``, never as closure constants: at
+    # published widths constants would embed gigabytes of weights in the
+    # compiled program.
     def _fn(self, name, builder, donate=()):
         name = f"{name}@{self.kv_dtype}@{self.tp_ruleset}"
         if name not in self._jit_cache:
@@ -684,17 +688,17 @@ class SpecDecoder:
             self._jit_cache[name] = fn
         return self._jit_cache[name]
 
-    def _target_forward(self, tokens, caches, cache_pos, tables=None,
+    def _target_forward(self, params, tokens, caches, cache_pos, tables=None,
                         collect_ssm=False, positions=None, tree_info=None):
-        return forward(self.tp, self.tc, tokens, positions=positions,
+        return forward(params[0], self.tc, tokens, positions=positions,
                        caches=caches, cache_pos=cache_pos,
                        enc_out=self.enc_out, collect_ssm=collect_ssm,
                        block_tables=tables, kv_block_size=self.kv_block_size,
                        tree_info=tree_info)
 
-    def _draft_forward(self, tokens, caches, cache_pos, tables=None,
+    def _draft_forward(self, params, tokens, caches, cache_pos, tables=None,
                        collect_ssm=False):
-        return forward(self.dp, self.dc, tokens, caches=caches,
+        return forward(params[1], self.dc, tokens, caches=caches,
                        cache_pos=cache_pos, enc_out=self.draft_enc_out,
                        collect_ssm=collect_ssm, block_tables=tables,
                        kv_block_size=self.kv_block_size)
@@ -714,7 +718,7 @@ class SpecDecoder:
         logits exact); the uniform-batch path keeps the 1-wide window."""
         w = self.prefill_chunk if chunked else 1
 
-        def step(state: DecodeState) -> DecodeState:
+        def step(params, state: DecodeState) -> DecodeState:
             gen, n, done, temp = state.gen, state.n, state.done, state.temp
             next_keys, use = acceptance.split_row_keys(state.rngs)
             last = jnp.take_along_axis(gen, (n - 1)[:, None], axis=1)
@@ -732,7 +736,7 @@ class SpecDecoder:
                 next_keys = jnp.where(prefilling[:, None], state.rngs,
                                       next_keys)
             logits, tcache, _ = self._target_forward(
-                toks, state.tcache, cp, state.tables)
+                params, toks, state.tcache, cp, state.tables)
             nxt = _pick_next(logits[:, 0], temp, use)
             gen2 = jax.vmap(
                 lambda g, t, p: jax.lax.dynamic_update_slice(g, t[None], (p,))
@@ -780,25 +784,27 @@ class SpecDecoder:
         # first new token, so exactly max_new forwards produce max_new
         # tokens (unlike spec prefills, which stop at prompt[:-1] and let
         # the first verify window re-read x_{P-1})
-        def pre(toks, c, temp, keys):
+        def pre(params, toks, c, temp, keys):
             logits, c, _ = self._target_forward(
-                toks, c, jnp.zeros((toks.shape[0],), jnp.int32))
+                params, toks, c, jnp.zeros((toks.shape[0],), jnp.int32))
             return _pick_next(logits[:, -1], temp, keys), c
-        prefill = self._fn("ar_prefill", pre, donate=(1,))
-        step = self._fn("ar_step", self._build_ar_step(), donate=(0,))
+        prefill = self._fn("ar_prefill", pre, donate=(2,))
+        step = self._fn("ar_step", self._build_ar_step(), donate=(1,))
+        params = (self.tp, self.dp)
 
         next_keys, use = acceptance.split_row_keys(state.rngs)
-        first, tcache = prefill(prompt, state.tcache, state.temp, use)
+        first, tcache = prefill(params, prompt, state.tcache, state.temp, use)
         state = dataclasses.replace(
             state, gen=state.gen.at[:, p].set(first),
             n=state.n + 1, tcache=tcache, rngs=next_keys)
         for _ in range(max_new - 1):
-            state = step(state)
+            state = step(params, state)
         tokens = state.gen[:, :p + max_new]
         stats = SpecStats(max_new, max_new * b, 0, max_new, None, 0.0, 1.0)
         return tokens, stats
 
-    def _pard_depth_logits(self, gen, n, m, dcache, tables, pfinfo=None):
+    def _pard_depth_logits(self, params, gen, n, m, dcache, tables,
+                           pfinfo=None):
         """ONE PARD draft forward (Eq. 7): proposal logits for every depth
         1..K. Slot A-1 (the last real token) proposes depth 1, the K-1 mask
         slots the rest. Returns (lg [B, K, V], new draft cache).
@@ -819,7 +825,7 @@ class SpecDecoder:
             pos = jnp.where(prefilling, pf, pos)
             ssm_idx = jnp.where(prefilling, cl - 1, ssm_idx)
         logits, dcache, _ = self._draft_forward(
-            tok, dcache, pos, tables, collect_ssm=d_has_ssm)
+            params, tok, dcache, pos, tables, collect_ssm=d_has_ssm)
         if d_has_ssm:
             dcache = gather_ssm_states(dc, dcache, ssm_idx)
         a = n - m
@@ -849,9 +855,10 @@ class SpecDecoder:
         d_has_ssm = _has_ssm(dc)
         cw = self.chunk_width                           # == k + 1 (flat)
 
-        def propose_pard(gen, n, m, dcache, tables, temp, dkeys, pfinfo):
-            lg, dcache = self._pard_depth_logits(gen, n, m, dcache, tables,
-                                                 pfinfo)
+        def propose_pard(params, gen, n, m, dcache, tables, temp, dkeys,
+                         pfinfo):
+            lg, dcache = self._pard_depth_logits(params, gen, n, m, dcache,
+                                                 tables, pfinfo)
             greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
             if greedy_only:
                 return greedy, None, dcache, 1              # 1 draft forward
@@ -865,7 +872,8 @@ class SpecDecoder:
             props = jax.lax.cond(jnp.any(temp > 0), samp, lambda: greedy)
             return props, scaled, dcache, 1                 # 1 draft forward
 
-        def propose_vsd(gen, n, m, dcache, tables, temp, dkeys, pfinfo):
+        def propose_vsd(params, gen, n, m, dcache, tables, temp, dkeys,
+                        pfinfo):
             # call 1: advance committed window, propose token 1
             tok = _draft_window(gen, n, m, k, mask_id)[:, :k + 1]  # reals only
             pos = m
@@ -877,7 +885,7 @@ class SpecDecoder:
                 pos = jnp.where(prefilling, pf, pos)
                 ssm_idx = jnp.where(prefilling, cl - 1, ssm_idx)
             logits, dcache, _ = self._draft_forward(
-                tok, dcache, pos, tables, collect_ssm=d_has_ssm)
+                params, tok, dcache, pos, tables, collect_ssm=d_has_ssm)
             a = n - m
             if d_has_ssm:
                 # roll SSM state back to "after the last real token"; the AR
@@ -898,8 +906,8 @@ class SpecDecoder:
                 props.append(pj)
                 if j == k - 1:
                     break
-                lgn, dcache, _ = self._draft_forward(pj[:, None], dcache,
-                                                     cur_pos, tables)
+                lgn, dcache, _ = self._draft_forward(params, pj[:, None],
+                                                     dcache, cur_pos, tables)
                 cur_pos = cur_pos + 1
                 lg_list.append(lgn[:, 0])
             props = jnp.stack(props, axis=1)                # [B, K]
@@ -911,7 +919,7 @@ class SpecDecoder:
 
         propose = propose_pard if mode == "pard" else propose_vsd
 
-        def step(state: DecodeState):
+        def step(params, state: DecodeState):
             gen, n, m, done = state.gen, state.n, state.m, state.done
             tcache, dcache, tables = state.tcache, state.dcache, state.tables
             temp = state.temp
@@ -933,9 +941,9 @@ class SpecDecoder:
                 if not greedy_only:
                     next_keys = jnp.where(prefilling[:, None], state.rngs,
                                           next_keys)
-            props, scaled_q, dcache, n_draft = propose(gen, n, m, dcache,
-                                                       tables, temp, dkeys,
-                                                       pfinfo)
+            props, scaled_q, dcache, n_draft = propose(params, gen, n, m,
+                                                       dcache, tables, temp,
+                                                       dkeys, pfinfo)
 
             # verify window: [last committed, d_1..d_K]
             last = jnp.take_along_axis(gen, (n - 1)[:, None], axis=1)
@@ -946,7 +954,7 @@ class SpecDecoder:
                                 _chunk_window(gen, pf, cl, k + 1), vin)
                 vpos = jnp.where(prefilling, pf, vpos)
             logits, tcache_new, _ = self._target_forward(
-                vin, tcache, vpos, tables, collect_ssm=t_has_ssm)
+                params, vin, tcache, vpos, tables, collect_ssm=t_has_ssm)
 
             # acceptance (core/acceptance.py): greedy rule for temp == 0
             # rows, Leviathan sampling for temp > 0 rows — row-selected so
@@ -1078,7 +1086,7 @@ class SpecDecoder:
         bank_cmap = jnp.asarray(bank.child_map)                    # [T,S,MB]
         bank_nslots = jnp.asarray(bank.nslots)                     # [T]
 
-        def step(state: DecodeState):
+        def step(params, state: DecodeState):
             gen, n, m, done = state.gen, state.n, state.m, state.done
             tcache, dcache, tables = state.tcache, state.dcache, state.tables
             temp = state.temp
@@ -1113,8 +1121,8 @@ class SpecDecoder:
             # top-max_b per depth covers every template's ranks;
             # _topk_indices and argmax share lowest-index tie-breaking, so
             # rank 0 IS the flat path's argmax (degenerate-chain identity).
-            lg, dcache = self._pard_depth_logits(gen, n, m, dcache, tables,
-                                                 pfinfo)
+            lg, dcache = self._pard_depth_logits(params, gen, n, m, dcache,
+                                                 tables, pfinfo)
             topk = _topk_indices(lg, max_b)                        # [B,D,MB]
             di = jnp.maximum(node_depth - 1, 0)
             per_node = jnp.take_along_axis(
@@ -1163,7 +1171,7 @@ class SpecDecoder:
             tinfo = TreeAttnInfo(win_start=win_start, anc=win_anc,
                                  win_len=win_len)
             logits, tcache_new, _ = self._target_forward(
-                vin, tcache, win_start, tables, positions=positions,
+                params, vin, tcache, win_start, tables, positions=positions,
                 tree_info=tinfo)
 
             # acceptance (core/acceptance.py), row-selected greedy/sampled;
@@ -1265,11 +1273,11 @@ class SpecDecoder:
                 and idx.max() < len(self.tree), idx
             state = dataclasses.replace(state, tree_idx=jnp.asarray(idx))
 
-        prefill_t = self._fn("sp_prefill_t", lambda t, c: prefill_row(
-            self.tp, self.tc, t, None, c, enc_out=self.enc_out), donate=(1,))
-        prefill_d = self._fn("sp_prefill_d", lambda t, c: prefill_row(
-            self.dp, self.dc, t, None, c, enc_out=self.draft_enc_out),
-            donate=(1,))
+        prefill_t = self._fn("sp_prefill_t", lambda p, t, c: prefill_row(
+            p, self.tc, t, None, c, enc_out=self.enc_out), donate=(2,))
+        prefill_d = self._fn("sp_prefill_d", lambda p, t, c: prefill_row(
+            p, self.dc, t, None, c, enc_out=self.draft_enc_out),
+            donate=(2,))
         # donate the whole state: the steady state then updates gen + both
         # cache pools in place (no per-iteration multi-MB buffer copies)
         # greedy batches compile the sampled machinery out entirely (no
@@ -1279,16 +1287,17 @@ class SpecDecoder:
         if self.tree is not None:
             step = self._fn(f"tree_step_{self.tree.key}{sfx}",
                             self._build_tree_step(greedy_only=go),
-                            donate=(0,))
+                            donate=(1,))
         else:
             step = self._fn(f"spec_step_{mode}{sfx}",
                             self._build_spec_step(mode, greedy_only=go),
-                            donate=(0,))
+                            donate=(1,))
 
         state = dataclasses.replace(
-            state, tcache=prefill_t(prompt[:, :-1], state.tcache),
-            dcache=prefill_d(prompt[:, :-1], state.dcache))
+            state, tcache=prefill_t(self.tp, prompt[:, :-1], state.tcache),
+            dcache=prefill_d(self.dp, prompt[:, :-1], state.dcache))
 
+        params = (self.tp, self.dp)
         iters, draft_calls, target_calls = 0, 0, 0
         acc_hist = jnp.zeros((k,), jnp.int32)
         round_hist = None
@@ -1301,7 +1310,7 @@ class SpecDecoder:
             if t_reads_done is not None:
                 host_overhead_ms.append(
                     (time.perf_counter() - t_reads_done) * 1e3)
-            state, a, hist, rhist, _rank, n_draft = step(state)
+            state, a, hist, rhist, _rank, n_draft = step(params, state)
             iters += 1
             live_iters += live
             draft_calls += n_draft

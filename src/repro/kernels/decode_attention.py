@@ -4,38 +4,34 @@ The PARD serving hot path: a small query block (1 AR token or the K+1
 verification window) attends to a long KV cache. This is the kernel the
 paper's Table 6 bandwidth argument lives in: per iteration the draft+target
 weights stream once, and the KV cache stream dominates — so the kernel's job
-is to keep the cache read perfectly sequential and do the online softmax in
-VMEM.
+is to keep the cache read sequential and do the online softmax in VMEM.
 
-Grid: (batch, kv_head, num_kv_blocks). ALL queries for one kv head — the
-(K+1) positions x G grouped q heads — are flattened into one [Tq*G, D] tile
-that stays resident in VMEM across the whole cache sweep (Tq*G <= a few
-hundred rows), while K/V blocks stream through. Per-row validity comes from
-(kv_len, q_pos) scalars, prefetched to SMEM-like VMEM blocks.
+The cache is a pool of fixed-size blocks ``[NB, block, Hkv, D]`` plus a
+per-row block table ``[B, MBS]`` (the serving layout, serving/kv_pool.py).
+A contiguous ``[B, S, Hkv, D]`` cache is the same thing with an implicit
+table: ``as_pages`` reshapes it into ``[B * S/block, block, Hkv, D]`` (free:
+row-major contiguous) and numbers the blocks in order, so ONE pallas_call
+serves both layouts.
 
-Blocks past kv_len are skipped entirely (pl.when on the block index), so the
-swept bytes scale with the *actual* cache fill, not the allocated max_len.
+Grid: (batch, table entry). Each grid step DMAs one whole block — every KV
+head of ``block`` slots — so the K/V block ``(1, block, Hkv, D)`` spans the
+array's two minor dims, which is what the TPU tiling rule requires for a
+head dim of 64. The queries of one row are regrouped to ``[Hkv, Tq*G, D]``
+(kv head major; row ``i*G + j`` = query position ``i``, group member ``j``)
+and stay resident in VMEM across the sweep; a static loop over the kv heads
+runs the online softmax per head against its ``[block, D]`` slice.
 
-Two cache layouts share ONE kernel body:
+Per-row scalars (``kv_len``, and the block table) are scalar-prefetched into
+SMEM; per-query-row vectors (``q_pos``) arrive pre-expanded to ``[B, Tq*G, 1]``
+so their VMEM block spans the full minor dims as well. Blocks past a row's
+length are skipped (``pl.when``) and their index map repeats the row's last
+live block, so the pipeline issues no DMA for them either: swept bytes track
+the actual cache fill, not the allocated max_len.
 
-  * contiguous — k/v are [B, S, Hkv, D]; grid step ki streams block ki of
-    row b's buffer;
-  * paged — k/v are a pool of fixed-size blocks [NB, block, Hkv, D] plus a
-    per-row block table [B, MBS]. The table is scalar-prefetched
-    (PrefetchScalarGridSpec) so the BlockSpec index_map can resolve the
-    indirection *before* the DMA: grid step ki streams pool block
-    table[b, ki], which holds row b's absolute positions
-    [ki*block, (ki+1)*block). Unallocated entries point at the reserved
-    garbage block 0 and are skipped by the kv_len guard anyway.
-
-The kernel's masking logic is identical in both cases because a sequence
-block index ki maps to the same absolute position range either way.
-
-Validity is PER ROW — (kv_len, q_pos) scalars — so one launch serves the
-serving engine's fused mixed batches (DESIGN.md §8): decoding rows sweep
-their long cache while prefilling rows' chunks (q_pos = cursor + i,
-kv_len = cursor + chunk) skip every block past their short fill, keeping
-swept bytes proportional to each row's actual context.
+Validity is PER ROW, so one launch serves the serving engine's fused mixed
+batches (DESIGN.md §8): decoding rows sweep their long cache while
+prefilling rows' chunks (q_pos = cursor + i, kv_len = cursor + chunk) skip
+every block past their short fill.
 """
 from __future__ import annotations
 
@@ -49,130 +45,152 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(qpos_ref, kvlen_ref, q_ref, k_ref, v_ref, *rest, scale, window,
-            softcap, block_k, tq, g, quant=False):
+# ---------------------------------------------------------------------------
+# Shared by this module and tree_attention.py
+# ---------------------------------------------------------------------------
+
+def as_pages(x, block):
+    """View a contiguous cache leaf ``[B, S, ...]`` (S a multiple of
+    ``block``) as a block pool ``[B * S/block, block, ...]``."""
+    b, s = x.shape[:2]
+    return x.reshape((b * (s // block), block) + x.shape[2:])
+
+
+def identity_tables(b, nblocks):
+    """Block table of ``as_pages``: row ``r`` owns blocks ``r*n .. r*n+n-1``."""
+    return jnp.arange(b * nblocks, dtype=jnp.int32).reshape(b, nblocks)
+
+
+def group_queries(q, hkv):
+    """[B, Tq, Hq, D] -> [B, Hkv, Tq*G, D]: each kv head's grouped queries
+    as one row block (row i*G + j = query position i, group member j)."""
+    b, tq, hq, d = q.shape
+    g = hq // hkv
+    return q.reshape(b, tq, hkv, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, tq * g, d)
+
+
+def ungroup_output(o, tq):
+    """Inverse of ``group_queries`` for the kernel output."""
+    b, hkv, rows, d = o.shape
+    g = rows // tq
+    return o.reshape(b, hkv, tq, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, tq, hkv * g, d)
+
+
+def query_rows(x, g):
+    """Per-query vector [B, Tq] -> per-kernel-row column [B, Tq*G, 1]."""
+    return jnp.repeat(x, g, axis=1)[:, :, None]
+
+
+def last_block(ki, length, block):
+    """Table entry to DMA at grid step ``ki`` for a row of ``length`` live
+    slots: entries past the last live block repeat it (no new DMA)."""
+    return jnp.minimum(ki, jnp.maximum(length - 1, 0) // block)
+
+
+def kv_block_specs(hkv, d, block, quant, entry):
+    """K, V (and quant scale) BlockSpecs over a ``[NB, block, Hkv, D]`` pool.
+    ``entry(bi, ki, *prefetch_refs)`` gives the pool block for a grid step;
+    the scalar-prefetched block table is always the first prefetch ref."""
+    def kv_map(bi, ki, *refs):
+        return (entry(bi, ki, *refs), 0, 0, 0)
+
+    def scale_map(bi, ki, *refs):
+        return (entry(bi, ki, *refs), 0, 0)
+
+    specs = [pl.BlockSpec((1, block, hkv, d), kv_map)] * 2
     if quant:
-        # quantized KV stream (DESIGN.md §10): per-(slot, head) float32
-        # scales ride in two extra refs right after k/v
-        ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_s, l_s, acc_s = rest
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+        specs += [pl.BlockSpec((1, block, hkv), scale_map)] * 2
+    return specs
+
+
+def attention_scratch(hkv, rows, d):
+    """Running max, running sum and accumulator, per kv head."""
+    return [pltpu.VMEM((hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((hkv, rows, d), jnp.float32)]
+
+
+def init_scratch(m_s, l_s, acc_s):
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+
+
+def sweep_block(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_s, l_s, acc_s, mask,
+                *, scale, softcap):
+    """One KV block of the online softmax for every kv head. ``mask`` is the
+    [Tq*G, block] validity of this block (the same for every head)."""
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h].astype(jnp.float32)                 # [rows, d]
+        k = k_ref[0, :, h, :].astype(jnp.float32)           # [block, d]
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        if ks_ref is not None:
+            # dequant fused into the sweep: the block expands against its
+            # per-slot scales right after the DMA, still inside VMEM
+            k = k * ks_ref[0, :, h:h + 1]
+            v = v * vs_ref[0, :, h:h + 1]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if softcap:
+            s = jnp.tanh(s / softcap) * softcap
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_s[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_s[h] = l_s[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_s[h] = acc_s[h] * alpha + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_s[h] = m_new
+
+
+def write_output(o_ref, l_s, acc_s):
+    """Normalize; rows that saw no valid key (padding) come out as 0."""
+    for h in range(o_ref.shape[1]):
+        denom = jnp.where(l_s[h] == 0.0, 1.0, l_s[h])
+        o_ref[0, h] = (acc_s[h] / denom).astype(o_ref.dtype)
+
+
+def split_quant_refs(rest, quant):
+    if quant:
+        return rest[0], rest[1], rest[2:]
+    return None, None, rest
+
+
+# ---------------------------------------------------------------------------
+# Decode / flat verify
+# ---------------------------------------------------------------------------
+
+def _kernel(bt_ref, kvlen_ref, qpos_ref, q_ref, k_ref, v_ref, *rest, scale,
+            window, softcap, block_k, quant):
+    # bt_ref (the scalar-prefetched block table) is consumed only by the
+    # BlockSpec index maps
+    ks_ref, vs_ref, (o_ref, m_s, l_s, acc_s) = split_quant_refs(rest, quant)
+    bi, ki = pl.program_id(0), pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+        init_scratch(m_s, l_s, acc_s)
 
-    kv_len = kvlen_ref[0]                              # scalar for this row
+    kv_len = kvlen_ref[bi]
     k_start = ki * block_k
 
     @pl.when(k_start < kv_len)
     def _compute():
-        q = q_ref[0, :, :, :].astype(jnp.float32)      # [tq, g, d]
-        d = q.shape[-1]
-        q2 = q.reshape(tq * g, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [bk, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quant:
-            # dequant fused into the sweep: the block expands against its
-            # scales right after the DMA, still inside VMEM
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
-        s = jnp.dot(q2, k.T, preferred_element_type=jnp.float32) * scale
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
-
-        # rows are (q position i, group member): validity depends only on i
-        qp = qpos_ref[0, :]                            # [tq]
-        qp_rows = jnp.repeat(qp, g)[:, None]           # [tq*g, 1] — static
+        qp_rows = qpos_ref[0]                               # [rows, 1]
         k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (tq * g, block_k), 1)
+            jnp.int32, (qp_rows.shape[0], block_k), 1)
         mask = (k_pos < kv_len) & (k_pos <= qp_rows)
         if window:
             mask &= k_pos > qp_rows - window
-        s = jnp.where(mask, s, NEG_INF)
+        sweep_block(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_s, l_s, acc_s,
+                    mask, scale=scale, softcap=softcap)
 
-        m_prev = m_s[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_s[...] = acc_s[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_s[...] = m_new
-
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == pl.num_programs(1) - 1)
     def _final():
-        denom = jnp.where(l_s[...] == 0.0, 1.0, l_s[...])
-        o_ref[0, :, 0, :] = (acc_s[...] / denom).reshape(
-            tq, g * acc_s.shape[-1]).astype(o_ref.dtype)
-
-
-def decode_attention(q, k, v, kv_len, q_pos, *, k_scale=None, v_scale=None,
-                     window=0, softcap=0.0, scale=None, block_k=256,
-                     interpret=False):
-    """q: [B, Tq, Hq, D] (Tq small); k, v: [B, S, Hkv, D];
-    kv_len: [B] int32 valid cache entries; q_pos: [B, Tq] absolute.
-    k_scale/v_scale: optional [B, S, Hkv] float32 dequant scales for
-    quantized k/v (int8 / fp8); dequant is fused into the stream."""
-    b, tq, hq, d = q.shape
-    s_len, hkv = k.shape[1], k.shape[2]
-    g = hq // hkv
-    quant = k_scale is not None
-    if scale is None:
-        scale = 1.0 / (d ** 0.5)
-
-    # group q heads by their kv head: [B, Tq, Hkv, G, D]
-    qg = q.reshape(b, tq, hkv, g, d)
-    grid = (b, hkv, pl.cdiv(s_len, block_k))
-
-    kern = functools.partial(_kernel, scale=scale, window=window,
-                             softcap=softcap, block_k=block_k, tq=tq, g=g,
-                             quant=quant)
-
-    in_specs = [
-        pl.BlockSpec((1, tq), lambda bi, h, ki: (bi, 0)),       # q_pos
-        pl.BlockSpec((1,), lambda bi, h, ki: (bi,)),            # kv_len
-        pl.BlockSpec((1, tq, 1, g, d),
-                     lambda bi, h, ki: (bi, 0, h, 0, 0)),       # q
-        pl.BlockSpec((1, block_k, 1, d),
-                     lambda bi, h, ki: (bi, ki, h, 0)),         # k
-        pl.BlockSpec((1, block_k, 1, d),
-                     lambda bi, h, ki: (bi, ki, h, 0)),         # v
-    ]
-    args = [q_pos.astype(jnp.int32), kv_len.astype(jnp.int32), qg, k, v]
-    if quant:
-        for _ in range(2):                                      # k/v scales
-            in_specs.append(pl.BlockSpec((1, block_k, 1),
-                                         lambda bi, h, ki: (bi, ki, h)))
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
-
-    out = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tq, 1, g * d),
-                               lambda bi, h, ki: (bi, 0, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, tq, hkv, g * d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((tq * g, 1), jnp.float32),
-            pltpu.VMEM((tq * g, 1), jnp.float32),
-            pltpu.VMEM((tq * g, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
-    return out.reshape(b, tq, hq, d)
-
-
-def _paged_kernel(bt_ref, qpos_ref, kvlen_ref, q_ref, k_ref, v_ref, *rest,
-                  **kw):
-    # bt_ref (the scalar-prefetched block table) is consumed only by the
-    # BlockSpec index_maps; the compute body is the contiguous kernel's.
-    _kernel(qpos_ref, kvlen_ref, q_ref, k_ref, v_ref, *rest, **kw)
+        write_output(o_ref, l_s, acc_s)
 
 
 def decode_attention_paged(q, k_pages, v_pages, block_tables, kv_len, q_pos,
@@ -189,51 +207,58 @@ def decode_attention_paged(q, k_pages, v_pages, block_tables, kv_len, q_pos,
     """
     b, tq, hq, d = q.shape
     block, hkv = k_pages.shape[1], k_pages.shape[2]
-    mbs = block_tables.shape[1]
     g = hq // hkv
+    rows = tq * g
     quant = k_scale is not None
     if scale is None:
         scale = 1.0 / (d ** 0.5)
 
-    qg = q.reshape(b, tq, hkv, g, d)
-    kern = functools.partial(_paged_kernel, scale=scale, window=window,
-                             softcap=softcap, block_k=block, tq=tq, g=g,
-                             quant=quant)
+    def entry(bi, ki, bt, kl):
+        return bt[bi, last_block(ki, kl[bi], block)]
 
-    in_specs = [
-        pl.BlockSpec((1, tq), lambda bi, h, ki, bt: (bi, 0)),   # q_pos
-        pl.BlockSpec((1,), lambda bi, h, ki, bt: (bi,)),        # kv_len
-        pl.BlockSpec((1, tq, 1, g, d),
-                     lambda bi, h, ki, bt: (bi, 0, h, 0, 0)),   # q
-        pl.BlockSpec((1, block, 1, d),
-                     lambda bi, h, ki, bt: (bt[bi, ki], 0, h, 0)),  # k
-        pl.BlockSpec((1, block, 1, d),
-                     lambda bi, h, ki, bt: (bt[bi, ki], 0, h, 0)),  # v
-    ]
-    args = [block_tables.astype(jnp.int32), q_pos.astype(jnp.int32),
-            kv_len.astype(jnp.int32), qg, k_pages, v_pages]
+    def row_map(bi, ki, *_):
+        return (bi, 0, 0)
+
+    def head_map(bi, ki, *_):
+        return (bi, 0, 0, 0)
+
+    in_specs = [pl.BlockSpec((1, rows, 1), row_map),                # q_pos
+                pl.BlockSpec((1, hkv, rows, d), head_map)]          # q
+    in_specs += kv_block_specs(hkv, d, block, quant, entry)
+    args = [block_tables.astype(jnp.int32), kv_len.astype(jnp.int32),
+            query_rows(q_pos.astype(jnp.int32), g), group_queries(q, hkv),
+            k_pages, v_pages]
     if quant:
-        for _ in range(2):                                      # k/v scales
-            in_specs.append(pl.BlockSpec(
-                (1, block, 1), lambda bi, h, ki, bt: (bt[bi, ki], 0, h)))
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hkv, mbs),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tq, 1, g * d),
-                               lambda bi, h, ki, bt: (bi, 0, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((tq * g, 1), jnp.float32),
-            pltpu.VMEM((tq * g, 1), jnp.float32),
-            pltpu.VMEM((tq * g, d), jnp.float32),
-        ],
-    )
+    kern = functools.partial(_kernel, scale=scale, window=window,
+                             softcap=softcap, block_k=block, quant=quant)
     out = pl.pallas_call(
         kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, tq, hkv, g * d), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, block_tables.shape[1]),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, hkv, rows, d), head_map),
+            scratch_shapes=attention_scratch(hkv, rows, d)),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
         interpret=interpret,
     )(*args)
-    return out.reshape(b, tq, hq, d)
+    return ungroup_output(out, tq)
+
+
+def decode_attention(q, k, v, kv_len, q_pos, *, k_scale=None, v_scale=None,
+                     window=0, softcap=0.0, scale=None, block_k=256,
+                     interpret=False):
+    """q: [B, Tq, Hq, D] (Tq small); k, v: [B, S, Hkv, D] with S a multiple
+    of ``block_k``; kv_len: [B] int32 valid cache entries; q_pos: [B, Tq]
+    absolute. k_scale/v_scale: optional [B, S, Hkv] float32 dequant scales
+    for quantized k/v (int8 / fp8); dequant is fused into the stream."""
+    b, s = k.shape[:2]
+    quant = k_scale is not None
+    return decode_attention_paged(
+        q, as_pages(k, block_k), as_pages(v, block_k),
+        identity_tables(b, s // block_k), kv_len, q_pos,
+        k_scale=as_pages(k_scale, block_k) if quant else None,
+        v_scale=as_pages(v_scale, block_k) if quant else None,
+        window=window, softcap=softcap, scale=scale, interpret=interpret)

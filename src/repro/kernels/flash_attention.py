@@ -7,10 +7,12 @@ structure). Supports sliding-window masking and gemma2-style attention
 logit softcapping.
 
 TPU adaptation notes (vs the CUDA flash-attention the paper's frameworks
-use): block shapes are MXU/VPU aligned — q blocks of 128 rows, kv blocks of
-128-512, head_dim padded to a multiple of 128 by ops.py; masks are computed
-from block-relative iotas (no [T,T] mask tensor touches HBM); fully-masked
-(q,kv) block pairs are skipped with pl.when on block indices.
+use): q/k/v run head-major (``[B, H, T, D]``, transposed here) so every
+block ``(1, 1, rows, D)`` spans the array's two minor dims — the TPU tiling
+rule for a head dim of 64; q blocks of up to 128 rows, kv blocks of 128;
+masks are computed from block-relative iotas (no [T,T] mask tensor touches
+HBM); fully-masked (q,kv) block pairs are skipped with pl.when on block
+indices.
 """
 from __future__ import annotations
 
@@ -49,10 +51,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *, scale, causal,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)     # [bq, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)     # [bk, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        q = q_ref[0, 0].astype(jnp.float32)           # [bq, d]
+        k = k_ref[0, 0].astype(jnp.float32)           # [bk, d]
+        v = v_ref[0, 0].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
 
@@ -78,13 +81,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *, scale, causal,
     def _final():
         denom = l_s[...]
         denom = jnp.where(denom == 0.0, 1.0, denom)   # fully-masked rows -> 0
-        o_ref[0, :, 0, :] = (acc_s[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_s[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
                     scale=None, block_q=128, block_k=128, interpret=False):
     """q: [B, T, Hq, D]; k, v: [B, S, Hkv, D]. T and S must be multiples of
-    the block sizes and D should be 128-aligned (ops.py pads)."""
+    the block sizes (ops.py pads)."""
     b, t, hq, d = q.shape
     s_len = k.shape[1]
     hkv = k.shape[2]
@@ -98,23 +101,25 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
         _kernel, scale=scale, causal=causal, window=window, softcap=softcap,
         block_q=block_q, block_k=block_k, seq_len=s_len)
 
-    return pl.pallas_call(
+    def q_map(bi, h, qi, ki):
+        return (bi, h, qi, 0)
+
+    def kv_map(bi, h, qi, ki):
+        return (bi, h // g, ki, 0)
+
+    out = pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, d), lambda bi, h, qi, ki: (bi, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda bi, h, qi, ki, g=g: (bi, ki, h // g, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda bi, h, qi, ki, g=g: (bi, ki, h // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda bi, h, qi, ki: (bi, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, t, hq, d), q.dtype),
+        in_specs=[pl.BlockSpec((1, 1, block_q, d), q_map),
+                  pl.BlockSpec((1, 1, block_k, d), kv_map),
+                  pl.BlockSpec((1, 1, block_k, d), kv_map)],
+        out_specs=pl.BlockSpec((1, 1, block_q, d), q_map),
+        out_shape=jax.ShapeDtypeStruct((b, hq, t, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)))
+    return out.transpose(0, 2, 1, 3)

@@ -1,8 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handle padding to TPU-aligned block shapes, GQA head grouping, dtype
-plumbing, and interpret-mode dispatch (CPU backend -> interpret=True so the
-kernels validate on this container; on TPU they compile natively).
+plumbing, and interpret-mode dispatch: on a TPU the kernels always compile
+natively; elsewhere they run in the Pallas interpreter (so they validate on
+a CPU-only host) unless the caller asks for a native compile, as the
+compile rehearsal for a described TPU does.
 """
 from __future__ import annotations
 
@@ -20,10 +22,15 @@ from . import tree_attention as _tree
 
 
 def _interpret(flag):
-    if flag is not None:
-        return flag
-    return jax.default_backend() == "cpu"
+    if jax.default_backend() == "tpu":
+        return False
+    return True if flag is None else flag
 
+
+# The decode / tree wrappers below are NOT jitted: they read the activation
+# mesh at trace time (``_over_kv_heads``), and a jit cache keyed on shapes
+# alone would hand a trace made under one mesh to a call under another.
+# They are traced inline into the serving steps that call them.
 
 # --------------------------------------------------------------------------
 # Activation sharding hints (sharded serving, DESIGN.md §11 / §13)
@@ -173,6 +180,47 @@ def rowparallel_einsum(eq, x, w, *, x_axis, w_axis):
     return jnp.sum(partials.astype(jnp.float32), axis=0).astype(x.dtype)
 
 
+def _model_axis_size():
+    if _ACTIVATION_MESH is None:
+        return 1
+    return dict(zip(_ACTIVATION_MESH.axis_names,
+                    _ACTIVATION_MESH.devices.shape)).get("model", 1)
+
+
+def _over_kv_heads(fn, args, head_axes, hkv):
+    """Run a kernel on each device's own kv heads under the activation
+    mesh. A Pallas call cannot be partitioned by GSPMD, so inside a sharded
+    step it runs in a ``shard_map`` over "model": the arguments whose kv-head
+    axis is given in ``head_axes`` (q, pools, scales, output) split there —
+    exactly how the serving rules place the head-sharded projections and KV
+    pools — and everything else (tables, lengths, positions) replicates.
+    Attention is independent per kv head, so no collective is needed. A kv
+    head count the model axis does not divide replicates the whole call.
+    Identity without a mesh. ``None`` entries of ``args`` pass through."""
+    model = _model_axis_size()
+    if model == 1:
+        return fn(*args)
+    split = hkv % model == 0
+
+    def spec(ax):
+        if ax is None or not split:
+            return jax.sharding.PartitionSpec()
+        return jax.sharding.PartitionSpec(*([None] * ax), "model")
+
+    live = [i for i, a in enumerate(args) if a is not None]
+
+    def local(*xs):
+        full = list(args)
+        for i, x in zip(live, xs):
+            full[i] = x
+        return fn(*full)
+
+    return jax.shard_map(
+        local, mesh=_ACTIVATION_MESH,
+        in_specs=tuple(spec(head_axes[i]) for i in live),
+        out_specs=spec(2), check_vma=False)(*(args[i] for i in live))
+
+
 def _pad_axis(x, axis, mult):
     size = x.shape[axis]
     pad = (-size) % mult
@@ -204,8 +252,6 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
     return out[:, :t]
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "window", "softcap", "scale", "block_k", "interpret"))
 def decode_attention(q, k, v, kv_len, q_pos, *, k_scale=None, v_scale=None,
                      window=0, softcap=0.0, scale=None, block_k=256,
                      interpret=None):
@@ -219,14 +265,16 @@ def decode_attention(q, k, v, kv_len, q_pos, *, k_scale=None, v_scale=None,
     if k_scale is not None:
         k_scale, _ = _pad_axis(k_scale, 1, block_k)
         v_scale, _ = _pad_axis(v_scale, 1, block_k)
-    return _dec.decode_attention(q, k, v, kv_len, q_pos, k_scale=k_scale,
-                                 v_scale=v_scale, window=window,
-                                 softcap=softcap, scale=scale,
-                                 block_k=block_k, interpret=interpret)
+    kern = functools.partial(_dec.decode_attention, window=window,
+                             softcap=softcap, scale=scale, block_k=block_k,
+                             interpret=interpret)
+    return _over_kv_heads(
+        lambda q, k, v, kl, qp, ks, vs: kern(q, k, v, kl, qp, k_scale=ks,
+                                             v_scale=vs),
+        (q, k, v, kv_len, q_pos, k_scale, v_scale),
+        (2, 2, 2, None, None, 2, 2), k.shape[2])
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "window", "softcap", "scale", "interpret"))
 def decode_attention_paged(q, k_pages, v_pages, block_tables, kv_len, q_pos,
                            *, k_scale=None, v_scale=None, window=0,
                            softcap=0.0, scale=None, interpret=None):
@@ -239,15 +287,16 @@ def decode_attention_paged(q, k_pages, v_pages, block_tables, kv_len, q_pos,
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    return _dec.decode_attention_paged(q, k_pages, v_pages, block_tables,
-                                       kv_len, q_pos, k_scale=k_scale,
-                                       v_scale=v_scale, window=window,
-                                       softcap=softcap, scale=scale,
-                                       interpret=interpret)
+    kern = functools.partial(_dec.decode_attention_paged, window=window,
+                             softcap=softcap, scale=scale,
+                             interpret=interpret)
+    return _over_kv_heads(
+        lambda q, k, v, bt, kl, qp, ks, vs: kern(q, k, v, bt, kl, qp,
+                                                 k_scale=ks, v_scale=vs),
+        (q, k_pages, v_pages, block_tables, kv_len, q_pos, k_scale, v_scale),
+        (2, 2, 2, None, None, None, 2, 2), k_pages.shape[2])
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "window", "softcap", "scale", "block_k", "interpret"))
 def tree_attention(q, k, v, kv_len, q_pos, win_start, anc, *, win_len=None,
                    k_scale=None, v_scale=None, window=0, softcap=0.0,
                    scale=None, block_k=256, interpret=None):
@@ -267,15 +316,16 @@ def tree_attention(q, k, v, kv_len, q_pos, win_start, anc, *, win_len=None,
     if k_scale is not None:
         k_scale, _ = _pad_axis(k_scale, 1, block_k)
         v_scale, _ = _pad_axis(v_scale, 1, block_k)
-    return _tree.tree_attention(q, k, v, kv_len, q_pos, win_start, anc,
-                                win_len=win_len, k_scale=k_scale,
-                                v_scale=v_scale, window=window,
-                                softcap=softcap, scale=scale,
-                                block_k=block_k, interpret=interpret)
+    kern = functools.partial(_tree.tree_attention, window=window,
+                             softcap=softcap, scale=scale, block_k=block_k,
+                             interpret=interpret)
+    return _over_kv_heads(
+        lambda q, k, v, kl, qp, ws, an, wl, ks, vs: kern(
+            q, k, v, kl, qp, ws, an, win_len=wl, k_scale=ks, v_scale=vs),
+        (q, k, v, kv_len, q_pos, win_start, anc, win_len, k_scale, v_scale),
+        (2, 2, 2, None, None, None, None, None, 2, 2), k.shape[2])
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "window", "softcap", "scale", "interpret"))
 def tree_attention_paged(q, k_pages, v_pages, block_tables, kv_len, q_pos,
                          win_start, anc, *, win_len=None, k_scale=None,
                          v_scale=None, window=0, softcap=0.0, scale=None,
@@ -290,12 +340,16 @@ def tree_attention_paged(q, k_pages, v_pages, block_tables, kv_len, q_pos,
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    return _tree.tree_attention_paged(q, k_pages, v_pages, block_tables,
-                                      kv_len, q_pos, win_start, anc,
-                                      win_len=win_len, k_scale=k_scale,
-                                      v_scale=v_scale, window=window,
-                                      softcap=softcap, scale=scale,
-                                      interpret=interpret)
+    kern = functools.partial(_tree.tree_attention_paged, window=window,
+                             softcap=softcap, scale=scale,
+                             interpret=interpret)
+    return _over_kv_heads(
+        lambda q, k, v, bt, kl, qp, ws, an, wl, ks, vs: kern(
+            q, k, v, bt, kl, qp, ws, an, win_len=wl, k_scale=ks, v_scale=vs),
+        (q, k_pages, v_pages, block_tables, kv_len, q_pos, win_start, anc,
+         win_len, k_scale, v_scale),
+        (2, 2, 2, None, None, None, None, None, None, 2, 2),
+        k_pages.shape[2])
 
 
 @functools.partial(jax.jit, static_argnames=(

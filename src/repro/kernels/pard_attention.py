@@ -12,8 +12,10 @@ Allowed q(s_q, b_q) -> k(s_k, b_k):
     s_k == s_q         and b_k == b_q     (self)
 plus segment > 0 on both sides (0 = padding).
 
-Grid: (batch, head, num_q_blocks, num_kv_blocks); metadata streams as
-[block]-sized int32 tiles beside the K/V tiles.
+Grid: (batch, head, num_q_blocks, num_kv_blocks); q/k/v run head-major
+(``[B, H, T, D]``) so each block spans the two minor dims (the TPU tiling
+rule), and the metadata streams beside the K/V tiles as a ``[bq, 1]``
+column for the queries and a ``[1, bk]`` row for the keys.
 """
 from __future__ import annotations
 
@@ -38,17 +40,18 @@ def _kernel(qseg_ref, qbase_ref, kseg_ref, kbase_ref, q_ref, k_ref, v_ref,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    q = q_ref[0, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
 
-    qs = qseg_ref[0, :][:, None]
-    qb = qbase_ref[0, :][:, None]
-    ks = kseg_ref[0, :][None, :]
-    kb = kbase_ref[0, :][None, :]
+    qs = qseg_ref[0]                                   # [bq, 1]
+    qb = qbase_ref[0]
+    ks = kseg_ref[0]                                   # [1, bk]
+    kb = kbase_ref[0]
     real_ctx = (ks == 1) & (kb < qb)
     chain = (ks > 1) & (ks < qs) & (kb == qb)
     self_tok = (ks == qs) & (kb == qb)
@@ -67,7 +70,7 @@ def _kernel(qseg_ref, qbase_ref, kseg_ref, kbase_ref, q_ref, k_ref, v_ref,
     @pl.when(ki == nk - 1)
     def _final():
         denom = jnp.where(l_s[...] == 0.0, 1.0, l_s[...])
-        o_ref[0, :, 0, :] = (acc_s[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_s[...] / denom).astype(o_ref.dtype)
 
 
 def pard_attention(q, k, v, segment, base, *, scale=None, softcap=0.0,
@@ -85,25 +88,32 @@ def pard_attention(q, k, v, segment, base, *, scale=None, softcap=0.0,
     seg = segment.astype(jnp.int32)
     bas = base.astype(jnp.int32)
 
-    return pl.pallas_call(
+    def q_map(bi, hh, qi, ki):
+        return (bi, hh, qi, 0)
+
+    def kv_map(bi, hh, qi, ki):
+        return (bi, hh, ki, 0)
+
+    out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda bi, hh, qi, ki: (bi, qi)),
-            pl.BlockSpec((1, block_q), lambda bi, hh, qi, ki: (bi, qi)),
-            pl.BlockSpec((1, block_k), lambda bi, hh, qi, ki: (bi, ki)),
-            pl.BlockSpec((1, block_k), lambda bi, hh, qi, ki: (bi, ki)),
-            pl.BlockSpec((1, block_q, 1, d), lambda bi, hh, qi, ki: (bi, qi, hh, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda bi, hh, qi, ki: (bi, ki, hh, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda bi, hh, qi, ki: (bi, ki, hh, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda bi, hh, qi, ki: (bi, qi, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda bi, hh, qi, ki: (bi, qi, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda bi, hh, qi, ki: (bi, 0, ki)),
+            pl.BlockSpec((1, 1, block_k), lambda bi, hh, qi, ki: (bi, 0, ki)),
+            pl.BlockSpec((1, 1, block_q, d), q_map),
+            pl.BlockSpec((1, 1, block_k, d), kv_map),
+            pl.BlockSpec((1, 1, block_k, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda bi, hh, qi, ki: (bi, qi, hh, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, t, h, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, d), q_map),
+        out_shape=jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
-    )(seg, bas, seg, bas, q, k, v)
+    )(seg[:, :, None], bas[:, :, None], seg[:, None, :], bas[:, None, :],
+      *(x.transpose(0, 2, 1, 3) for x in (q, k, v)))
+    return out.transpose(0, 2, 1, 3)

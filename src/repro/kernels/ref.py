@@ -2,15 +2,17 @@
 
 These are the semantic source of truth: each kernel's tests sweep shapes and
 dtypes and assert allclose against these functions. They intentionally share
-the model's reference attention core (models.attention.attend — itself pure
-jnp) so the kernels are validated against exactly what the model computes.
+the model's reference attention core (models.attention.attend_reference —
+pure jnp, never a kernel, whatever the platform or the pinned backend) so
+the kernels are validated against exactly what the model computes.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-from ..models.attention import (PardMaskInfo, TreeAttnInfo, attend,
-                                dequantize_kv, gather_pages)
+from ..models.attention import (PardMaskInfo, TreeAttnInfo,
+                                attend_reference, dequantize_kv,
+                                gather_pages)
 from ..models.ssm import ssd_scan_ref
 
 
@@ -29,8 +31,8 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0, softcap=0.0,
     s = k.shape[1]
     q_pos = jnp.broadcast_to(jnp.arange(t)[None], (b, t))
     kv_pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    return attend(q, k, v, q_pos, kv_pos, s, causal=causal, window=window,
-                  attn_softcap=softcap, scale=scale)
+    return attend_reference(q, k, v, q_pos, kv_pos, s, causal=causal,
+                            window=window, attn_softcap=softcap, scale=scale)
 
 
 def decode_attention_ref(q, k, v, kv_len, q_pos, *, k_scale=None,
@@ -44,8 +46,8 @@ def decode_attention_ref(q, k, v, kv_len, q_pos, *, k_scale=None,
     b = q.shape[0]
     s = k.shape[1]
     kv_pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    return attend(q, k, v, q_pos, kv_pos, kv_len, causal=True, window=window,
-                  attn_softcap=softcap, scale=scale)
+    return attend_reference(q, k, v, q_pos, kv_pos, kv_len, causal=True,
+                            window=window, attn_softcap=softcap, scale=scale)
 
 
 def decode_attention_paged_ref(q, k_pages, v_pages, block_tables, kv_len,
@@ -95,8 +97,9 @@ def tree_attention_ref(q, k, v, kv_len, q_pos, win_start, anc, *,
         # slots are invisible on both paths
         kv_len = jnp.minimum(jnp.asarray(kv_len),
                              jnp.asarray(win_start) + jnp.asarray(win_len))
-    return attend(q, k, v, q_pos, kv_pos, kv_len, causal=True, window=window,
-                  attn_softcap=softcap, scale=scale, tree_info=info)
+    return attend_reference(q, k, v, q_pos, kv_pos, kv_len, causal=True,
+                            window=window, attn_softcap=softcap, scale=scale,
+                            tree_info=info)
 
 
 def tree_attention_paged_ref(q, k_pages, v_pages, block_tables, kv_len,
@@ -124,8 +127,8 @@ def pard_attention_ref(q, k, v, segment, base, *, scale=None, softcap=0.0):
     b, t = q.shape[:2]
     pos = jnp.zeros((b, t), jnp.int32)
     info = PardMaskInfo(jnp.asarray(segment), jnp.asarray(base))
-    return attend(q, k, v, pos, pos, t, causal=False, attn_softcap=softcap,
-                  scale=scale, mask_info=info)
+    return attend_reference(q, k, v, pos, pos, t, causal=False,
+                            attn_softcap=softcap, scale=scale, mask_info=info)
 
 
 def ssd_ref(x, dt, A, B, C, init_state=None):

@@ -26,11 +26,11 @@ whole tree. Ancestors sit at most ``max_depth`` logical positions behind the
 query, far inside any realistic sliding window, so the window test applies
 to context keys only.
 
-Like kernels/decode_attention.py, ONE kernel body serves both cache layouts:
-contiguous ``[B, S, Hkv, D]`` rows, and the block-paged pool where the
-scalar-prefetched block table resolves the pool indirection in the BlockSpec
-index_map before the DMA. Blocks past ``kv_len`` are skipped, so swept bytes
-track the actual cache fill.
+The grid, block layout, query grouping and the online-softmax sweep are the
+decode kernel's (kernels/decode_attention.py): one pallas_call over the
+block pool serves both cache layouts, per-row scalars (``kv_len``,
+``win_start``, ``win_len``) are scalar-prefetched, and the per-query
+``q_pos``/``anc`` vectors arrive pre-expanded to kernel rows.
 """
 from __future__ import annotations
 
@@ -41,61 +41,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from .decode_attention import (as_pages, attention_scratch, group_queries,
+                               identity_tables, init_scratch, kv_block_specs,
+                               last_block, query_rows, split_quant_refs,
+                               sweep_block, ungroup_output, write_output)
 
 
-def _kernel(qpos_ref, kvlen_ref, winstart_ref, winlen_ref, anc_ref, q_ref,
-            k_ref, v_ref, *rest, scale, window, softcap, block_k, tq, g,
-            quant=False):
-    if quant:
-        # quantized KV stream (DESIGN.md §10): per-(slot, head) float32
-        # scales ride in two extra refs right after k/v
-        ks_ref, vs_ref, o_ref, m_s, l_s, acc_s, qp_s, anc_s = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_s, l_s, acc_s, qp_s, anc_s = rest
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _kernel(bt_ref, kvlen_ref, winstart_ref, winlen_ref, qpos_ref, anc_ref,
+            q_ref, k_ref, v_ref, *rest, scale, window, softcap, block_k, tq,
+            quant):
+    ks_ref, vs_ref, (o_ref, m_s, l_s, acc_s) = split_quant_refs(rest, quant)
+    bi, ki = pl.program_id(0), pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-        # the per-row mask operands are k-block-invariant: expand the
-        # [tq] position / ancestor-bitmask vectors to query-row shape ONCE
-        # per (batch, head) program instead of on every k-block visit
-        qp_s[...] = jnp.repeat(qpos_ref[0, :], g)[:, None]
-        anc_s[...] = jnp.repeat(anc_ref[0, :], g)[:, None]
+        init_scratch(m_s, l_s, acc_s)
 
-    kv_len = kvlen_ref[0]                              # scalar for this row
-    ws = winstart_ref[0]
-    wl = winlen_ref[0]                                 # row's own window
-    eff_len = jnp.minimum(kv_len, ws + wl)             # per-row sweep bound
+    ws = winstart_ref[bi]
+    wl = winlen_ref[bi]                                # row's own window
+    eff_len = jnp.minimum(kvlen_ref[bi], ws + wl)      # per-row sweep bound
     k_start = ki * block_k
 
     @pl.when(k_start < eff_len)
     def _compute():
-        q = q_ref[0, :, :, :].astype(jnp.float32)      # [tq, g, d]
-        d = q.shape[-1]
-        q2 = q.reshape(tq * g, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [bk, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quant:
-            # dequant fused into the sweep: the block expands against its
-            # scales right after the DMA, still inside VMEM
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
-        s = jnp.dot(q2, k.T, preferred_element_type=jnp.float32) * scale
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
-
-        # rows are (window slot i, group member): the mask depends only on
-        # i — read the expansions hoisted into scratch at ki == 0
-        qp_rows = qp_s[...]                            # [tq*g, 1] int32
-        anc_rows = anc_s[...]                          # [tq*g, 1] uint32
+        qp_rows = qpos_ref[0]                          # [rows, 1] int32
+        anc_rows = anc_ref[0]                          # [rows, 1] uint32
         k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (tq * g, block_k), 1)
+            jnp.int32, (qp_rows.shape[0], block_k), 1)
         ctx = k_pos < ws                               # committed context
         if window:
             ctx &= k_pos > qp_rows - window
@@ -104,97 +76,12 @@ def _kernel(qpos_ref, kvlen_ref, winstart_ref, winlen_ref, anc_ref, q_ref,
         bit = (anc_rows >> jnp.clip(j, 0, tq - 1).astype(jnp.uint32)
                ) & jnp.uint32(1)
         mask = (k_pos < eff_len) & (ctx | (in_win & (bit == 1)))
-        s = jnp.where(mask, s, NEG_INF)
+        sweep_block(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_s, l_s, acc_s,
+                    mask, scale=scale, softcap=softcap)
 
-        m_prev = m_s[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_s[...] = acc_s[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_s[...] = m_new
-
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == pl.num_programs(1) - 1)
     def _final():
-        denom = jnp.where(l_s[...] == 0.0, 1.0, l_s[...])
-        o_ref[0, :, 0, :] = (acc_s[...] / denom).reshape(
-            tq, g * acc_s.shape[-1]).astype(o_ref.dtype)
-
-
-def tree_attention(q, k, v, kv_len, q_pos, win_start, anc, *, win_len=None,
-                   k_scale=None, v_scale=None, window=0, softcap=0.0,
-                   scale=None, block_k=256, interpret=False):
-    """q: [B, Tq, Hq, D] — the packed verify window; k, v: [B, S, Hkv, D];
-    kv_len: [B]; q_pos: [B, Tq] logical positions (root pos + depth);
-    win_start: [B] cache index of window slot 0; anc: [B, Tq] uint32
-    ancestor bitmasks (bit j = window slot j visible); win_len: [B] int32
-    count of meaningful window slots per row (None = Tq for every row —
-    single-template batches); k_scale/v_scale: optional [B, S, Hkv] float32
-    dequant scales for quantized k/v (int8 / fp8)."""
-    b, tq, hq, d = q.shape
-    s_len, hkv = k.shape[1], k.shape[2]
-    g = hq // hkv
-    quant = k_scale is not None
-    if scale is None:
-        scale = 1.0 / (d ** 0.5)
-    if win_len is None:
-        win_len = jnp.full((b,), tq, jnp.int32)
-
-    qg = q.reshape(b, tq, hkv, g, d)
-    grid = (b, hkv, pl.cdiv(s_len, block_k))
-
-    kern = functools.partial(_kernel, scale=scale, window=window,
-                             softcap=softcap, block_k=block_k, tq=tq, g=g,
-                             quant=quant)
-
-    in_specs = [
-        pl.BlockSpec((1, tq), lambda bi, h, ki: (bi, 0)),       # q_pos
-        pl.BlockSpec((1,), lambda bi, h, ki: (bi,)),            # kv_len
-        pl.BlockSpec((1,), lambda bi, h, ki: (bi,)),            # win_start
-        pl.BlockSpec((1,), lambda bi, h, ki: (bi,)),            # win_len
-        pl.BlockSpec((1, tq), lambda bi, h, ki: (bi, 0)),       # anc
-        pl.BlockSpec((1, tq, 1, g, d),
-                     lambda bi, h, ki: (bi, 0, h, 0, 0)),       # q
-        pl.BlockSpec((1, block_k, 1, d),
-                     lambda bi, h, ki: (bi, ki, h, 0)),         # k
-        pl.BlockSpec((1, block_k, 1, d),
-                     lambda bi, h, ki: (bi, ki, h, 0)),         # v
-    ]
-    args = [q_pos.astype(jnp.int32), kv_len.astype(jnp.int32),
-            win_start.astype(jnp.int32), win_len.astype(jnp.int32),
-            anc.astype(jnp.uint32), qg, k, v]
-    if quant:
-        for _ in range(2):                                      # k/v scales
-            in_specs.append(pl.BlockSpec((1, block_k, 1),
-                                         lambda bi, h, ki: (bi, ki, h)))
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
-
-    out = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tq, 1, g * d),
-                               lambda bi, h, ki: (bi, 0, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, tq, hkv, g * d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((tq * g, 1), jnp.float32),
-            pltpu.VMEM((tq * g, 1), jnp.float32),
-            pltpu.VMEM((tq * g, d), jnp.float32),
-            pltpu.VMEM((tq * g, 1), jnp.int32),     # hoisted q positions
-            pltpu.VMEM((tq * g, 1), jnp.uint32),    # hoisted ancestor masks
-        ],
-        interpret=interpret,
-    )(*args)
-    return out.reshape(b, tq, hq, d)
-
-
-def _paged_kernel(bt_ref, qpos_ref, kvlen_ref, winstart_ref, winlen_ref,
-                  anc_ref, q_ref, k_ref, v_ref, *rest, **kw):
-    # bt_ref (the scalar-prefetched block table) is consumed only by the
-    # BlockSpec index_maps; the compute body is the contiguous kernel's.
-    _kernel(qpos_ref, kvlen_ref, winstart_ref, winlen_ref, anc_ref, q_ref,
-            k_ref, v_ref, *rest, **kw)
+        write_output(o_ref, l_s, acc_s)
 
 
 def tree_attention_paged(q, k_pages, v_pages, block_tables, kv_len, q_pos,
@@ -213,60 +100,69 @@ def tree_attention_paged(q, k_pages, v_pages, block_tables, kv_len, q_pos,
     """
     b, tq, hq, d = q.shape
     block, hkv = k_pages.shape[1], k_pages.shape[2]
-    mbs = block_tables.shape[1]
     g = hq // hkv
+    rows = tq * g
     quant = k_scale is not None
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if win_len is None:
         win_len = jnp.full((b,), tq, jnp.int32)
 
-    qg = q.reshape(b, tq, hkv, g, d)
-    kern = functools.partial(_paged_kernel, scale=scale, window=window,
-                             softcap=softcap, block_k=block, tq=tq, g=g,
-                             quant=quant)
+    def entry(bi, ki, bt, kl, ws, wl):
+        return bt[bi, last_block(ki, jnp.minimum(kl[bi], ws[bi] + wl[bi]),
+                                 block)]
 
-    in_specs = [
-        pl.BlockSpec((1, tq), lambda bi, h, ki, bt: (bi, 0)),   # q_pos
-        pl.BlockSpec((1,), lambda bi, h, ki, bt: (bi,)),        # kv_len
-        pl.BlockSpec((1,), lambda bi, h, ki, bt: (bi,)),        # win_start
-        pl.BlockSpec((1,), lambda bi, h, ki, bt: (bi,)),        # win_len
-        pl.BlockSpec((1, tq), lambda bi, h, ki, bt: (bi, 0)),   # anc
-        pl.BlockSpec((1, tq, 1, g, d),
-                     lambda bi, h, ki, bt: (bi, 0, h, 0, 0)),   # q
-        pl.BlockSpec((1, block, 1, d),
-                     lambda bi, h, ki, bt: (bt[bi, ki], 0, h, 0)),  # k
-        pl.BlockSpec((1, block, 1, d),
-                     lambda bi, h, ki, bt: (bt[bi, ki], 0, h, 0)),  # v
-    ]
-    args = [block_tables.astype(jnp.int32), q_pos.astype(jnp.int32),
-            kv_len.astype(jnp.int32), win_start.astype(jnp.int32),
-            win_len.astype(jnp.int32), anc.astype(jnp.uint32), qg, k_pages,
-            v_pages]
+    def row_map(bi, ki, *_):
+        return (bi, 0, 0)
+
+    def head_map(bi, ki, *_):
+        return (bi, 0, 0, 0)
+
+    in_specs = [pl.BlockSpec((1, rows, 1), row_map),                # q_pos
+                pl.BlockSpec((1, rows, 1), row_map),                # anc
+                pl.BlockSpec((1, hkv, rows, d), head_map)]          # q
+    in_specs += kv_block_specs(hkv, d, block, quant, entry)
+    args = [block_tables.astype(jnp.int32), kv_len.astype(jnp.int32),
+            win_start.astype(jnp.int32), win_len.astype(jnp.int32),
+            query_rows(q_pos.astype(jnp.int32), g),
+            query_rows(anc.astype(jnp.uint32), g), group_queries(q, hkv),
+            k_pages, v_pages]
     if quant:
-        for _ in range(2):                                      # k/v scales
-            in_specs.append(pl.BlockSpec(
-                (1, block, 1), lambda bi, h, ki, bt: (bt[bi, ki], 0, h)))
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hkv, mbs),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tq, 1, g * d),
-                               lambda bi, h, ki, bt: (bi, 0, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((tq * g, 1), jnp.float32),
-            pltpu.VMEM((tq * g, 1), jnp.float32),
-            pltpu.VMEM((tq * g, d), jnp.float32),
-            pltpu.VMEM((tq * g, 1), jnp.int32),     # hoisted q positions
-            pltpu.VMEM((tq * g, 1), jnp.uint32),    # hoisted ancestor masks
-        ],
-    )
+    kern = functools.partial(_kernel, scale=scale, window=window,
+                             softcap=softcap, block_k=block, tq=tq,
+                             quant=quant)
     out = pl.pallas_call(
         kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, tq, hkv, g * d), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b, block_tables.shape[1]),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, hkv, rows, d), head_map),
+            scratch_shapes=attention_scratch(hkv, rows, d)),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
         interpret=interpret,
     )(*args)
-    return out.reshape(b, tq, hq, d)
+    return ungroup_output(out, tq)
+
+
+def tree_attention(q, k, v, kv_len, q_pos, win_start, anc, *, win_len=None,
+                   k_scale=None, v_scale=None, window=0, softcap=0.0,
+                   scale=None, block_k=256, interpret=False):
+    """q: [B, Tq, Hq, D] — the packed verify window; k, v: [B, S, Hkv, D]
+    with S a multiple of ``block_k``; kv_len: [B]; q_pos: [B, Tq] logical
+    positions (root pos + depth); win_start: [B] cache index of window slot
+    0; anc: [B, Tq] uint32 ancestor bitmasks (bit j = window slot j
+    visible); win_len: [B] int32 count of meaningful window slots per row
+    (None = Tq for every row — single-template batches); k_scale/v_scale:
+    optional [B, S, Hkv] float32 dequant scales for quantized k/v."""
+    b, s = k.shape[:2]
+    quant = k_scale is not None
+    return tree_attention_paged(
+        q, as_pages(k, block_k), as_pages(v, block_k),
+        identity_tables(b, s // block_k), kv_len, q_pos, win_start, anc,
+        win_len=win_len,
+        k_scale=as_pages(k_scale, block_k) if quant else None,
+        v_scale=as_pages(v_scale, block_k) if quant else None,
+        window=window, softcap=softcap, scale=scale, interpret=interpret)

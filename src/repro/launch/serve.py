@@ -20,6 +20,79 @@ import argparse
 import time
 
 
+def load_params(cfg, key, ckpt=None, *, mesh=None, tp_ruleset="exact",
+                replicate=False):
+    """Random params for ``cfg`` drawn from ``key``, then the checkpoint at
+    ``ckpt`` if given.
+
+    Without a mesh they land on the default device. With one,
+    ``init_params`` runs under ``jit`` with ``out_shardings`` set to the
+    serving layout — the target ruleset's (``param_specs(serving=True)``)
+    or, for a draft (``replicate=True``), a full copy per device — so each
+    device only ever materialises its own shard: a target larger than one
+    chip is never held whole on one (DESIGN.md §11)."""
+    import functools
+
+    import jax
+
+    from repro.models import init_params
+    from repro.sharding import specs
+
+    if mesh is None:
+        params = init_params(key, cfg)
+    else:
+        init = functools.partial(init_params, cfg=cfg)
+        shapes = jax.eval_shape(init, key)
+        tree = (specs.replicated_specs(shapes) if replicate else
+                specs.param_specs(shapes, mesh, serving=True,
+                                  ruleset=tp_ruleset))
+        params = jax.jit(init, out_shardings=specs.to_named(tree, mesh))(key)
+    if ckpt:
+        from repro.training import checkpoint
+        params = checkpoint.restore(ckpt, params)
+    return params
+
+
+def make_prompts(vocab_size, n, prompt_len, seed, *, share=1):
+    """``n`` synthetic prompts from the Markov corpus. ``share > 1`` is
+    the shared-prefix mix: ``share`` requests per system prompt of
+    ``prompt_len`` tokens, each with a unique 8-token tail; groups
+    interleave round-robin so same-prefix requests arrive across batch
+    generations — concurrent identical prompts cannot hit (computed
+    gating), later arrivals do."""
+    import numpy as np
+
+    from repro.data.pipeline import MarkovCorpus
+
+    corpus = MarkovCorpus(vocab_size=vocab_size, seed=0, determinism=2.0)
+    rng = np.random.default_rng(seed)
+    share = max(1, share)
+    sys_prompts = [corpus.prompts(rng, 1, prompt_len)[0]
+                   for _ in range(-(-n // share))]
+    if share == 1:
+        return [corpus.prompts(rng, 1, prompt_len)[0] for _ in range(n)]
+    return [np.concatenate([sys_prompts[i % len(sys_prompts)],
+                            np.asarray(corpus.prompts(rng, 1, 8)[0],
+                                       np.int32)])
+            for i in range(n)]
+
+
+def serve_requests(eng, prompts, max_new, *, greedy_requests=0):
+    """Submit ``prompts`` and run the engine to completion; returns the
+    completions and the wall seconds from first submit to last token.
+    The first ``greedy_requests`` requests decode greedily even when the
+    engine default samples (mixed batches)."""
+    from repro.serving.engine import SamplingParams
+
+    t0 = time.perf_counter()
+    for i, prompt in enumerate(prompts):
+        temp = 0.0 if i < greedy_requests else None
+        eng.submit(prompt, params=SamplingParams(max_new=max_new,
+                                                 temperature=temp))
+    comps = eng.run()                # pipelining comes from config.pipelined
+    return comps, time.perf_counter() - t0
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--target", required=True)
@@ -115,55 +188,31 @@ def main():
         ensure_host_devices(args.devices)
 
     import jax
-    import numpy as np
 
     from repro.configs import get_config
-    from repro.data.pipeline import MarkovCorpus
-    from repro.models import init_params
-    from repro.serving.engine import Engine, EngineConfig, SamplingParams
-    from repro.training import checkpoint
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.serving.engine import Engine, EngineConfig
 
+    enable_compile_cache()
+    config = EngineConfig.from_args(args)
+    tree = config.tree
+    # a dp > 1 engine places one copy per replica itself (SpecDecoder)
+    mesh = config.mesh if config.dp == 1 else None
     tc = get_config(args.target)
-    tp = init_params(jax.random.PRNGKey(0), tc)
-    if args.target_ckpt:
-        tp = checkpoint.restore(args.target_ckpt, tp)
+    tp = load_params(tc, jax.random.PRNGKey(0), args.target_ckpt, mesh=mesh,
+                     tp_ruleset=config.tp_ruleset)
     dp = dc = None
     if args.mode != "ar":
         assert args.draft, "--draft required for vsd/pard"
         dc = get_config(args.draft)
-        dp = init_params(jax.random.PRNGKey(1), dc)
-        if args.draft_ckpt:
-            dp = checkpoint.restore(args.draft_ckpt, dp)
-
-    config = EngineConfig.from_args(args)
-    tree = config.tree
+        dp = load_params(dc, jax.random.PRNGKey(1), args.draft_ckpt,
+                         mesh=mesh, replicate=True)
     eng = Engine(tp, tc, dp, dc, config=config)
 
-    corpus = MarkovCorpus(vocab_size=tc.vocab_size, seed=0, determinism=2.0)
-    rng = np.random.default_rng(args.seed)
-    share = max(1, args.prefix_share)
-    sys_prompts = [corpus.prompts(rng, 1, args.prompt_len)[0]
-                   for _ in range(-(-args.requests // share))]
-    t0 = time.perf_counter()
-    for i in range(args.requests):
-        # per-request temperature: the first --greedy-requests rows decode
-        # greedily even when the engine default samples (mixed batches)
-        temp = 0.0 if i < args.greedy_requests else None
-        if share > 1:
-            # shared-prefix mix: `share` requests per system prompt, each
-            # with a unique tail (the prefix-cache benchmark workload);
-            # groups interleave round-robin so same-prefix requests arrive
-            # across batch generations — concurrent identical prompts
-            # cannot hit (computed gating), later arrivals do
-            prompt = np.concatenate([
-                sys_prompts[i % len(sys_prompts)],
-                np.asarray(corpus.prompts(rng, 1, 8)[0], np.int32)])
-        else:
-            prompt = corpus.prompts(rng, 1, args.prompt_len)[0]
-        eng.submit(prompt, params=SamplingParams(max_new=args.max_new,
-                                                 temperature=temp))
-    comps = eng.run()                # pipelining comes from config.pipelined
-    wall = time.perf_counter() - t0
+    prompts = make_prompts(tc.vocab_size, args.requests, args.prompt_len,
+                           args.seed, share=args.prefix_share)
+    comps, wall = serve_requests(eng, prompts, args.max_new,
+                                 greedy_requests=args.greedy_requests)
 
     total = sum(c.generated for c in comps)
     label = args.mode if tree is None else (

@@ -46,24 +46,38 @@ Array = jax.Array
 
 NEG_INF = -1e30
 
-# attention backend: "xla" (jnp reference, default) or "pallas" (the
-# kernels/ implementations; interpret-mode on CPU, native on TPU). Switch
-# with set_attention_backend — tests assert both paths agree.
-_BACKEND = "xla"
+# attention backend: "xla" (the jnp reference) or "pallas" (the kernels/
+# implementations). set_attention_backend pins one for tests (interpret-
+# mode kernels on the CPU — the tests assert both paths agree); None, the
+# default, lets the platform choose (_use_pallas).
+_BACKEND = None
 
 
-def set_attention_backend(name: str) -> None:
+def set_attention_backend(name) -> None:
     global _BACKEND
-    assert name in ("xla", "pallas")
+    assert name in (None, "xla", "pallas")
     _BACKEND = name
 
 
-def _pallas_ok(q, k, mask_info, scale) -> bool:
+def _use_pallas(cached: bool = True) -> bool:
+    """Whether attention runs the Pallas kernels. Unpinned, a TPU runs the
+    compiled kernels on the CACHED paths — the serving steps' prefill
+    chunks, decode and verify windows — and every other backend the jnp
+    reference. An uncached forward (training, whose loss is
+    differentiated; scoring) keeps the jnp path on a TPU too: the kernels
+    define no VJP, and flash_attention is not partitioned under a training
+    mesh."""
+    if _BACKEND is None:
+        return cached and jax.default_backend() == "tpu"
+    return _BACKEND == "pallas"
+
+
+def _pallas_ok(q, k, v, mask_info, *, cached: bool = True) -> bool:
     """The Pallas kernels cover the standard GQA cases: no PARD metadata
-    (training masks use ops.pard_attention via the loss path), head_dim
-    uniform q/k (excludes MLA's mixed dims handled by the xla path)."""
-    return (_BACKEND == "pallas" and mask_info is None
-            and q.shape[-1] == k.shape[-1])
+    (training masks use ops.pard_attention via the loss path) and one head
+    dim for q, k and v (MLA's value dim differs: the xla path)."""
+    return (_use_pallas(cached) and mask_info is None
+            and q.shape[-1] == k.shape[-1] == v.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -111,7 +125,11 @@ def quantize_kv(x, qdtype):
     maxval = _QUANT_MAXVAL[jnp.dtype(qdtype)]
     xf = x.astype(jnp.float32)
     amax = jnp.max(jnp.abs(xf), axis=-1)
-    scale = jnp.where(amax > 0, amax / maxval, 1.0)
+    # floored at the smallest normal float: amax / maxval of a tiny row
+    # would be subnormal, which the backend may flush to a zero scale
+    scale = jnp.where(amax > 0,
+                      jnp.maximum(amax / maxval, jnp.finfo(jnp.float32).tiny),
+                      1.0)
     scaled = xf / scale[..., None]
     if jnp.dtype(qdtype) == jnp.dtype(jnp.int8):
         q = jnp.clip(jnp.round(scaled), -maxval, maxval).astype(jnp.int8)
@@ -205,7 +223,8 @@ def pard_mask(q_seg, q_base, k_seg, k_base):
 def attend(q, k, v, q_pos, kv_pos, kv_len, *, causal=True, window=0,
            attn_softcap=0.0, scale=None, mask_info=None, kv_mask_info=None,
            tree_info=None, k_scale=None, v_scale=None):
-    """Masked multi-head attention core (pure jnp reference path).
+    """Masked multi-head attention: the Pallas kernels where ``_pallas_ok``
+    picks them, else the jnp reference (``attend_reference``).
 
     q:      [B, Tq, Hq, Dk]
     k, v:   [B, Tk, Hkv, Dk] / [B, Tk, Hkv, Dv]
@@ -220,40 +239,53 @@ def attend(q, k, v, q_pos, kv_pos, kv_len, *, causal=True, window=0,
             kernels fuse the dequant into their KV stream; every other path
             dequantizes up front (the reference semantics).
     """
+    b, tq = q.shape[:2]
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    # q spanning every key is an uncached forward (training, scoring); a
+    # shorter q is a prefill chunk, decode or verify window against a cache
+    full = tq == k.shape[1]
+    kernel = _pallas_ok(q, k, v, mask_info, cached=not full)
+    fused_dequant = kernel and (tree_info is not None or (causal and not full))
+    if k_scale is not None and not fused_dequant:
+        k = dequantize_kv(k, k_scale)
+        v = dequantize_kv(v, v_scale)
+        k_scale = v_scale = None
+
+    if kernel and (tree_info is not None or causal):
+        from ..kernels import ops
+        kv_len_arr = jnp.broadcast_to(jnp.asarray(kv_len), (b,)).astype(jnp.int32)
+        if tree_info is not None:
+            return ops.tree_attention(q, k, v, kv_len_arr, q_pos,
+                                      tree_info.win_start, tree_info.anc,
+                                      win_len=tree_info.win_len,
+                                      k_scale=k_scale, v_scale=v_scale,
+                                      window=window, softcap=attn_softcap,
+                                      scale=scale)
+        if full:
+            return ops.flash_attention(q, k, v, causal=True, window=window,
+                                       softcap=attn_softcap, scale=scale)
+        return ops.decode_attention(q, k, v, kv_len_arr, q_pos,
+                                    k_scale=k_scale, v_scale=v_scale,
+                                    window=window, softcap=attn_softcap,
+                                    scale=scale)
+    return attend_reference(q, k, v, q_pos, kv_pos, kv_len, causal=causal,
+                            window=window, attn_softcap=attn_softcap,
+                            scale=scale, mask_info=mask_info,
+                            kv_mask_info=kv_mask_info, tree_info=tree_info)
+
+
+def attend_reference(q, k, v, q_pos, kv_pos, kv_len, *, causal=True,
+                     window=0, attn_softcap=0.0, scale=None, mask_info=None,
+                     kv_mask_info=None, tree_info=None):
+    """The jnp attention core, whatever the backend: ``attend``'s
+    semantics on unquantized k/v, and the oracle of every attention kernel
+    (kernels/ref.py)."""
     b, tq, hq, dk = q.shape
     hkv = k.shape[2]
     g = hq // hkv
     if scale is None:
         scale = 1.0 / math.sqrt(dk)
-
-    if k_scale is not None and not (
-            _pallas_ok(q, k, mask_info, scale)
-            and (tree_info is not None or (causal and tq != k.shape[1]))):
-        # quantized cache on a path without a dequant-fused kernel
-        k = dequantize_kv(k, k_scale)
-        v = dequantize_kv(v, v_scale)
-        k_scale = v_scale = None
-
-    if _pallas_ok(q, k, mask_info, scale) and tree_info is not None:
-        from ..kernels import ops
-        kv_len_arr = jnp.broadcast_to(jnp.asarray(kv_len), (b,)).astype(jnp.int32)
-        return ops.tree_attention(q, k, v, kv_len_arr, q_pos,
-                                  tree_info.win_start, tree_info.anc,
-                                  win_len=tree_info.win_len,
-                                  k_scale=k_scale, v_scale=v_scale,
-                                  window=window, softcap=attn_softcap,
-                                  scale=scale)
-    if _pallas_ok(q, k, mask_info, scale) and causal:
-        from ..kernels import ops
-        kv_len_arr = jnp.broadcast_to(jnp.asarray(kv_len), (b,)).astype(jnp.int32)
-        if tq == k.shape[1]:          # full self-attention (training/prefill)
-            return ops.flash_attention(q, k, v, causal=True, window=window,
-                                       softcap=attn_softcap, scale=scale)
-        # small-q decode/verify against a long cache
-        return ops.decode_attention(q, k, v, kv_len_arr, q_pos,
-                                    k_scale=k_scale, v_scale=v_scale,
-                                    window=window, softcap=attn_softcap,
-                                    scale=scale)
 
     qg = q.reshape(b, tq, hkv, g, dk)
     logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
@@ -399,9 +431,8 @@ def _paged_attend(q, k_pages, v_pages, block_tables, q_pos, kv_len, *,
     is avoided wherever the kernel path is active (TPU decode/verify).
     """
     b, tq = q.shape[:2]
-    if (_BACKEND == "pallas" and causal
-            and q.shape[-1] == k_pages.shape[-1]
-            and tq <= _PAGED_KERNEL_MAX_TQ):
+    if (causal and tq <= _PAGED_KERNEL_MAX_TQ
+            and _pallas_ok(q, k_pages, v_pages, None)):
         from ..kernels import ops
         kv_len_arr = jnp.broadcast_to(jnp.asarray(kv_len), (b,)).astype(jnp.int32)
         if tree_info is not None:

@@ -314,8 +314,8 @@ class Executor:
             # per tick by whether any row is actually prefilling
             builder = self.dec._build_ar_step(chunked=variant == "mixed")
 
-            def step(state):
-                return builder(state), None, None, None, None, 0
+            def step(params, state):
+                return builder(params, state), None, None, None, None, 0
             return step
         # spec/tree windows already fit the chunk (same shapes either way:
         # the chunk substitution is a few jnp.where selects), so one
@@ -339,10 +339,14 @@ class Executor:
         with the step outputs — the pipelined scheduler cannot derive it
         from host mirrors, which run one step ahead of unharvested
         results — and re-returns ``n``/``gen`` as explicit outputs so a
-        harvest needs no read of the (soon-to-be-donated) state."""
+        harvest needs no read of the (soon-to-be-donated) state.
+
+        The (target, draft) params are the step's FIRST argument, never
+        closure constants: at published widths constants would embed
+        gigabytes of weights in the program."""
         inner = self._build(variant, greedy_only)
 
-        def fused(state, retire, tree_sel, limits):
+        def fused(params, state, retire, tree_sel, limits):
             # staged retirement + the device-side limit freeze: a row whose
             # committed count reached its limit is frozen even if the host
             # has not harvested that result yet (the pipelined loop's
@@ -356,7 +360,7 @@ class Executor:
             st = dataclasses.replace(state, done=done, temp=temp,
                                      tree_idx=tree_idx)
             live = ~(st.done | (st.pf_pos < st.pf_len))
-            new_state, a, _hist, rhist, rank, _nd = inner(st)
+            new_state, a, _hist, rhist, rank, _nd = inner(params, st)
             return new_state, a, rank, rhist, live, new_state.n, new_state.gen
         return fused
 
@@ -378,28 +382,7 @@ class Executor:
         the two compiled variants across steps is safe."""
         variant = "mixed" if (any_prefilling and self.mode == "ar") \
             else "decode"
-        greedy_only = not any_sampled and self.mode != "ar"
-        key = (variant, tree_sel is not None, greedy_only, self.kv_dtype,
-               self.replica, self.tp_ruleset)
-        if key not in self._step_fns:
-            fused = self._build_fused(variant, apply_tree=tree_sel is not None,
-                                      greedy_only=greedy_only)
-            if self.mesh is None:
-                self._step_fns[key] = jax.jit(fused, donate_argnums=(0,))
-            else:
-                # pin shardings on BOTH sides of the fused step: the donated
-                # state's buffers keep their layout tick over tick (no
-                # resharding churn), and the step stays one device
-                # computation per dispatch. Scalars/handle outputs
-                # replicate; None outputs (mode="ar") take a None entry.
-                repl = jax.sharding.NamedSharding(
-                    self.mesh, jax.sharding.PartitionSpec())
-                aux = repl if self.mode != "ar" else None
-                self._step_fns[key] = jax.jit(
-                    fused, donate_argnums=(0,),
-                    in_shardings=(self._state_sh, repl, repl, repl),
-                    out_shardings=(self._state_sh, aux, aux, aux,
-                                   repl, repl, repl))
+        step_fn = self._step_fn(variant, tree_sel is not None, any_sampled)
         b = self.max_batch
         retire_d = (jnp.zeros((b,), bool) if retire is None
                     else jnp.asarray(retire, bool))
@@ -407,16 +390,15 @@ class Executor:
                     else jnp.asarray(limits, jnp.int32))
         tree_d = (jnp.zeros((b,), jnp.int32) if tree_sel is None
                   else jnp.asarray(tree_sel, jnp.int32))
+        args = (self._params, self.state, retire_d, tree_d, limits_d)
         if self.mesh is not None:
             # trace under the activation mesh + ruleset so the forward's
             # partial/gather_activation hints bake in (§11/§13)
             from ..kernels import ops as _ops
             with _ops.activation_mesh(self.mesh, self.tp_ruleset):
-                self.state, a, rank, rhist, live, n, gen = \
-                    self._step_fns[key](self.state, retire_d, tree_d, limits_d)
+                self.state, a, rank, rhist, live, n, gen = step_fn(*args)
         else:
-            self.state, a, rank, rhist, live, n, gen = \
-                self._step_fns[key](self.state, retire_d, tree_d, limits_d)
+            self.state, a, rank, rhist, live, n, gen = step_fn(*args)
         return StepHandle(a=a, rank=rank, rhist=rhist, live=live, n=n,
                           gen=gen, n_draft=self._n_draft,
                           tree_sel=None if tree_sel is None
@@ -454,32 +436,58 @@ class Executor:
     def step_hlo(self, *, tree: bool = False, any_sampled: bool = False) -> str:
         """Compiled (post-GSPMD) HLO text of the decode-variant fused step.
 
-        AOT lower + compile against the live DecodeState — nothing
-        executes and nothing is donated, so this is safe to call on a
-        serving executor between ticks. tools/comm_audit.py walks the
-        returned text to count per-step collectives and their byte
-        volumes, the measurable gate for the throughput ruleset
-        (DESIGN.md §13; CPU-emulated collective wall-clock is not
-        trustworthy, op/byte accounting is)."""
-        greedy_only = not any_sampled and self.mode != "ar"
-        fused = self._build_fused("decode", apply_tree=tree,
-                                  greedy_only=greedy_only)
+        Lowers the SAME jitted step that ``dispatch`` runs against the live
+        DecodeState — nothing executes and nothing is donated, so this is
+        safe between ticks, and after the step has run it costs no second
+        compile (JAX's compilation cache holds the executable).
+        tools/comm_audit.py walks the returned text to count per-step
+        collectives and their byte volumes (DESIGN.md §13); on a TPU a
+        ``tpu_custom_call`` in it shows the Pallas kernels are in the
+        step."""
         b = self.max_batch
-        args = (self.state, jnp.zeros((b,), bool),
+        args = (self._params, self.state, jnp.zeros((b,), bool),
                 jnp.zeros((b,), jnp.int32),
                 jnp.full((b,), NO_LIMIT, jnp.int32))
+        jitted = self._step_fn("decode", tree, any_sampled)
         if self.mesh is None:
-            return jax.jit(fused).lower(*args).compile().as_text()
-        repl = jax.sharding.NamedSharding(
-            self.mesh, jax.sharding.PartitionSpec())
-        aux = repl if self.mode != "ar" else None
-        jitted = jax.jit(
-            fused,
-            in_shardings=(self._state_sh, repl, repl, repl),
-            out_shardings=(self._state_sh, aux, aux, aux, repl, repl, repl))
+            return jitted.lower(*args).compile().as_text()
         from ..kernels import ops as _ops
         with _ops.activation_mesh(self.mesh, self.tp_ruleset):
             return jitted.lower(*args).compile().as_text()
+
+    @property
+    def _params(self):
+        """(target, draft) weights: the first argument of every step."""
+        return self.dec.tp, self.dec.dp
+
+    def _step_fn(self, variant: str, apply_tree: bool, any_sampled: bool):
+        """The jitted fused step for one variant, built once per executor.
+        On a mesh the shardings are pinned on BOTH sides: the donated
+        state's buffers keep their layout tick over tick (no resharding
+        churn), and the step stays one device computation per dispatch.
+        Params keep the shardings they were placed with; scalars/handle
+        outputs replicate; None outputs (mode="ar") take a None entry."""
+        greedy_only = not any_sampled and self.mode != "ar"
+        key = (variant, apply_tree, greedy_only, self.kv_dtype,
+               self.replica, self.tp_ruleset)
+        if key in self._step_fns:
+            return self._step_fns[key]
+        fused = self._build_fused(variant, apply_tree=apply_tree,
+                                  greedy_only=greedy_only)
+        if self.mesh is None:
+            fn = jax.jit(fused, donate_argnums=(1,))
+        else:
+            repl = jax.sharding.NamedSharding(
+                self.mesh, jax.sharding.PartitionSpec())
+            aux = repl if self.mode != "ar" else None
+            param_sh = jax.tree.map(lambda x: x.sharding, self._params)
+            fn = jax.jit(
+                fused, donate_argnums=(1,),
+                in_shardings=(param_sh, self._state_sh, repl, repl, repl),
+                out_shardings=(self._state_sh, aux, aux, aux,
+                               repl, repl, repl))
+        self._step_fns[key] = fn
+        return fn
 
     # --------------------------------------------------------------- host
     def read_n(self) -> np.ndarray:

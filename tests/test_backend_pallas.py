@@ -14,7 +14,7 @@ from repro.models.attention import set_attention_backend
 @pytest.fixture(autouse=True)
 def _restore_backend():
     yield
-    set_attention_backend("xla")
+    set_attention_backend(None)
 
 
 @pytest.mark.parametrize("arch", ["tiny-target", "gemma2-27b"])
@@ -85,3 +85,69 @@ def test_paged_decode_backend_equivalence():
     out = run()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-3, rtol=2e-3)
+
+
+def _has_pallas_call(fn, *args):
+    return "pallas_call" in str(jax.make_jaxpr(fn)(*args))
+
+
+def _oracle_cases():
+    """(name, oracle, args) for every attention oracle of kernels/ref.py,
+    at a tiny GQA shape with a paged pool and a 4-slot verify window."""
+    from repro.kernels import ref
+    b, tq, hq, hkv, d, bs, mbs = 2, 4, 4, 2, 8, 8, 2
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(ks[0], (b, tq, hq, d))
+    kc = jax.random.normal(ks[1], (b, bs * mbs, hkv, d))
+    kp = jax.random.normal(ks[2], (1 + b * mbs, bs, hkv, d))
+    tables = jnp.arange(1, 1 + b * mbs, dtype=jnp.int32).reshape(b, mbs)
+    kv_len = jnp.full((b,), 12, jnp.int32)
+    q_pos = 8 + jnp.broadcast_to(jnp.arange(tq)[None], (b, tq))
+    win = jnp.full((b,), 8, jnp.int32)
+    anc = jnp.broadcast_to(jnp.uint32([1, 3, 5, 9])[None], (b, tq))
+    seg = jnp.ones((b, tq), jnp.int32)
+    base = jnp.broadcast_to(jnp.arange(tq)[None], (b, tq))
+    return [
+        ("flash", ref.flash_attention_ref, (q, q[:, :, :hkv], q[:, :, :hkv])),
+        ("decode", ref.decode_attention_ref, (q, kc, kc, kv_len, q_pos)),
+        ("decode_paged", ref.decode_attention_paged_ref,
+         (q, kp, kp, tables, kv_len, q_pos)),
+        ("tree", ref.tree_attention_ref,
+         (q, kc, kc, kv_len, q_pos, win, anc)),
+        ("tree_paged", ref.tree_attention_paged_ref,
+         (q, kp, kp, tables, kv_len, q_pos, win, anc)),
+        ("pard", ref.pard_attention_ref, (q, q, q, seg, base)),
+    ]
+
+
+@pytest.mark.parametrize("name", [c[0] for c in _oracle_cases()])
+def test_oracles_never_run_a_kernel(name):
+    """The kernels/ref.py oracles are the jnp reference whatever the
+    backend: with the kernels pinned on, an oracle that reached a kernel
+    would compare the kernel with itself."""
+    _, oracle, args = next(c for c in _oracle_cases() if c[0] == name)
+    set_attention_backend("pallas")
+    assert not _has_pallas_call(oracle, *args)
+
+
+def test_platform_default_runs_kernels_on_cached_paths_only(monkeypatch):
+    """Unpinned on a TPU, the serving steps' cached forwards run the
+    kernels and an uncached forward (training, differentiated) keeps the
+    jnp path, so ``jax.grad`` of a loss never meets a pallas_call."""
+    from repro.core.adaptation import ar_loss
+    set_attention_backend(None)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("tiny-target")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+
+    def decode(params, caches):
+        return forward(params, cfg, tokens[:, :1], caches=caches,
+                       cache_pos=jnp.full(2, 15, jnp.int32))[0]
+
+    caches = init_caches(cfg, 2, 64)
+    assert _has_pallas_call(decode, params, caches)
+    assert not _has_pallas_call(
+        lambda p: forward(p, cfg, tokens)[0], params)
+    assert not _has_pallas_call(
+        jax.grad(lambda p: ar_loss(p, cfg, tokens)[0]), params)

@@ -199,3 +199,24 @@ def test_sharded_pipelined_loop_identity(models):
     assert base.keys() == got.keys()
     for i in base:
         assert np.array_equal(base[i], got[i])
+
+
+@needs4
+@pytest.mark.parametrize("n", [2, 4])
+def test_kernel_path_token_identity_across_meshes(models, n):
+    """The Pallas kernels inside a sharded step run in a shard_map over the
+    kv heads ("model"): split when the axis divides them (n=2 for the tiny
+    target's 2 kv heads), replicated when it does not (n=4). Either way
+    the kernel path gives the same tokens on a mesh of n as on one."""
+    from repro.models.attention import set_attention_backend
+    set_attention_backend("pallas")
+    try:
+        base = _serve(models, None, "paged")
+        got = _serve(models, mesh_mod.make_host_mesh(model=n, data=1),
+                     "paged")
+    finally:
+        set_attention_backend(None)
+    assert base.keys() == got.keys()
+    for i in base:
+        assert np.array_equal(base[i], got[i]), \
+            f"request {i} diverged on the {n}-device mesh"

@@ -11,6 +11,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import cache_shapes, param_shapes
 from repro.sharding.specs import cache_specs, data_spec, param_specs
 
@@ -103,9 +104,7 @@ def test_cache_specs_long_context_seq_sharding():
 def test_sharded_train_step_runs_on_host_mesh():
     """End-to-end pjit train step on the test process's devices."""
     n = jax.device_count()
-    # jax.sharding.AxisType landed after 0.4.x; plain make_mesh axes are
-    # already Auto-typed under the installed API
-    mesh = jax.make_mesh((n, 1), ("data", "model"))
+    mesh = make_host_mesh(model=1)
     cfg = get_config("tiny-draft")
     from repro.training.optimizer import AdamW
     from repro.training.train_loop import Trainer

@@ -208,7 +208,6 @@ def test_psum_bf16_is_f32_upcast_sum_rounded_once():
     bf16-valued terms — exact in f32), and rounds to bf16 once. The HLO
     shows the reduction computation ``promoted``; here it is pinned
     behaviorally against a real 4-way psum."""
-    from jax.experimental.shard_map import shard_map
     mesh = mesh_mod.make_host_mesh(model=4, data=1)
     rng = np.random.default_rng(0)
     parts = jnp.asarray(
@@ -217,8 +216,8 @@ def test_psum_bf16_is_f32_upcast_sum_rounded_once():
 
     @jax.jit
     def psum4(p):
-        f = shard_map(lambda s: jax.lax.psum(s, "model"), mesh=mesh,
-                      in_specs=P(("model",), None), out_specs=P())
+        f = jax.shard_map(lambda s: jax.lax.psum(s, "model"), mesh=mesh,
+                          in_specs=P(("model",), None), out_specs=P())
         return f(p)
 
     # each shard holds a (1, 256) slice, so the psum'd output keeps the

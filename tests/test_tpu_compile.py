@@ -1,0 +1,116 @@
+"""The served Pallas kernels compile for a TPU v5e at published widths.
+
+No chip is needed: the TPU compiler is installed and compiles for a
+described, unattached ``v5e:2x2`` topology. The paged decode and tree
+verify kernels — bf16 and int8 pools, at the verify windows the serving
+steps use — are compiled at ``llama3.2-1b`` widths on one chip and at
+``llama3.1-8b`` widths split four ways (the per-device shard of a tp=4
+step), and the compiled program must hold the kernel (``tpu_custom_call``).
+This catches what interpret mode cannot: block shapes the TPU tiling rule
+refuses, and more VMEM than a kernel may use. The AR training loss is
+differentiated and compiled for the same chip, with the attention path the
+platform picks on a TPU: a Pallas kernel there has no VJP.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library.
+"""
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import ops
+
+B, BLOCK, BLOCKS_PER_ROW = 8, 64, 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for an unattached chip cannot be read back from the
+    # persistent cache: keep these compiles out of it
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _widths(model, tp):
+    cfg = get_config(model)
+    return cfg.n_heads // tp, cfg.n_kv_heads // tp, cfg.resolved_head_dim
+
+
+@pytest.mark.parametrize("model,tp", [("llama3.2-1b", 1),
+                                      ("llama3.1-8b", 4)])
+@pytest.mark.parametrize("kernel,tq", [("decode", 9), ("tree", 23)])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_kernel_compiles_for_v5e(one_chip, model, tp, kernel, tq, kv):
+    hq, hkv, d = _widths(model, tp)
+    nb = 1 + B * BLOCKS_PER_ROW
+    kv_dtype = jnp.bfloat16 if kv == "bf16" else jnp.int8
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [s((B, tq, hq, d), jnp.bfloat16),
+            s((nb, BLOCK, hkv, d), kv_dtype), s((nb, BLOCK, hkv, d), kv_dtype),
+            s((B, BLOCKS_PER_ROW), jnp.int32), s((B,), jnp.int32),
+            s((B, tq), jnp.int32)]
+    if kernel == "tree":
+        args += [s((B,), jnp.int32), s((B, tq), jnp.uint32),
+                 s((B,), jnp.int32)]
+    scales = ([s((nb, BLOCK, hkv), jnp.float32)] * 2 if kv == "int8"
+              else [None, None])
+
+    def step(*a):
+        ks, vs = a[len(args):] if kv == "int8" else (None, None)
+        if kernel == "tree":
+            q, kp, vp, bt, kl, qp, ws, anc, wl = a[:len(args)]
+            return ops.tree_attention_paged(
+                q, kp, vp, bt, kl, qp, ws, anc, win_len=wl, k_scale=ks,
+                v_scale=vs, interpret=False)
+        q, kp, vp, bt, kl, qp = a[:len(args)]
+        return ops.decode_attention_paged(q, kp, vp, bt, kl, qp, k_scale=ks,
+                                          v_scale=vs, interpret=False)
+
+    live = args + [x for x in scales if x is not None]
+    compiled = jax.jit(step).lower(*live).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ar_loss_grad_compiles_for_v5e(one_chip, monkeypatch):
+    """``jax.grad`` of the AR loss at ``llama3.2-1b`` widths (depth cut to
+    2 layers) compiles for one v5e with the attention path a TPU picks
+    unpinned: the uncached training forward stays on the jnp path."""
+    from repro.core.adaptation import ar_loss
+    from repro.models import init_params
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_config("llama3.2-1b"), num_layers=2)
+    shapes = jax.eval_shape(lambda k: init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        shapes)
+    tokens = jax.ShapeDtypeStruct((2, 129), jnp.int32, sharding=one_chip)
+    grad = jax.jit(jax.grad(lambda p, t: ar_loss(p, cfg, t)[0]))
+    compiled = grad.lower(params, tokens).compile()
+    assert "tpu_custom_call" not in compiled.as_text()

@@ -15,11 +15,11 @@ model decode-window forward jitted with the params as EXPLICIT sharded
 arguments, which is what a real deployment pays — weights resident as
 sharded device buffers that XLA cannot constant-fold. The engine's fused
 step (``audit_executor`` / ``Executor.step_hlo``) is recorded alongside
-as a diagnostic: there the params enter the jit as closure constants, so
-on the tiny CI models XLA folds the exact ruleset's weight/activation
-gathers into replicated constants and under-reports its traffic (the
-recorded numbers show exactly that, which is why they don't bear the
-gate). The ``serve_sharded`` benchmark records both audits for both
+as a diagnostic. Its recorded numbers date from when the params entered
+that jit as closure constants, which let XLA fold the exact ruleset's
+weight/activation gathers on the tiny CI models and under-report its
+traffic; the params are now step arguments, and the diagnostic has not
+been re-recorded. The ``serve_sharded`` benchmark records both audits for both
 rulesets in BENCH_serve.json and ``benchmarks.run --scenario sharded``
 gates the forward-audit ratio (throughput must cut collective bytes
 >= 2x vs exact on tp4 and bound all-reduces at <= 2 per layer).
@@ -117,9 +117,7 @@ def forward_hlo(params, cfg, mesh, ruleset: str, *, batch: int = 2,
                 width: int = 5) -> str:
     """Compiled HLO of the decode-window forward with the params as
     EXPLICIT jit arguments placed by the ruleset's ``param_specs`` — the
-    collective pattern a deployment with resident sharded weights pays
-    (closure-constant params would let XLA fold the exact ruleset's
-    gathers away; see module docstring)."""
+    collective pattern a deployment with resident sharded weights pays."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops as _ops
@@ -162,9 +160,9 @@ def audit_forward(params, cfg, mesh, ruleset: str, **kw) -> Dict:
 def audit_executor(ex, *, tree: bool = False,
                    any_sampled: bool = False) -> Dict:
     """Collective stats of one executor's fused decode step — DIAGNOSTIC
-    only (closure-constant params let XLA fold exact's gathers, and the
-    step contains several loops — draft scan, layer scans of two models —
-    so static instruction counts are not scaled to executions)."""
+    only (the step contains several loops — draft scan, layer scans of
+    two models — so static instruction counts are not scaled to
+    executions)."""
     stats = collective_stats(ex.step_hlo(tree=tree, any_sampled=any_sampled))
     n_layers = max(1, ex.tc.num_layers)
     stats["n_layers"] = n_layers
