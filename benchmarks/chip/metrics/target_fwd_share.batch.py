@@ -1,0 +1,13 @@
+"""Verification: device self time of the ops under the program's
+``target_forward`` scope over the device's busy time in the traced window,
+in %."""
+
+
+def read(run):
+    t = run.trace
+    if not t or not t["busy_s"]:
+        return None
+    scopes = dict(t.get("device_scopes", ()))
+    if "target_forward" not in scopes:
+        return None
+    return 100.0 * scopes["target_forward"] / t["busy_s"]

@@ -49,7 +49,6 @@ layout (the seeded-determinism tests in tests/test_sampled_tree.py).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -61,6 +60,7 @@ from ..models.attention import (TreeAttnInfo, paged_flat_index,
                                 resolve_kv_dtype)
 from ..models.config import (ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA, SSM,
                              ModelConfig, scan_plan)
+from ..serving import spans
 from . import acceptance
 
 # re-exported: the flat T>0 acceptance rule lives in core/acceptance.py now
@@ -537,8 +537,6 @@ class SpecStats:
     mean_accepted: float      # mean committed tokens per iteration (a+1)
     round_hist: Any = None    # [max_b] — accepts per sibling rank (tree:
     #                           multi-round rounds / top-k ranks; chain: [1])
-    host_overhead_p50_ms: float = 0.0   # wall time between one iteration's
-    host_overhead_p95_ms: float = 0.0   # blocking reads and the next dispatch
     # sharded serving only (tools/comm_audit.py, DESIGN.md §13): per-step
     # collective op counts and byte volumes of the compiled fused step —
     # {"all-reduce": n, ...} / total bytes moved. None off-mesh.
@@ -621,6 +619,12 @@ class SpecDecoder:
         self._jit_cache: Dict[str, Any] = {}
 
     @property
+    def verify_width(self) -> int:
+        """Tokens in the target's verify window: K + 1 flat, the bank's
+        widest template for a tree (the window is batch-wide)."""
+        return self.tree.max_slots if self.tree is not None else self.k + 1
+
+    @property
     def window_slack(self) -> int:
         """Positions a step may touch beyond the committed count: the 2K
         draft mask window vs the verify window (K+1 flat, the bank's widest
@@ -630,8 +634,7 @@ class SpecDecoder:
         cover the chunked AR step's window: its decode rows carry
         ``prefill_chunk - 1`` pad slots whose KV writes land past the
         committed count and are re-covered next step."""
-        verify = self.tree.max_slots if self.tree is not None else self.k + 1
-        slack = max(2 * self.k, verify)
+        slack = max(2 * self.k, self.verify_width)
         if self.dp is None:
             slack = max(slack, self.prefill_chunk)
         return slack + 2
@@ -645,8 +648,7 @@ class SpecDecoder:
         engines have no draft forward and use ``prefill_chunk``."""
         if self.dp is None:
             return self.prefill_chunk
-        verify = self.tree.max_slots if self.tree is not None else self.k + 1
-        return min(2 * self.k, verify)
+        return min(2 * self.k, self.verify_width)
 
     def row_slack(self, tmpl_idx: int) -> int:
         """Window slack for ONE request pinned to bank template
@@ -735,19 +737,21 @@ class SpecDecoder:
                 # _build_spec_step)
                 next_keys = jnp.where(prefilling[:, None], state.rngs,
                                       next_keys)
-            logits, tcache, _ = self._target_forward(
-                params, toks, state.tcache, cp, state.tables)
-            nxt = _pick_next(logits[:, 0], temp, use)
-            gen2 = jax.vmap(
-                lambda g, t, p: jax.lax.dynamic_update_slice(g, t[None], (p,))
-            )(gen, nxt, n)
-            frozen = (done | prefilling) if chunked else done
-            gen = jnp.where(frozen[:, None], gen, gen2)
-            n = jnp.where(frozen, n, n + 1)
-            return dataclasses.replace(
-                state, gen=gen, n=n, tcache=tcache, rngs=next_keys,
-                pf_pos=(state.pf_pos if not chunked else
-                        jnp.where(prefilling, pf + cl, state.pf_pos)))
+            with jax.named_scope(spans.TARGET):
+                logits, tcache, _ = self._target_forward(
+                    params, toks, state.tcache, cp, state.tables)
+            with jax.named_scope(spans.ACCEPT):
+                nxt = _pick_next(logits[:, 0], temp, use)
+            with jax.named_scope(spans.COMMIT):
+                gen2 = jax.vmap(lambda g, t, p: jax.lax.dynamic_update_slice(
+                    g, t[None], (p,)))(gen, nxt, n)
+                frozen = (done | prefilling) if chunked else done
+                gen = jnp.where(frozen[:, None], gen, gen2)
+                n = jnp.where(frozen, n, n + 1)
+                return dataclasses.replace(
+                    state, gen=gen, n=n, tcache=tcache, rngs=next_keys,
+                    pf_pos=(state.pf_pos if not chunked else
+                            jnp.where(prefilling, pf + cl, state.pf_pos)))
         return step
 
     def init_state(self, prompt: Array, gen_len: int,
@@ -941,92 +945,98 @@ class SpecDecoder:
                 if not greedy_only:
                     next_keys = jnp.where(prefilling[:, None], state.rngs,
                                           next_keys)
-            props, scaled_q, dcache, n_draft = propose(params, gen, n, m,
-                                                       dcache, tables, temp,
-                                                       dkeys, pfinfo)
+            with jax.named_scope(spans.DRAFT):
+                props, scaled_q, dcache, n_draft = propose(
+                    params, gen, n, m, dcache, tables, temp, dkeys, pfinfo)
 
-            # verify window: [last committed, d_1..d_K]
-            last = jnp.take_along_axis(gen, (n - 1)[:, None], axis=1)
-            vin = jnp.concatenate([last.astype(jnp.int32), props], axis=1)
-            vpos = n - 1
-            if chunked:
-                vin = jnp.where(prefilling[:, None],
-                                _chunk_window(gen, pf, cl, k + 1), vin)
-                vpos = jnp.where(prefilling, pf, vpos)
-            logits, tcache_new, _ = self._target_forward(
-                params, vin, tcache, vpos, tables, collect_ssm=t_has_ssm)
+            with jax.named_scope(spans.TARGET):
+                # verify window: [last committed, d_1..d_K]
+                last = jnp.take_along_axis(gen, (n - 1)[:, None], axis=1)
+                vin = jnp.concatenate([last.astype(jnp.int32), props], axis=1)
+                vpos = n - 1
+                if chunked:
+                    vin = jnp.where(prefilling[:, None],
+                                    _chunk_window(gen, pf, cl, k + 1), vin)
+                    vpos = jnp.where(prefilling, pf, vpos)
+                logits, tcache_new, _ = self._target_forward(
+                    params, vin, tcache, vpos, tables, collect_ssm=t_has_ssm)
 
             # acceptance (core/acceptance.py): greedy rule for temp == 0
             # rows, Leviathan sampling for temp > 0 rows — row-selected so
             # one batch mixes both; the sampled branch (softmaxes + accept
             # draws) only executes when some row actually samples
-            a_g, acc_g, commit_g = acceptance.greedy_chain_accept(
-                logits, props)
+            with jax.named_scope(spans.ACCEPT):
+                a_g, acc_g, commit_g = acceptance.greedy_chain_accept(
+                    logits, props)
+                if greedy_only:
+                    a, accepted, commit_tok = a_g, acc_g, commit_g
+                else:
+                    def samp_accept():
+                        qprob = jax.nn.softmax(scaled_q, axis=-1)  # [B,K,V]
+                        p_full = acceptance.temp_softmax(logits, temp)
+                        return acceptance.leviathan_accept(p_full, qprob,
+                                                           props, akeys)
 
-            if greedy_only:
-                a, accepted, commit_tok = a_g, acc_g, commit_g
-            else:
-                def samp_accept():
-                    qprob = jax.nn.softmax(scaled_q, axis=-1)   # [B, K, V]
-                    p_full = acceptance.temp_softmax(logits, temp)
-                    return acceptance.leviathan_accept(p_full, qprob, props,
-                                                       akeys)
+                    a_s, acc_s, commit_s = jax.lax.cond(
+                        jnp.any(temp > 0), samp_accept,
+                        lambda: (jnp.zeros_like(a_g), jnp.zeros_like(acc_g),
+                                 jnp.zeros_like(commit_g)))
+                    sampled = temp > 0
+                    a = jnp.where(sampled, a_s, a_g)
+                    accepted = jnp.where(sampled[:, None], acc_s, acc_g)
+                    commit_tok = jnp.where(sampled, commit_s, commit_g)
 
-                a_s, acc_s, commit_s = jax.lax.cond(
-                    jnp.any(temp > 0), samp_accept,
-                    lambda: (jnp.zeros_like(a_g), jnp.zeros_like(acc_g),
-                             jnp.zeros_like(commit_g)))
-                sampled = temp > 0
-                a = jnp.where(sampled, a_s, a_g)
-                accepted = jnp.where(sampled[:, None], acc_s, acc_g)
-                commit_tok = jnp.where(sampled, commit_s, commit_g)
+            with jax.named_scope(spans.COMMIT):
+                # frozen rows commit nothing: done rows stay done, prefilling
+                # rows consumed a prompt chunk instead of a verify window
+                frozen = (done | prefilling) if chunked else done
 
-            # frozen rows commit nothing: done rows stay done, prefilling
-            # rows consumed a prompt chunk instead of a verify window
-            frozen = (done | prefilling) if chunked else done
+                # committed tokens this iteration: d_1..d_a, then commit_tok
+                j = jnp.arange(k + 1)[None, :]
+                props_ext = jnp.concatenate([props, props[:, -1:]], axis=1)
+                vec = jnp.where(j < a[:, None], props_ext,
+                                jnp.where(j == a[:, None],
+                                          commit_tok[:, None], 0))
+                # frozen rows: rewrite what's already there
+                old = jax.vmap(lambda g, p: jax.lax.dynamic_slice(
+                    g, (p,), (k + 1,)))(gen, n)
+                vec = jnp.where(frozen[:, None], old, vec)
+                gen = _row_write(gen, vec.astype(gen.dtype), n)
 
-            # committed tokens this iteration: d_1..d_a, then commit_tok
-            j = jnp.arange(k + 1)[None, :]
-            props_ext = jnp.concatenate([props, props[:, -1:]], axis=1)
-            vec = jnp.where(j < a[:, None], props_ext,
-                            jnp.where(j == a[:, None], commit_tok[:, None], 0))
-            # frozen rows: rewrite what's already there
-            old = jax.vmap(lambda g, p: jax.lax.dynamic_slice(g, (p,), (k + 1,)))(
-                gen, n)
-            vec = jnp.where(frozen[:, None], old, vec)
-            gen = _row_write(gen, vec.astype(gen.dtype), n)
+                n_commit = jnp.where(frozen, 0, a + 1)
+                new_m = jnp.where(frozen, m, n)
+                new_n = n + n_commit
 
-            n_commit = jnp.where(frozen, 0, a + 1)
-            new_m = jnp.where(frozen, m, n)
-            new_n = n + n_commit
-
-            if t_has_ssm:
-                # state after input index a (last committed token processed);
-                # prefilling rows keep the state after their chunk's last
-                # REAL token (pads excluded — DESIGN.md §3 unchanged)
-                ssm_idx = a if not chunked else jnp.where(prefilling, cl - 1,
-                                                          a)
-                tcache_new = gather_ssm_states(tc, tcache_new, ssm_idx)
-            # frozen rows keep old caches? their cache contents are untouched
-            # at positions < n and never read beyond; safe to keep new buffers.
-            acc_hist = jnp.sum(
-                jnp.where(frozen[:, None], 0, accepted), axis=0)  # [K]
-            # chain = one sibling per depth: round 0 holds every accept
-            round_hist = jnp.sum(
-                jnp.where(frozen, 0, a))[None].astype(jnp.int32)
-            # per-row accepted rank (chain: rank 0 everywhere it accepted;
-            # -1 rejected/frozen) — the adaptive tree controller's signal,
-            # shaped like the tree step's so callers share one unpacking
-            rank = jnp.where(
-                (jnp.arange(1, k + 1)[None, :] <= a[:, None])
-                & ~frozen[:, None], 0, -1).astype(jnp.int32)
-            new_state = dataclasses.replace(
-                state, gen=gen, n=new_n, m=new_m, tcache=tcache_new,
-                dcache=dcache, rngs=next_keys,
-                pf_pos=(state.pf_pos if not chunked else
-                        jnp.where(prefilling, pf + cl, state.pf_pos)))
-            return new_state, jnp.where(frozen, 0, a), acc_hist, round_hist, \
-                rank, n_draft
+                if t_has_ssm:
+                    # state after input index a (last committed token
+                    # processed); prefilling rows keep the state after their
+                    # chunk's last REAL token (pads excluded — DESIGN.md §3
+                    # unchanged)
+                    ssm_idx = (a if not chunked
+                               else jnp.where(prefilling, cl - 1, a))
+                    tcache_new = gather_ssm_states(tc, tcache_new, ssm_idx)
+                # frozen rows keep old caches? their cache contents are
+                # untouched at positions < n and never read beyond; safe to
+                # keep new buffers.
+                acc_hist = jnp.sum(
+                    jnp.where(frozen[:, None], 0, accepted), axis=0)  # [K]
+                # chain = one sibling per depth: round 0 holds every accept
+                round_hist = jnp.sum(
+                    jnp.where(frozen, 0, a))[None].astype(jnp.int32)
+                # per-row accepted rank (chain: rank 0 everywhere it
+                # accepted; -1 rejected/frozen) — the adaptive tree
+                # controller's signal, shaped like the tree step's so callers
+                # share one unpacking
+                rank = jnp.where(
+                    (jnp.arange(1, k + 1)[None, :] <= a[:, None])
+                    & ~frozen[:, None], 0, -1).astype(jnp.int32)
+                new_state = dataclasses.replace(
+                    state, gen=gen, n=new_n, m=new_m, tcache=tcache_new,
+                    dcache=dcache, rngs=next_keys,
+                    pf_pos=(state.pf_pos if not chunked else
+                            jnp.where(prefilling, pf + cl, state.pf_pos)))
+                return (new_state, jnp.where(frozen, 0, a), acc_hist,
+                        round_hist, rank, n_draft)
 
         return step
 
@@ -1117,135 +1127,148 @@ class SpecDecoder:
                     next_keys = jnp.where(prefilling[:, None], state.rngs,
                                           next_keys)
 
-            # draft: depth distributions -> per-row template tokens. One
-            # top-max_b per depth covers every template's ranks;
-            # _topk_indices and argmax share lowest-index tie-breaking, so
-            # rank 0 IS the flat path's argmax (degenerate-chain identity).
-            lg, dcache = self._pard_depth_logits(params, gen, n, m, dcache,
-                                                 tables, pfinfo)
-            topk = _topk_indices(lg, max_b)                        # [B,D,MB]
-            di = jnp.maximum(node_depth - 1, 0)
-            per_node = jnp.take_along_axis(
-                topk, di[:, :, None], axis=1)                      # [B,N,MB]
-            props_g = jnp.take_along_axis(
-                per_node, choice[:, 1:, None], axis=2)[..., 0]     # [B, N]
-            if greedy_only:
-                props = props_g
-            else:
-                # sampled rows: i.i.d. candidates per node (multi-round
-                # acceptance requires sibling draws from q, not top-k); the
-                # per-node draws only execute when some row actually samples
-                scaled = acceptance.scale_logits(lg, temp)         # [B,D,V]
-                any_sampled = jnp.any(temp > 0)
-                props_s = jax.lax.cond(
-                    any_sampled,
-                    lambda: acceptance.sample_tree_props_rows(
-                        scaled, node_depth, dkeys),
-                    lambda: props_g)
-                sampled = temp > 0
-                props = jnp.where(sampled[:, None], props_s, props_g)
+            with jax.named_scope(spans.DRAFT):
+                # draft: depth distributions -> per-row template tokens. One
+                # top-max_b per depth covers every template's ranks;
+                # _topk_indices and argmax share lowest-index tie-breaking, so
+                # rank 0 IS the flat path's argmax (degenerate-chain identity).
+                lg, dcache = self._pard_depth_logits(params, gen, n, m, dcache,
+                                                     tables, pfinfo)
+                topk = _topk_indices(lg, max_b)                    # [B,D,MB]
+                di = jnp.maximum(node_depth - 1, 0)
+                per_node = jnp.take_along_axis(
+                    topk, di[:, :, None], axis=1)                  # [B,N,MB]
+                props_g = jnp.take_along_axis(
+                    per_node, choice[:, 1:, None], axis=2)[..., 0]  # [B, N]
+                if greedy_only:
+                    props = props_g
+                else:
+                    # sampled rows: i.i.d. candidates per node (multi-round
+                    # acceptance requires sibling draws from q, not top-k);
+                    # the per-node draws only execute when some row actually
+                    # samples
+                    scaled = acceptance.scale_logits(lg, temp)     # [B,D,V]
+                    any_sampled = jnp.any(temp > 0)
+                    props_s = jax.lax.cond(
+                        any_sampled,
+                        lambda: acceptance.sample_tree_props_rows(
+                            scaled, node_depth, dkeys),
+                        lambda: props_g)
+                    sampled = temp > 0
+                    props = jnp.where(sampled[:, None], props_s, props_g)
 
-            # verify: one target forward over the packed tree; per-row
-            # win_len bounds each row's window to its own template
-            last = jnp.take_along_axis(gen, (n - 1)[:, None], axis=1)
-            vin = jnp.concatenate([last.astype(jnp.int32), props], axis=1)
-            positions = (n - 1)[:, None] + depth
-            win_start, win_anc, win_len = n - 1, anc, nslots
-            if chunked:
-                # prefilling rows: a cl-token causal chunk through the same
-                # packed window (pads past cl are invisible and re-covered).
-                # Slice at the chunk width cw — guaranteed inside the gen
-                # buffer by the slack validation — and pad to the window:
-                # slicing at s (up to 32) could clamp near max_len and
-                # silently shift the chunk.
-                chunk = _chunk_window(gen, pf, cl, cw)
-                chunk = jnp.pad(chunk, ((0, 0), (0, s - cw)))
-                vin = jnp.where(prefilling[:, None], chunk, vin)
-                positions = jnp.where(
-                    prefilling[:, None],
-                    pf[:, None] + jnp.arange(s)[None, :], positions)
-                win_start = jnp.where(prefilling, pf, win_start)
-                win_anc = jnp.where(prefilling[:, None], chain_anc[None, :],
-                                    win_anc)
-                win_len = jnp.where(prefilling, cl, win_len)
-            tinfo = TreeAttnInfo(win_start=win_start, anc=win_anc,
-                                 win_len=win_len)
-            logits, tcache_new, _ = self._target_forward(
-                params, vin, tcache, win_start, tables, positions=positions,
-                tree_info=tinfo)
+            with jax.named_scope(spans.TARGET):
+                # verify: one target forward over the packed tree; per-row
+                # win_len bounds each row's window to its own template
+                last = jnp.take_along_axis(gen, (n - 1)[:, None], axis=1)
+                vin = jnp.concatenate([last.astype(jnp.int32), props], axis=1)
+                positions = (n - 1)[:, None] + depth
+                win_start, win_anc, win_len = n - 1, anc, nslots
+                if chunked:
+                    # prefilling rows: a cl-token causal chunk through the
+                    # same packed window (pads past cl are invisible and
+                    # re-covered). Slice at the chunk width cw — guaranteed
+                    # inside the gen buffer by the slack validation — and pad
+                    # to the window: slicing at s (up to 32) could clamp near
+                    # max_len and silently shift the chunk.
+                    chunk = _chunk_window(gen, pf, cl, cw)
+                    chunk = jnp.pad(chunk, ((0, 0), (0, s - cw)))
+                    vin = jnp.where(prefilling[:, None], chunk, vin)
+                    positions = jnp.where(
+                        prefilling[:, None],
+                        pf[:, None] + jnp.arange(s)[None, :], positions)
+                    win_start = jnp.where(prefilling, pf, win_start)
+                    win_anc = jnp.where(prefilling[:, None],
+                                        chain_anc[None, :], win_anc)
+                    win_len = jnp.where(prefilling, cl, win_len)
+                tinfo = TreeAttnInfo(win_start=win_start, anc=win_anc,
+                                     win_len=win_len)
+                logits, tcache_new, _ = self._target_forward(
+                    params, vin, tcache, win_start, tables,
+                    positions=positions, tree_info=tinfo)
 
-            # acceptance (core/acceptance.py), row-selected greedy/sampled;
-            # the multi-round machinery only executes when a row samples
-            a_g, tok_g, slot_g, commit_g, rank_g = \
-                acceptance.greedy_tree_accept_rows(
-                    logits, props, parent, depth, choice, anc, nslots, d)
+            with jax.named_scope(spans.ACCEPT):
+                # acceptance (core/acceptance.py), row-selected greedy/sampled;
+                # the multi-round machinery only executes when a row samples
+                a_g, tok_g, slot_g, commit_g, rank_g = \
+                    acceptance.greedy_tree_accept_rows(
+                        logits, props, parent, depth, choice, anc, nslots, d)
 
-            if greedy_only:
-                a, tok_depth, src_slot = a_g, tok_g, slot_g
-                commit_tok, rank = commit_g, rank_g
-            else:
-                def samp_accept():
-                    p_full = acceptance.temp_softmax(logits, temp)  # [B,S,V]
-                    q_depth = jax.nn.softmax(scaled, axis=-1)       # [B,D,V]
-                    return acceptance.sampled_tree_accept_rows(
-                        p_full, q_depth, props, cmap, akeys, d, max_b)
+                if greedy_only:
+                    a, tok_depth, src_slot = a_g, tok_g, slot_g
+                    commit_tok, rank = commit_g, rank_g
+                else:
+                    def samp_accept():
+                        # [B, S, V] and [B, D, V]
+                        p_full = acceptance.temp_softmax(logits, temp)
+                        q_depth = jax.nn.softmax(scaled, axis=-1)
+                        return acceptance.sampled_tree_accept_rows(
+                            p_full, q_depth, props, cmap, akeys, d, max_b)
 
-                a_s, tok_s, slot_s, commit_s, rank_s = jax.lax.cond(
-                    any_sampled, samp_accept,
-                    lambda: (jnp.zeros_like(a_g), jnp.zeros_like(tok_g),
-                             jnp.zeros_like(slot_g), jnp.zeros_like(commit_g),
-                             jnp.full_like(rank_g, -1)))
-                a = jnp.where(sampled, a_s, a_g)
-                tok_depth = jnp.where(sampled[:, None], tok_s, tok_g)
-                src_slot = jnp.where(sampled[:, None], slot_s, slot_g)
-                commit_tok = jnp.where(sampled, commit_s, commit_g)
-                rank = jnp.where(sampled[:, None], rank_s, rank_g)  # [B, D]
+                    a_s, tok_s, slot_s, commit_s, rank_s = jax.lax.cond(
+                        any_sampled, samp_accept,
+                        lambda: (jnp.zeros_like(a_g), jnp.zeros_like(tok_g),
+                                 jnp.zeros_like(slot_g),
+                                 jnp.zeros_like(commit_g),
+                                 jnp.full_like(rank_g, -1)))
+                    a = jnp.where(sampled, a_s, a_g)
+                    tok_depth = jnp.where(sampled[:, None], tok_s, tok_g)
+                    src_slot = jnp.where(sampled[:, None], slot_s, slot_g)
+                    commit_tok = jnp.where(sampled, commit_s, commit_g)
+                    rank = jnp.where(sampled[:, None], rank_s,
+                                     rank_g)                       # [B, D]
 
-            # frozen rows commit nothing: done rows stay done, prefilling
-            # rows consumed a prompt chunk instead of a verify window
-            frozen = (done | prefilling) if chunked else done
+            with jax.named_scope(spans.COMMIT):
+                # frozen rows commit nothing: done rows stay done, prefilling
+                # rows consumed a prompt chunk instead of a verify window
+                frozen = (done | prefilling) if chunked else done
 
-            dflt = jnp.arange(1, d + 1, dtype=jnp.int32)[None, :]
-            # rejected depths and frozen rows: identity copy (src == dst)
-            src_slot = jnp.where((src_slot > 0) & ~frozen[:, None],
-                                 src_slot, dflt)
+                dflt = jnp.arange(1, d + 1, dtype=jnp.int32)[None, :]
+                # rejected depths and frozen rows: identity copy (src == dst)
+                src_slot = jnp.where((src_slot > 0) & ~frozen[:, None],
+                                     src_slot, dflt)
 
-            # committed tokens this iteration: path d_1..d_a, then commit_tok
-            j = jnp.arange(d + 1)[None, :]
-            tok_ext = jnp.concatenate([tok_depth, tok_depth[:, -1:]], axis=1)
-            vec = jnp.where(j < a[:, None], tok_ext,
-                            jnp.where(j == a[:, None], commit_tok[:, None], 0))
-            old = jax.vmap(lambda g, p: jax.lax.dynamic_slice(
-                g, (p,), (d + 1,)))(gen, n)
-            vec = jnp.where(frozen[:, None], old, vec)
-            gen = _row_write(gen, vec.astype(gen.dtype), n)
+                # committed tokens this iteration: path d_1..d_a, then
+                # commit_tok
+                j = jnp.arange(d + 1)[None, :]
+                tok_ext = jnp.concatenate([tok_depth, tok_depth[:, -1:]],
+                                          axis=1)
+                vec = jnp.where(j < a[:, None], tok_ext,
+                                jnp.where(j == a[:, None],
+                                          commit_tok[:, None], 0))
+                old = jax.vmap(lambda g, p: jax.lax.dynamic_slice(
+                    g, (p,), (d + 1,)))(gen, n)
+                vec = jnp.where(frozen[:, None], old, vec)
+                gen = _row_write(gen, vec.astype(gen.dtype), n)
 
-            # only the winning path's KV survives at committed positions
-            src_pos = (n - 1)[:, None] + src_slot                  # [B, D]
-            tcache_new = compact_tree_caches(
-                tc, tcache_new, src_pos, n, d, tables, self.kv_block_size)
+            with jax.named_scope(spans.TREE_COMPACT):
+                # only the winning path's KV survives at committed positions
+                src_pos = (n - 1)[:, None] + src_slot                  # [B, D]
+                tcache_new = compact_tree_caches(
+                    tc, tcache_new, src_pos, n, d, tables, self.kv_block_size)
 
-            n_commit = jnp.where(frozen, 0, a + 1)
-            new_m = jnp.where(frozen, m, n)
-            new_n = n + n_commit
-            hist = jnp.sum(
-                jnp.where(frozen[:, None], 0,
-                          (a[:, None] > jnp.arange(d)[None, :])
-                          .astype(jnp.int32)), axis=0)             # [D]
-            # per-round accept counts: which sibling rank won at each
-            # accepted depth (rank == -1 where the depth rejected)
-            valid = (rank >= 0) & ~frozen[:, None]                 # [B, D]
-            round_hist = jnp.sum(
-                (rank[:, :, None] == jnp.arange(max_b)[None, None, :])
-                & valid[:, :, None], axis=(0, 1)).astype(jnp.int32)
-            rank = jnp.where(frozen[:, None], -1, rank)
-            new_state = dataclasses.replace(
-                state, gen=gen, n=new_n, m=new_m, tcache=tcache_new,
-                dcache=dcache, rngs=next_keys,
-                pf_pos=(state.pf_pos if not chunked else
-                        jnp.where(prefilling, pf + cl, state.pf_pos)))
-            return new_state, jnp.where(frozen, 0, a), hist, round_hist, \
-                rank, 1
+            with jax.named_scope(spans.COMMIT):
+                n_commit = jnp.where(frozen, 0, a + 1)
+                new_m = jnp.where(frozen, m, n)
+                new_n = n + n_commit
+                hist = jnp.sum(
+                    jnp.where(frozen[:, None], 0,
+                              (a[:, None] > jnp.arange(d)[None, :])
+                              .astype(jnp.int32)), axis=0)             # [D]
+                # per-round accept counts: which sibling rank won at each
+                # accepted depth (rank == -1 where the depth rejected)
+                valid = (rank >= 0) & ~frozen[:, None]                 # [B, D]
+                round_hist = jnp.sum(
+                    (rank[:, :, None] == jnp.arange(max_b)[None, None, :])
+                    & valid[:, :, None], axis=(0, 1)).astype(jnp.int32)
+                rank = jnp.where(frozen[:, None], -1, rank)
+                new_state = dataclasses.replace(
+                    state, gen=gen, n=new_n, m=new_m, tcache=tcache_new,
+                    dcache=dcache, rngs=next_keys,
+                    pf_pos=(state.pf_pos if not chunked else
+                            jnp.where(prefilling, pf + cl, state.pf_pos)))
+                return new_state, jnp.where(frozen, 0, a), hist, round_hist, \
+                    rank, 1
 
         return step
 
@@ -1303,13 +1326,8 @@ class SpecDecoder:
         round_hist = None
         acc_total, live_iters = 0, 0
         target_n = p + max_new
-        host_overhead_ms = []       # blocking-reads-done -> next dispatch
-        t_reads_done = None
         while True:
             live = int(jnp.sum(~state.done))
-            if t_reads_done is not None:
-                host_overhead_ms.append(
-                    (time.perf_counter() - t_reads_done) * 1e3)
             state, a, hist, rhist, _rank, n_draft = step(params, state)
             iters += 1
             live_iters += live
@@ -1319,9 +1337,7 @@ class SpecDecoder:
             round_hist = rhist if round_hist is None else round_hist + rhist
             acc_total += int(jnp.sum(a))
             state = dataclasses.replace(state, done=state.n >= target_n)
-            stop = bool(jnp.all(state.done)) or iters > max_new + 2
-            t_reads_done = time.perf_counter()
-            if stop:
+            if bool(jnp.all(state.done)) or iters > max_new + 2:
                 break
 
         n, gen = state.n, state.gen
@@ -1336,9 +1352,5 @@ class SpecDecoder:
             acceptance_rate=acc_total / (live_iters * k),
             mean_accepted=acc_total / live_iters + 1.0,
             round_hist=jax.device_get(round_hist),
-            host_overhead_p50_ms=(float(np.percentile(host_overhead_ms, 50))
-                                  if host_overhead_ms else 0.0),
-            host_overhead_p95_ms=(float(np.percentile(host_overhead_ms, 95))
-                                  if host_overhead_ms else 0.0),
         )
         return tokens, stats

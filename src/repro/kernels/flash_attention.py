@@ -121,5 +121,6 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)))
     return out.transpose(0, 2, 1, 3)

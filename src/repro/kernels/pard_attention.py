@@ -114,6 +114,7 @@ def pard_attention(q, k, v, segment, base, *, scale=None, softcap=0.0,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="pard_attention",
     )(seg[:, :, None], bas[:, :, None], seg[:, None, :], bas[:, None, :],
       *(x.transpose(0, 2, 1, 3) for x in (q, k, v)))
     return out.transpose(0, 2, 1, 3)

@@ -122,6 +122,7 @@ def ssd_chunked_kernel(x, dt, A, B, C, init_state=None, *, chunk=128,
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="ssd",
     )(A.astype(jnp.float32), x.transpose(0, 2, 1, 3),
       dt.transpose(0, 2, 1)[..., None], B, C, init_state.astype(jnp.float32))
     return y.transpose(0, 2, 1, 3), sf

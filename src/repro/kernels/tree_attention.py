@@ -143,6 +143,7 @@ def tree_attention_paged(q, k_pages, v_pages, block_tables, kv_len, q_pos,
             scratch_shapes=attention_scratch(hkv, rows, d)),
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
         interpret=interpret,
+        name="paged_tree_attention",
     )(*args)
     return ungroup_output(out, tq)
 

@@ -59,7 +59,7 @@ from typing import Optional
 
 from ..core.spec_decode import SpecDecoder
 from ..models.config import ModelConfig
-from . import kv_pool
+from . import kv_pool, spans
 from .config import EngineConfig, SamplingParams  # noqa: F401  (re-export)
 from .executor import Executor
 from .scheduler import (Completion, Request, Scheduler,  # noqa: F401
@@ -204,7 +204,6 @@ class Engine:
         def pending() -> int:
             return sum(len(q) for q in inflight.values())
 
-        sched._harvest_done_t = None   # don't count inter-run wall time
         while sched.has_work() or pending():
             dispatched = False
             if sched.has_work() and sched.stats["steps"] < max_steps:
@@ -221,11 +220,13 @@ class Engine:
                         f"request {req.rid} (prompt={len(req.prompt)}, "
                         f"max_new={req.max_new}) needs more KV blocks than "
                         f"the pool holds; raise kv_num_blocks or max_len")
-                for rep in sched.replicas:
-                    rep.ex.sync_tables(rep.alloc)
-                if self.paged:
-                    self.peak_kv_bytes_in_use = max(
-                        self.peak_kv_bytes_in_use, self.kv_bytes_in_use())
+                with spans.phase(sched.stats, "sync_tables"):
+                    for rep in sched.replicas:
+                        rep.ex.sync_tables(rep.alloc)
+                    if self.paged:
+                        self.peak_kv_bytes_in_use = max(
+                            self.peak_kv_bytes_in_use,
+                            self.kv_bytes_in_use())
                 for rep in sched.replicas:
                     if rep.has_live():
                         inflight[rep.rep].append(sched.dispatch(rep.rep))
@@ -235,6 +236,9 @@ class Engine:
                     sched.process(q.popleft())
             if not dispatched and not pending():
                 break                  # step budget exhausted, fully drained
+        if not sched.has_work():
+            # the wait for new requests is not host overhead of a step
+            sched._harvest_done_ns = None
         return sched.completions
 
     def run_pipelined(self, max_steps: int = 100000):

@@ -43,7 +43,7 @@ from ..core.spec_decode import DecodeState, SpecDecoder
 from ..models import init_caches
 from ..models.attention import resolve_kv_dtype
 from ..models.config import SSM, ModelConfig, scan_plan
-from . import kv_pool
+from . import kv_pool, spans
 
 # "no staged commit limit" sentinel: n never reaches int32 max, so the
 # device-side freeze ``done |= n >= limits`` is a no-op for these rows
@@ -73,6 +73,9 @@ class StepHandle:
     # scheduler-stamped: which engine replica dispatched this step, so
     # process() harvests and attributes it on the right replica (§12)
     replica: int = 0
+    # scheduler-stamped: the step number, so a harvest's span names the
+    # dispatch it belongs to (serving/spans.py)
+    step: int = -1
 
 
 @dataclasses.dataclass
@@ -347,19 +350,21 @@ class Executor:
         inner = self._build(variant, greedy_only)
 
         def fused(params, state, retire, tree_sel, limits):
-            # staged retirement + the device-side limit freeze: a row whose
-            # committed count reached its limit is frozen even if the host
-            # has not harvested that result yet (the pipelined loop's
-            # one-step-stale horizon must not let it overrun its blocks)
-            done = state.done | retire | (state.n >= limits)
-            # temp resets with retirement (see retire_row)
-            temp = jnp.where(retire, 0.0, state.temp)
-            tree_idx = state.tree_idx
-            if apply_tree and tree_idx is not None:
-                tree_idx = tree_sel
-            st = dataclasses.replace(state, done=done, temp=temp,
-                                     tree_idx=tree_idx)
-            live = ~(st.done | (st.pf_pos < st.pf_len))
+            with jax.named_scope(spans.STAGED):
+                # staged retirement + the device-side limit freeze: a row
+                # whose committed count reached its limit is frozen even if
+                # the host has not harvested that result yet (the pipelined
+                # loop's one-step-stale horizon must not let it overrun its
+                # blocks)
+                done = state.done | retire | (state.n >= limits)
+                # temp resets with retirement (see retire_row)
+                temp = jnp.where(retire, 0.0, state.temp)
+                tree_idx = state.tree_idx
+                if apply_tree and tree_idx is not None:
+                    tree_idx = tree_sel
+                st = dataclasses.replace(state, done=done, temp=temp,
+                                         tree_idx=tree_idx)
+                live = ~(st.done | (st.pf_pos < st.pf_len))
             new_state, a, _hist, rhist, rank, _nd = inner(params, st)
             return new_state, a, rank, rhist, live, new_state.n, new_state.gen
         return fused

@@ -19,6 +19,9 @@ The ``Scheduler`` owns every decision that does NOT touch the device:
     inter-commit latency percentiles, and per-step host overhead (the
     wall time from harvest-complete to the next dispatch), recorded on
     every ``Completion`` and summarised by ``latency_summary``;
+  * the loop's host phases as profiler spans and nanosecond counters in
+    ``stats`` (serving/spans.py), and the paged-KV page counts of each
+    step (``kv_pages_live`` / ``kv_pages_grid``);
   * the adaptive tree-template controller (``TreeController``) and the
     between-windows reshaping cadence.
 
@@ -62,7 +65,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..core.spec_decode import SpecDecoder, TemplateBank
-from . import kv_pool
+from . import kv_pool, spans
 from .config import SamplingParams
 from .executor import NO_LIMIT, Executor, StepHandle, StepResult
 
@@ -331,9 +334,12 @@ class Scheduler:
 
         self.queue: deque[Request] = deque()
         self.completions: List[Completion] = []
-        # per-step host overhead: harvest-complete -> next dispatch, ms
+        # per-step host overhead: harvest-complete -> next dispatch, ms.
+        # The harvest-complete stamp (perf_counter_ns) survives between
+        # ``Engine.run`` calls, so a loop run one step a call still records
+        # a sample per step; the engine clears it when the loop drains.
         self.host_overhead_ms: List[float] = []
-        self._harvest_done_t: Optional[float] = None
+        self._harvest_done_ns: Optional[int] = None
 
         self._next_rid = 0
         self._submit_t_of: Dict[int, float] = {}   # rid -> submit wall time
@@ -342,7 +348,12 @@ class Scheduler:
             draft_forwards=0, target_forwards=0, round_hist=None,
             prefill_chunks=0, prefill_tokens=0,
             prefix_lookup_blocks=0, prefix_hit_blocks=0,
-            replica_steps=[0] * self.dp, affinity_routed=0)
+            replica_steps=[0] * self.dp, affinity_routed=0,
+            # host phases of the loop (serving/spans.py), integer ns
+            **spans.counters(),
+            # paged KV: pages the occupied rows' contexts cover, and the
+            # pages the attention kernels' grid steps over (see dispatch)
+            kv_pages_live=0, kv_pages_grid=0)
         if bank is not None:
             self.stats["tree_hist"] = np.zeros(len(bank), np.int64)
             self.stats["tree_switches"] = 0
@@ -568,26 +579,28 @@ class Scheduler:
         across replicas by ``_route_order`` (prefix-affinity, then
         least-loaded; with ``dp=1`` the order is trivially [replica 0] and
         this is the historical single-engine admission loop)."""
-        admitted = 0
-        progress = True
-        while progress and self.queue:
-            progress = False
-            window = min(len(self.queue), self.admit_window)
-            for qi in range(window):
-                req = self.queue[qi]
-                for rep, hit_len in self._route_order(req):
-                    slot = rep.first_free_slot()
-                    if slot is None:
-                        continue       # replica full: fall through
-                    if self._try_admit(rep, slot, req):
-                        del self.queue[qi]
-                        admitted += 1
-                        if hit_len > 0:
-                            self.stats["affinity_routed"] += 1
-                        progress = True
-                        break
-                if progress:
-                    break              # re-scan from the queue head
+        with spans.phase(self.stats, "admit") as ph:
+            admitted = 0
+            progress = True
+            while progress and self.queue:
+                progress = False
+                window = min(len(self.queue), self.admit_window)
+                for qi in range(window):
+                    req = self.queue[qi]
+                    for rep, hit_len in self._route_order(req):
+                        slot = rep.first_free_slot()
+                        if slot is None:
+                            continue       # replica full: fall through
+                        if self._try_admit(rep, slot, req):
+                            del self.queue[qi]
+                            admitted += 1
+                            if hit_len > 0:
+                                self.stats["affinity_routed"] += 1
+                            progress = True
+                            break
+                    if progress:
+                        break              # re-scan from the queue head
+            ph.set(admitted=admitted)
         return admitted
 
     # ----------------------------------------------------------- stepping
@@ -599,55 +612,89 @@ class Scheduler:
         step; per-slot commit limits ride along so a row that filled its
         budget in a still-unharvested step freezes itself. All
         dispatch-deterministic accounting advances immediately: the step
-        counters, and the prefill cursor mirrors + computed-block flags
-        (the chunk schedule is a pure function of the cursor, so admission
-        decisions made while this step is in flight see exact cursors)."""
-        rep = self.replicas[replica]
-        occ = rep.occupied_mask()
-        limits = np.where(occ, rep.slot_limit, NO_LIMIT).astype(np.int64)
-        tree_sel = (rep.slot_tree.astype(np.int32, copy=True)
-                    if self.bank is not None else None)
-        now = time.perf_counter()
-        if self._harvest_done_t is not None:
-            self.host_overhead_ms.append((now - self._harvest_done_t) * 1e3)
-            self._harvest_done_t = None
-        # greedy-specialization hint: retired slots' device temps are
-        # zeroed by THIS dispatch's staged mutations before the inner step
-        # runs, so occupied host mirrors are exactly the rows whose temp
-        # survives — when none samples, the executor picks the compiled
-        # variant with the sampled machinery removed (token-identical)
-        any_sampled = any(
-            s is not None
-            and (self.temperature if s.temperature is None
-                 else s.temperature) > 0
-            for s in rep.slots)
-        handle = rep.ex.dispatch(
-            retire=rep.pending_retire, tree_sel=tree_sel, limits=limits,
-            any_prefilling=rep.prefilling_count() > 0,
-            any_sampled=any_sampled)
-        handle.rids = np.asarray(
-            [-1 if s is None else s.rid for s in rep.slots], np.int64)
-        handle.replica = replica
-        rep.pending_retire = np.zeros(self.max_batch, bool)
+        counters, the paged-KV page counts, and the prefill cursor
+        mirrors + computed-block flags (the chunk schedule is a pure
+        function of the cursor, so admission decisions made while this
+        step is in flight see exact cursors)."""
+        step = self.stats["steps"]
+        with spans.phase(self.stats, "dispatch", step=step) as ph:
+            if self._harvest_done_ns is not None:
+                self.host_overhead_ms.append(
+                    (ph.t0 - self._harvest_done_ns) / 1e6)
+                self._harvest_done_ns = None
+            rep = self.replicas[replica]
+            occ = rep.occupied_mask()
+            limits = np.where(occ, rep.slot_limit, NO_LIMIT).astype(np.int64)
+            tree_sel = (rep.slot_tree.astype(np.int32, copy=True)
+                        if self.bank is not None else None)
+            # greedy-specialization hint: retired slots' device temps are
+            # zeroed by THIS dispatch's staged mutations before the inner
+            # step runs, so occupied host mirrors are exactly the rows whose
+            # temp survives — when none samples, the executor picks the
+            # compiled variant with the sampled machinery removed
+            # (token-identical)
+            any_sampled = any(
+                s is not None
+                and (self.temperature if s.temperature is None
+                     else s.temperature) > 0
+                for s in rep.slots)
+            any_prefilling = rep.prefilling_count() > 0
+            handle = rep.ex.dispatch(
+                retire=rep.pending_retire, tree_sel=tree_sel, limits=limits,
+                any_prefilling=any_prefilling, any_sampled=any_sampled)
+            handle.rids = np.asarray(
+                [-1 if s is None else s.rid for s in rep.slots], np.int64)
+            handle.replica = replica
+            handle.step = step
+            rep.pending_retire = np.zeros(self.max_batch, bool)
 
-        self.stats["steps"] += 1
-        self.stats["replica_steps"][replica] += 1
-        self.stats["target_forwards"] += 1
-        self.stats["draft_forwards"] += handle.n_draft
-        # advance the host prefill mirrors in lockstep with the device
-        for slot in np.nonzero(occ)[0]:
-            pf, pfl = rep.slot_pf[slot], rep.slot_pf_len[slot]
-            if pf < pfl:
-                cl = int(min(self.chunk, pfl - pf))
-                rep.slot_pf[slot] = pf + cl
-                self.stats["prefill_chunks"] += 1
-                self.stats["prefill_tokens"] += cl
-                if self.paged and self.prefix_cache:
-                    # the blocks become readable once THIS step completes
-                    # on device — before any later-dispatched step could
-                    # read them through a prefix match (sequential stream)
-                    rep.alloc.mark_computed(slot, int(rep.slot_pf[slot]))
+            self.stats["steps"] += 1
+            self.stats["replica_steps"][replica] += 1
+            self.stats["target_forwards"] += 1
+            self.stats["draft_forwards"] += handle.n_draft
+            if self.paged:
+                self._count_kv_pages(rep, occ, any_prefilling)
+            # advance the host prefill mirrors in lockstep with the device
+            for slot in np.nonzero(occ)[0]:
+                pf, pfl = rep.slot_pf[slot], rep.slot_pf_len[slot]
+                if pf < pfl:
+                    cl = int(min(self.chunk, pfl - pf))
+                    rep.slot_pf[slot] = pf + cl
+                    self.stats["prefill_chunks"] += 1
+                    self.stats["prefill_tokens"] += cl
+                    if self.paged and self.prefix_cache:
+                        # the blocks become readable once THIS step
+                        # completes on device — before any later-dispatched
+                        # step could read them through a prefix match
+                        # (sequential stream)
+                        rep.alloc.mark_computed(slot, int(rep.slot_pf[slot]))
         return handle
+
+    def _count_kv_pages(self, rep: _Replica, occ: np.ndarray,
+                        any_prefilling: bool) -> None:
+        """Add this step's paged-KV page counts, from host mirrors only.
+
+        ``kv_pages_grid``: ``max_batch`` x the block table's width — what
+        the paged attention kernels' grid steps over
+        (``grid=(b, block_tables.shape[1])``; ``last_block`` stops the DMA
+        past a row's ``kv_len``, not the grid step). ``kv_pages_live``: the
+        pages the target forward's ``kv_len`` (window start + window width)
+        covers in each occupied row, the window starting at the last
+        committed token (``slot_last_n - 1``) or at the prefill cursor.
+        Their ratio is the share of the grid that does work. Exact in the
+        synchronous loop; in the pipelined loop ``slot_last_n`` is one
+        step stale (it advances at harvest)."""
+        if self.mode == "ar":
+            width = self.chunk if any_prefilling else 1
+        else:
+            width = self.dec.verify_width
+        prefilling = rep.slot_pf < rep.slot_pf_len
+        start = np.where(prefilling, rep.slot_pf, rep.slot_last_n - 1)
+        table_w = rep.alloc.tables.shape[1]
+        pages = np.minimum(-(-(start + width) // rep.alloc.block_size),
+                           table_w)
+        self.stats["kv_pages_live"] += int(pages[occ].sum())
+        self.stats["kv_pages_grid"] += self.max_batch * table_w
 
     def process(self, handle: StepHandle) -> None:
         """Harvest one in-flight step (ONE batched device transfer) and
@@ -655,10 +702,13 @@ class Scheduler:
         live mask, then completions, with retirement staged for the next
         dispatch boundary."""
         rep = self.replicas[handle.replica]
-        res = rep.ex.harvest(handle)
-        self._harvest_done_t = time.perf_counter()
-        self._note_results(rep, handle, res)
-        self._harvest_completions(rep, handle, res)
+        with spans.phase(self.stats, "harvest_wait",
+                         step=handle.step) as wait:
+            res = rep.ex.harvest(handle)
+        self._harvest_done_ns = wait.t1
+        with spans.phase(self.stats, "harvest", start_ns=wait.t1):
+            self._note_results(rep, handle, res)
+            self._harvest_completions(rep, handle, res)
 
     def _note_results(self, rep: _Replica, handle: StepHandle,
                       res: StepResult) -> None:
