@@ -56,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import forward, init_caches
-from ..models.attention import (TreeAttnInfo, paged_flat_index,
+from ..models.attention import (TreeAttnInfo, paged_index,
                                 resolve_kv_dtype)
 from ..models.config import (ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA, SSM,
                              ModelConfig, scan_plan)
@@ -406,20 +406,26 @@ def compact_tree_caches(cfg: ModelConfig, caches, src_pos, dst_start, depth,
         return jax.vmap(lambda row, tk, p: jax.lax.dynamic_update_slice(
             row, tk, (p,) + zeros))(leaf, taken, dst_start)
 
-    def move_paged(leaf):            # [NB, bs, ...]
-        src = paged_flat_index(tables, src_pos, block_size).reshape(-1)
-        dst = paged_flat_index(tables, dst_pos, block_size).reshape(-1)
-        pf = leaf.reshape((-1,) + leaf.shape[2:])
-        pf = pf.at[dst].set(pf[src])
-        return pf.reshape(leaf.shape)
-
-    move = move_contig if tables is None else move_paged
+    def move_paged(leaf, scanned):   # [(L,) NB, bs, ...]
+        # every layer of a stacked pool in one scatter, indexed as
+        # paged_index says: vmapped over the layer axis, the scatter is
+        # lowered around a relayout of the whole pool
+        heads = leaf.shape[-2] if leaf.ndim - scanned == 4 else None
+        src = paged_index(tables, src_pos, block_size, heads)
+        dst = paged_index(tables, dst_pos, block_size, heads)
+        if scanned:
+            layer = jnp.arange(leaf.shape[0]).reshape(
+                (-1,) + (1,) * src[0].ndim)
+            src, dst = (layer,) + src, (layer,) + dst
+        return leaf.at[dst].set(leaf[src])
 
     def one(spec, entry, scanned):
         if spec.mixer not in _ATTN_MIXERS:
             return entry
-        fn = jax.vmap(move) if scanned else move
-        return jax.tree.map(fn, entry)
+        if tables is None:
+            return jax.tree.map(jax.vmap(move_contig) if scanned
+                                else move_contig, entry)
+        return jax.tree.map(lambda x: move_paged(x, scanned), entry)
 
     return {
         "prefix": [one(s, caches["prefix"][i], False)

@@ -18,6 +18,9 @@ KV caches come in two layouts (DESIGN.md §5):
     reserved garbage block: unallocated table entries point at it, so writes
     past a row's allocation land there and are never attended (reads are
     bounded by ``kv_len``). The serving pool lives in serving/kv_pool.py.
+    Inside the layer loop a scanned layer's pool stays stacked over the
+    layers, ``[L, num_blocks, block, ...]``, and the paged functions take
+    the ``layer`` to write and read (models/transformer.py ``forward``).
 
 The paged layout is selected by passing ``block_tables``/``kv_block_size``
 through ``forward`` — the same rollback-by-``cache_pos`` semantics hold
@@ -367,33 +370,44 @@ def _write_cache(buf, new, cache_pos):
     return jax.vmap(row)(buf, new, cache_pos)
 
 
-def write_cache_paged(pages, new, cache_pos, block_tables, block_size):
+def write_cache_paged(pages, new, cache_pos, block_tables, block_size,
+                      layer=None):
     """Scatter new KV into a block-paged pool through per-row block tables.
 
-    pages: [NB, bs, ...]; new: [B, T, ...]; cache_pos: [B] int32;
-    block_tables: [B, MBS] int32. Rows own disjoint blocks, so the flattened
-    scatter indices never collide across the batch; positions mapping past a
-    row's table (or to unallocated entries) land in the reserved garbage
-    block 0, whose contents are never attended.
+    pages: [NB, bs, ...], or with ``layer`` (an int32 scalar, may be traced)
+    a pool stacked over layers [L, NB, bs, ...] written at that layer in
+    place; new: [B, T, ...]; cache_pos: [B] int32; block_tables: [B, MBS]
+    int32. Rows own disjoint blocks, so the scatter indices never collide
+    across the batch; positions mapping past a row's table (or to
+    unallocated entries) land in the reserved garbage block 0, whose
+    contents are never attended.
     """
-    b, t = new.shape[0], new.shape[1]
-    flat = paged_flat_index(block_tables, cache_pos[:, None]
-                            + jnp.arange(t)[None, :], block_size).reshape(-1)
-    pf = pages.reshape((-1,) + pages.shape[2:])
-    pf = pf.at[flat].set(new.reshape((-1,) + new.shape[2:]).astype(pages.dtype))
-    return pf.reshape(pages.shape)
+    t = new.shape[1]
+    at = paged_index(block_tables, cache_pos[:, None] + jnp.arange(t)[None, :],
+                     block_size, new.shape[2] if new.ndim == 4 else None)
+    if layer is not None:
+        at = (layer,) + at
+    return pages.at[at].set(new.astype(pages.dtype))
 
 
-def paged_flat_index(block_tables, pos, block_size):
-    """Map absolute positions [B, T] to flat pool-entry indices through the
-    per-row block tables. Positions past a row's table resolve to the
+def paged_index(block_tables, pos, block_size, heads=None):
+    """Index into a paged pool leaf [NB, bs, ...] for absolute positions
+    [B, T] through the per-row block tables: (block, slot) and, given
+    ``heads`` (a K/V leaf's kv-head count), the kv head, so that a scatter's
+    window is the minor axis alone. An 8-bit pool's default TPU layout puts
+    its small kv-head axis outside the slot axis: a scatter that merges
+    block and slot, or whose window spans (head, dim), has the whole pool
+    relaid out around it. Positions past a row's table resolve to the
     reserved garbage block 0 (never attended: reads are bounded by kv_len)."""
     ent = pos // block_size
     mbs = block_tables.shape[1]
     blk = jnp.take_along_axis(block_tables, jnp.clip(ent, 0, mbs - 1),
                               axis=1)                            # [B, T]
     blk = jnp.where(ent >= mbs, 0, blk)      # past the table -> garbage block
-    return blk * block_size + pos % block_size
+    off = pos % block_size
+    if heads is None:
+        return blk, off
+    return blk[..., None], off[..., None], jnp.arange(heads)
 
 
 def gather_pages(pages, block_tables):
@@ -403,6 +417,19 @@ def gather_pages(pages, block_tables):
     """
     g = jnp.take(pages, block_tables, axis=0)                    # [B, MBS, bs, ...]
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
+
+
+def layer_pages(pages, block_tables, layer):
+    """One layer of a pool stacked over layers, ``[L, NB, bs, ...]``, read
+    as a single-layer pool: the stacked pool viewed as ``[L * NB, bs, ...]``
+    (merging its two major axes moves no data) through block tables offset
+    to that layer's blocks. Slicing the layer out instead would copy it.
+    With ``layer=None`` the pool is one layer's already. Each layer's
+    garbage block is its own block 0. Returns (pages, block_tables)."""
+    if layer is None:
+        return pages, block_tables
+    return (pages.reshape((-1,) + pages.shape[2:]),
+            block_tables + layer * pages.shape[1])
 
 
 def _qk_rmsnorm(x, scale, eps):
@@ -418,8 +445,9 @@ _PAGED_KERNEL_MAX_TQ = 32
 
 def _paged_attend(q, k_pages, v_pages, block_tables, q_pos, kv_len, *,
                   causal=True, window=0, attn_softcap=0.0, scale=None,
-                  tree_info=None, k_scale=None, v_scale=None):
-    """Attention against a block-paged KV pool.
+                  tree_info=None, k_scale=None, v_scale=None, layer=None):
+    """Attention against a block-paged KV pool (stacked over layers and read
+    at ``layer`` when one is given, through ``layer_pages``).
 
     Uses the Pallas paged decode kernel for small query windows on the
     pallas backend (the kernel's mask is causal, so only when ``causal``);
@@ -431,25 +459,40 @@ def _paged_attend(q, k_pages, v_pages, block_tables, q_pos, kv_len, *,
     is avoided wherever the kernel path is active (TPU decode/verify).
     """
     b, tq = q.shape[:2]
-    if (causal and tq <= _PAGED_KERNEL_MAX_TQ
-            and _pallas_ok(q, k_pages, v_pages, None)):
+    kernel = (causal and tq <= _PAGED_KERNEL_MAX_TQ
+              and _pallas_ok(q, k_pages, v_pages, None))
+    if kernel and k_scale is not None and layer is not None:
+        # the kernels take row-major operands. An 8-bit pool's and its
+        # float32 scales' default TPU layouts are not row-major (their small
+        # kv-head axis would pad), so the stacked quantized pool handed to a
+        # kernel would be relaid out whole before and after the layer loop.
+        # Hand it the layer instead: only that slice is relaid out.
+        k_pages, v_pages, k_scale, v_scale = (
+            x[layer] for x in (k_pages, v_pages, k_scale, v_scale))
+        layer = None
+    k_pages, tables = layer_pages(k_pages, block_tables, layer)
+    v_pages, _ = layer_pages(v_pages, block_tables, layer)
+    if k_scale is not None:
+        k_scale, _ = layer_pages(k_scale, block_tables, layer)
+        v_scale, _ = layer_pages(v_scale, block_tables, layer)
+    if kernel:
         from ..kernels import ops
         kv_len_arr = jnp.broadcast_to(jnp.asarray(kv_len), (b,)).astype(jnp.int32)
         if tree_info is not None:
             return ops.tree_attention_paged(
-                q, k_pages, v_pages, block_tables, kv_len_arr, q_pos,
+                q, k_pages, v_pages, tables, kv_len_arr, q_pos,
                 tree_info.win_start, tree_info.anc,
                 win_len=tree_info.win_len, k_scale=k_scale, v_scale=v_scale,
                 window=window, softcap=attn_softcap, scale=scale)
         return ops.decode_attention_paged(
-            q, k_pages, v_pages, block_tables, kv_len_arr, q_pos,
+            q, k_pages, v_pages, tables, kv_len_arr, q_pos,
             k_scale=k_scale, v_scale=v_scale,
             window=window, softcap=attn_softcap, scale=scale)
-    k = gather_pages(k_pages, block_tables)
-    v = gather_pages(v_pages, block_tables)
+    k = gather_pages(k_pages, tables)
+    v = gather_pages(v_pages, tables)
     if k_scale is not None:
-        k = dequantize_kv(k, gather_pages(k_scale, block_tables))
-        v = dequantize_kv(v, gather_pages(v_scale, block_tables))
+        k = dequantize_kv(k, gather_pages(k_scale, tables))
+        v = dequantize_kv(v, gather_pages(v_scale, tables))
     s = k.shape[1]
     kv_pos = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     return attend(q, k, v, q_pos, kv_pos, kv_len, causal=causal,
@@ -459,8 +502,9 @@ def _paged_attend(q, k_pages, v_pages, block_tables, q_pos, kv_len, *,
 
 def gqa_apply(params, cfg, x, positions, *, layer_window=0, cache=None,
               cache_pos=None, mask_info=None, causal=True, use_rope=True,
-              block_tables=None, kv_block_size=0, tree_info=None):
-    """Self attention. Returns (y, new_cache)."""
+              block_tables=None, kv_block_size=0, tree_info=None, layer=None):
+    """Self attention. Returns (y, new_cache). ``layer``: with paged caches,
+    the leaves are pools stacked over layers, written and read there."""
     b, t, _ = x.shape
     q = jnp.einsum("btd,dhk->bthk", x, params["wq"].astype(x.dtype))
     k = jnp.einsum("btd,dhk->bthk", x, params["wk"].astype(x.dtype))
@@ -492,22 +536,24 @@ def gqa_apply(params, cfg, x, positions, *, layer_window=0, cache=None,
             v, sv = quantize_kv(v, cache["v"].dtype)
             new_cache = {
                 "k_scale": write_cache_paged(cache["k_scale"], sk, cache_pos,
-                                             block_tables, kv_block_size),
+                                             block_tables, kv_block_size,
+                                             layer),
                 "v_scale": write_cache_paged(cache["v_scale"], sv, cache_pos,
-                                             block_tables, kv_block_size)}
+                                             block_tables, kv_block_size,
+                                             layer)}
         else:
             new_cache = {}
         new_cache["k"] = write_cache_paged(cache["k"], k, cache_pos,
-                                           block_tables, kv_block_size)
+                                           block_tables, kv_block_size, layer)
         new_cache["v"] = write_cache_paged(cache["v"], v, cache_pos,
-                                           block_tables, kv_block_size)
+                                           block_tables, kv_block_size, layer)
         out = _paged_attend(q, new_cache["k"], new_cache["v"], block_tables,
                             positions, cache_pos + t, causal=causal,
                             window=layer_window,
                             attn_softcap=cfg.attn_softcap, scale=scale,
                             tree_info=tree_info,
                             k_scale=new_cache.get("k_scale"),
-                            v_scale=new_cache.get("v_scale"))
+                            v_scale=new_cache.get("v_scale"), layer=layer)
     else:
         if "k_scale" in cache:
             k, sk = quantize_kv(k, cache["k"].dtype)
@@ -623,7 +669,7 @@ def _rms(x, scale, eps):
 
 def mla_apply(params, cfg, x, positions, *, cache=None, cache_pos=None,
               mask_info=None, block_tables=None, kv_block_size=0,
-              tree_info=None):
+              tree_info=None, layer=None):
     b, t, _ = x.shape
     h = cfg.n_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -653,18 +699,19 @@ def mla_apply(params, cfg, x, positions, *, cache=None, cache_pos=None,
         if "ckv_scale" in cache:
             qc, sc = quantize_kv(compressed, cache["ckv"].dtype)
             pages = write_cache_paged(cache["ckv"], qc, cache_pos,
-                                      block_tables, kv_block_size)
+                                      block_tables, kv_block_size, layer)
             spages = write_cache_paged(cache["ckv_scale"], sc, cache_pos,
-                                       block_tables, kv_block_size)
+                                       block_tables, kv_block_size, layer)
             new_cache = {"ckv": pages, "ckv_scale": spages}
-            kv_src = dequantize_kv(gather_pages(pages, block_tables),
-                                   gather_pages(spages, block_tables)
-                                   ).astype(x.dtype)
+            kv_src = dequantize_kv(
+                gather_pages(*layer_pages(pages, block_tables, layer)),
+                gather_pages(*layer_pages(spages, block_tables, layer))
+            ).astype(x.dtype)
         else:
             pages = write_cache_paged(cache["ckv"], compressed, cache_pos,
-                                      block_tables, kv_block_size)
+                                      block_tables, kv_block_size, layer)
             new_cache = {"ckv": pages}
-            kv_src = gather_pages(pages, block_tables)
+            kv_src = gather_pages(*layer_pages(pages, block_tables, layer))
         s = kv_src.shape[1]
         kv_pos = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
         kv_len = cache_pos + t

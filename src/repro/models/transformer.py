@@ -3,7 +3,9 @@
 The layer stack is executed as ``lax.scan`` over repeating *periods* (see
 config.scan_plan) so 95-layer models lower to a small HLO. Params, caches and
 SSM states for scanned layers are stacked on a leading ``n_repeats`` axis;
-prefix layers (e.g. deepseek-v2's leading dense layer) run unrolled.
+prefix layers (e.g. deepseek-v2's leading dense layer) run unrolled. Paged
+attention pools ride the scan's carry, written and read in place at the
+layer index; every other cache leaf is a scan input and output.
 
 Public entry points:
   init_params(key, cfg)                      -> param pytree
@@ -142,7 +144,7 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
 def _apply_layer(lp, cfg: ModelConfig, spec: LayerSpec, x, positions, *,
                  cache=None, cache_pos=None, mask_info=None, enc_out=None,
                  collect_ssm=False, block_tables=None, kv_block_size=0,
-                 tree_info=None):
+                 tree_info=None, layer=None):
     _, norm = L.make_norm(cfg)
     aux = {}
     h = norm(lp["norm1"], x)
@@ -153,14 +155,14 @@ def _apply_layer(lp, cfg: ModelConfig, spec: LayerSpec, x, positions, *,
             lp["mixer"], cfg, h, positions, layer_window=window, cache=cache,
             cache_pos=cache_pos, mask_info=mask_info, use_rope=cfg.use_rope,
             block_tables=block_tables, kv_block_size=kv_block_size,
-            tree_info=tree_info)
+            tree_info=tree_info, layer=layer)
     elif spec.mixer == ATTN_MLA:
         y, new_cache = attn.mla_apply(lp["mixer"], cfg, h, positions,
                                       cache=cache, cache_pos=cache_pos,
                                       mask_info=mask_info,
                                       block_tables=block_tables,
                                       kv_block_size=kv_block_size,
-                                      tree_info=tree_info)
+                                      tree_info=tree_info, layer=layer)
     elif spec.mixer == ATTN_CROSS:
         y = attn.cross_attn_apply(lp["mixer"], cfg, h, enc_out)
         new_cache = cache
@@ -260,13 +262,13 @@ def forward(params, cfg: ModelConfig, tokens: Array, positions=None, *,
     aux_total = jnp.zeros((), jnp.float32)
     new_caches = {"prefix": [], "scan": []}
 
-    def run(lp, spec, x, cache):
+    def run(lp, spec, x, cache, layer=None):
         return _apply_layer(lp, cfg, spec, x, positions, cache=cache,
                             cache_pos=cache_pos, mask_info=mask_info,
                             enc_out=enc_out, collect_ssm=collect_ssm,
                             block_tables=block_tables,
                             kv_block_size=kv_block_size,
-                            tree_info=tree_info)
+                            tree_info=tree_info, layer=layer)
 
     # ---- prefix layers (unrolled) ----
     for i, spec in enumerate(plan.prefix):
@@ -278,26 +280,47 @@ def forward(params, cfg: ModelConfig, tokens: Array, positions=None, *,
     # ---- scanned periods ----
     if plan.n_repeats:
         period = plan.period
+        # Paged attention pools ride the carry, stacked over the layers:
+        # each layer scatters its new KV into the pool in place and the
+        # kernels read it at the layer index. As scan outputs they would be
+        # fresh buffers, zero-filled before the loop and copied whole into
+        # the returned caches after it. SSM states (small, batch-indexed),
+        # contiguous caches and the no-cache path stay inputs and outputs.
+        carried = tuple(block_tables is not None and caches is not None
+                        and spec.mixer in (ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA)
+                        for spec in period)
+        scan_in = (caches["scan"] if caches is not None
+                   else [{} for _ in period])
 
         def body(carry, xs):
-            x, aux_acc = carry
-            lps, cs = xs
-            new_cs = []
+            x, aux_acc, pools = carry
+            lps, cs = xs[:2]
+            new_cs, new_pools = [], []
             for j, spec in enumerate(period):
-                cache_j = cs[j] if caches is not None else None
-                x, nc, aux = run(lps[j], spec, x, cache_j)
+                if carried[j]:
+                    x, nc, aux = run(lps[j], spec, x, pools[j], layer=xs[2])
+                    new_pools.append(nc)
+                    nc = {}
+                else:
+                    cache_j = cs[j] if caches is not None else None
+                    x, nc, aux = run(lps[j], spec, x, cache_j)
+                    new_pools.append({})
                 new_cs.append(nc)
                 aux_acc = aux_acc + aux.get("load_balance_loss", 0.0)
-            return (x, aux_acc), tuple(new_cs)
+            return (x, aux_acc, tuple(new_pools)), tuple(new_cs)
 
         if remat:
             body = jax.checkpoint(body)
 
+        pools = tuple(c if k else {} for c, k in zip(scan_in, carried))
         xs = (tuple(params["scan"]),
-              tuple(caches["scan"]) if caches is not None
-              else tuple({} for _ in period))
-        (x, aux_total), scan_caches = jax.lax.scan(body, (x, aux_total), xs)
-        new_caches["scan"] = list(scan_caches)
+              tuple({} if k else c for c, k in zip(scan_in, carried)))
+        if any(carried):
+            xs += (jnp.arange(plan.n_repeats, dtype=jnp.int32),)
+        (x, aux_total, pools), scan_caches = jax.lax.scan(
+            body, (x, aux_total, pools), xs)
+        new_caches["scan"] = [p if k else c for p, c, k
+                              in zip(pools, scan_caches, carried)]
 
     if last_only:
         # serving prefill: only the last position's logits are consumed;

@@ -126,6 +126,57 @@ def test_decode_attention_paged_ignores_garbage_block():
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=0)
 
 
+@pytest.mark.parametrize("kernel", ["decode", "tree"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("layer", [0, 2, 4])
+def test_paged_kernels_read_stacked_pool_at_layer(kernel, kv, layer):
+    """The layer loop hands the paged kernels a pool stacked over layers
+    ``[L, NB, bs, Hkv, D]`` and a traced layer index (``_paged_attend``):
+    a bf16 pool viewed as ``[L * NB, ...]`` through offset block tables, an
+    8-bit pool and its scales sliced at the layer. Either must equal the
+    oracle on the slice ``pool[layer]``."""
+    from repro.models.attention import (TreeAttnInfo, _paged_attend,
+                                        quantize_kv, set_attention_backend)
+    n_layers, b, tq, hq, hkv, d, bs, mbs = 5, 2, 9, 4, 2, 64, 32, 4
+    nb = 1 + b * mbs
+    k_pages = rand(n_layers, nb, bs, hkv, d, k=60, dtype=jnp.bfloat16)
+    v_pages = rand(n_layers, nb, bs, hkv, d, k=61, dtype=jnp.bfloat16)
+    ks = vs = None
+    if kv == "int8":
+        k_pages, ks = quantize_kv(k_pages, jnp.int8)
+        v_pages, vs = quantize_kv(v_pages, jnp.int8)
+    perm = np.random.default_rng(layer).permutation(np.arange(1, nb))
+    tables = jnp.asarray(perm.reshape(b, mbs), jnp.int32)
+    q = rand(b, tq, hq, d, k=62)
+    kv_len = jnp.asarray([bs * mbs - 7, bs * 2 + tq], jnp.int32)
+    q_pos = (kv_len - tq)[:, None] + jnp.arange(tq)[None, :]
+    win_start = kv_len - tq
+    anc = jnp.broadcast_to(
+        jnp.asarray([(1 << (s + 1)) - 1 for s in range(tq)], jnp.uint32),
+        (b, tq))
+    tree = TreeAttnInfo(win_start, anc) if kernel == "tree" else None
+
+    def at(x, i):
+        return None if x is None else x[i]
+
+    set_attention_backend("pallas")
+    try:
+        out = jax.jit(lambda i: _paged_attend(
+            q, k_pages, v_pages, tables, q_pos, kv_len, tree_info=tree,
+            k_scale=ks, v_scale=vs, layer=i))(jnp.int32(layer))
+    finally:
+        set_attention_backend(None)
+    if kernel == "decode":
+        want = ref.decode_attention_paged_ref(
+            q, k_pages[layer], v_pages[layer], tables, kv_len, q_pos,
+            k_scale=at(ks, layer), v_scale=at(vs, layer))
+    else:
+        want = ref.tree_attention_paged_ref(
+            q, k_pages[layer], v_pages[layer], tables, kv_len, q_pos,
+            win_start, anc, k_scale=at(ks, layer), v_scale=at(vs, layer))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+
+
 def test_decode_attention_window():
     b, tq, h, d, s = 2, 3, 4, 32, 128
     q, k, v = rand(b, tq, h, d, k=7), rand(b, s, h, d, k=8), rand(b, s, h, d, k=9)

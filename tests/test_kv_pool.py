@@ -10,6 +10,8 @@ from repro.models import forward, init_caches, init_params
 from repro.models.attention import gather_pages, write_cache_paged
 from repro.serving import kv_pool
 
+from hlo_text import pool_shapes, whole_buffer_fills_and_copies
+
 
 # ---------------------------------------------------------------------- I1/I2
 def test_allocator_reserves_garbage_block():
@@ -216,3 +218,26 @@ def test_quant_write_past_allocation_lands_in_garbage_block():
     assert np.all(np.asarray(scales[0, 0:3]) == 3.0)
     assert np.all(np.asarray(pages[1]) == 0)         # other blocks untouched
     assert np.all(np.asarray(scales[1]) == 1.0)
+
+
+# ------------------------------------------------- pools updated in place
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("kind", ["flat", "tree"])
+def test_fused_step_updates_stacked_pools_in_place(kind, kv):
+    """The paged pools of the scanned layers ride the layer loop's carry:
+    the compiled serving step writes each layer's KV into the donated pool
+    in place. No broadcast (the zero fill of a fresh loop output) and no
+    copy (loop output into the returned state) of a whole stacked pool
+    leaf, K, V or their scales, of either model may appear in it."""
+    from repro.serving.engine import Engine, EngineConfig
+    tc, dc = get_config("tiny-target"), get_config("tiny-draft")
+    eng = Engine(init_params(jax.random.PRNGKey(0), tc), tc,
+                 init_params(jax.random.PRNGKey(1), dc), dc,
+                 config=EngineConfig(
+                     mode="pard", k=4, max_batch=2, max_len=256,
+                     kv_block_size=16, kv_dtype=kv,
+                     tree=(2, 2, 1) if kind == "tree" else None))
+    shapes = pool_shapes(eng.ex.state)
+    assert len(shapes) == (2 if kv == "bf16" else 4)
+    text = eng.ex.step_hlo(tree=kind == "tree")
+    assert whole_buffer_fills_and_copies(text, shapes) == []

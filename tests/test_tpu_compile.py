@@ -2,10 +2,11 @@
 
 No chip is needed: the TPU compiler is installed and compiles for a
 described, unattached ``v5e:2x2`` topology. The paged decode and tree
-verify kernels — bf16 and int8 pools, at the verify windows the serving
-steps use — are compiled at ``llama3.2-1b`` widths on one chip and at
-``llama3.1-8b`` widths split four ways (the per-device shard of a tp=4
-step), and the compiled program must hold the kernel (``tpu_custom_call``).
+verify kernels — bf16 and int8 pools in the form the layer loop hands
+them, at the verify windows the serving steps use — are compiled at
+``llama3.2-1b`` widths on one chip and at ``llama3.1-8b`` widths split
+four ways (the per-device shard of a tp=4 step), and the compiled program
+must hold the kernel (``tpu_custom_call``).
 This catches what interpret mode cannot: block shapes the TPU tiling rule
 refuses, and more VMEM than a kernel may use. The AR training loss is
 differentiated and compiled for the same chip, with the attention path the
@@ -55,7 +56,8 @@ def one_chip(topo):
 
 def _widths(model, tp):
     cfg = get_config(model)
-    return cfg.n_heads // tp, cfg.n_kv_heads // tp, cfg.resolved_head_dim
+    return (cfg.num_layers, cfg.n_heads // tp, cfg.n_kv_heads // tp,
+            cfg.resolved_head_dim)
 
 
 @pytest.mark.parametrize("model,tp", [("llama3.2-1b", 1),
@@ -63,8 +65,10 @@ def _widths(model, tp):
 @pytest.mark.parametrize("kernel,tq", [("decode", 9), ("tree", 23)])
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 def test_paged_kernel_compiles_for_v5e(one_chip, model, tp, kernel, tq, kv):
-    hq, hkv, d = _widths(model, tp)
-    nb = 1 + B * BLOCKS_PER_ROW
+    n_layers, hq, hkv, d = _widths(model, tp)
+    # the layer loop hands a kernel every layer's bf16 pool viewed as one
+    # (models.attention.layer_pages), and one layer's slice of an int8 pool
+    nb = (1 + B * BLOCKS_PER_ROW) * (n_layers if kv == "bf16" else 1)
     kv_dtype = jnp.bfloat16 if kv == "bf16" else jnp.int8
 
     def s(shape, dtype):
@@ -114,3 +118,50 @@ def test_ar_loss_grad_compiles_for_v5e(one_chip, monkeypatch):
     grad = jax.jit(jax.grad(lambda p, t: ar_loss(p, cfg, t)[0]))
     compiled = grad.lower(params, tokens).compile()
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("kind", ["flat", "tree"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_step_updates_pools_in_place_on_v5e(one_chip, monkeypatch, kind,
+                                                  kv):
+    """The fused serving step, compiled for one v5e with the Pallas kernels
+    in it, updates the stacked KV pools in place: no broadcast or copy of a
+    whole pool leaf. On a TPU an 8-bit pool's default layout, and its
+    scales', is not the row-major one the kernels take, so a stacked
+    quantized pool handed whole to a kernel is relaid out around the layer
+    loop: a copy that no CPU compile shows. Head widths are the served
+    models' (2 kv heads of 128, 64-slot pages); depth, width and pool cut.
+    The K/V leaves are checked: at this cut size the compiler stages the
+    small float32 scale pools through VMEM, a relayout it does not make at
+    the served sizes of the benchmark's pool."""
+    from hlo_text import pool_shapes, whole_buffer_fills_and_copies
+    from repro.models import init_params
+    from repro.models.attention import set_attention_backend
+    from repro.serving.engine import Engine, EngineConfig
+    from repro.serving.executor import NO_LIMIT
+
+    cfg = dataclasses.replace(get_config("tiny-target"), num_layers=2,
+                              d_model=256, n_heads=4, n_kv_heads=2,
+                              head_dim=128)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    eng = Engine(params, cfg, params, cfg, config=EngineConfig(
+        mode="pard", k=8, max_batch=4, max_len=512, kv_block_size=64,
+        kv_dtype=kv, tree=(2, 2, 1) if kind == "tree" else None))
+    shapes = {s for s in pool_shapes(eng.ex.state)
+              if s[1].count(",") == 4}                  # [L, NB, bs, Hkv, D]
+    assert len(shapes) == 1
+    b = eng.ex.max_batch
+    args = (eng.ex._params, eng.ex.state, jnp.zeros((b,), bool),
+            jnp.zeros((b,), jnp.int32), jnp.full((b,), NO_LIMIT, jnp.int32))
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        args)
+    monkeypatch.setattr(ops, "_interpret", lambda flag: False)
+    set_attention_backend("pallas")
+    try:
+        text = eng.ex._step_fn("decode", kind == "tree", False).lower(
+            *args).compile().as_text()
+    finally:
+        set_attention_backend(None)
+    assert "tpu_custom_call" in text
+    assert whole_buffer_fills_and_copies(text, shapes) == []
