@@ -58,8 +58,8 @@ import numpy as np
 from ..models import forward, init_caches
 from ..models.attention import (TreeAttnInfo, paged_index,
                                 resolve_kv_dtype)
-from ..models.config import (ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA, SSM,
-                             ModelConfig, scan_plan)
+from ..models.config import (ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA, MLP_MOE,
+                             SSM, ModelConfig, scan_plan)
 from ..serving import spans
 from . import acceptance
 
@@ -176,6 +176,11 @@ def _topk_indices(logits: Array, k: int) -> Array:
 def _has_ssm(cfg: ModelConfig) -> bool:
     plan = scan_plan(cfg)
     return any(s.mixer == SSM for s in plan.prefix + plan.period)
+
+
+def _has_moe(cfg: ModelConfig) -> bool:
+    plan = scan_plan(cfg)
+    return any(s.mlp == MLP_MOE for s in plan.prefix + plan.period)
 
 
 # ---------------------------------------------------------------------------
@@ -479,6 +484,10 @@ class DecodeState:
       pf_len [B]     prompt tokens the row must prefill (prompt length - 1:
                      the last prompt token is re-processed by the first
                      verify window, exactly like the uniform-batch prefill).
+      moe_rows []    int32: the expert rows the last step's forwards
+                     computed on this device (``forward``'s aux); None
+                     when neither model has a MoE layer, so that their
+                     step carries no count.
     """
     gen: Array
     n: Array
@@ -492,6 +501,7 @@ class DecodeState:
     tree_idx: Optional[Array] = None
     pf_pos: Optional[Array] = None
     pf_len: Optional[Array] = None
+    moe_rows: Optional[Array] = None
 
 
 # every field is pytree data (derived from the dataclass so new fields can
@@ -622,6 +632,9 @@ class SpecDecoder:
         if draft_cfg is not None:
             assert draft_cfg.vocab_size == target_cfg.vocab_size, \
                 "speculative decoding requires a shared tokenizer/vocab"
+        # DecodeState.moe_rows is carried only where a MoE layer counts it
+        self.counts_moe_rows = _has_moe(target_cfg) or (
+            draft_cfg is not None and _has_moe(draft_cfg))
         self._jit_cache: Dict[str, Any] = {}
 
     @property
@@ -744,7 +757,7 @@ class SpecDecoder:
                 next_keys = jnp.where(prefilling[:, None], state.rngs,
                                       next_keys)
             with jax.named_scope(spans.TARGET):
-                logits, tcache, _ = self._target_forward(
+                logits, tcache, aux = self._target_forward(
                     params, toks, state.tcache, cp, state.tables)
             with jax.named_scope(spans.ACCEPT):
                 nxt = _pick_next(logits[:, 0], temp, use)
@@ -757,7 +770,9 @@ class SpecDecoder:
                 return dataclasses.replace(
                     state, gen=gen, n=n, tcache=tcache, rngs=next_keys,
                     pf_pos=(state.pf_pos if not chunked else
-                            jnp.where(prefilling, pf + cl, state.pf_pos)))
+                            jnp.where(prefilling, pf + cl, state.pf_pos)),
+                    moe_rows=(None if state.moe_rows is None
+                              else aux["moe_rows"]))
         return step
 
     def init_state(self, prompt: Array, gen_len: int,
@@ -781,7 +796,9 @@ class SpecDecoder:
             tree_idx=(jnp.zeros((b,), jnp.int32)
                       if self.tree is not None else None),
             pf_pos=jnp.zeros((b,), jnp.int32),
-            pf_len=jnp.zeros((b,), jnp.int32))
+            pf_len=jnp.zeros((b,), jnp.int32),
+            moe_rows=(jnp.zeros((), jnp.int32) if self.counts_moe_rows
+                      else None))
 
     def generate_ar(self, prompt: Array, max_new: int, seed: int = 0):
         """Plain autoregressive decoding (the losslessness reference):
@@ -817,7 +834,8 @@ class SpecDecoder:
                            pfinfo=None):
         """ONE PARD draft forward (Eq. 7): proposal logits for every depth
         1..K. Slot A-1 (the last real token) proposes depth 1, the K-1 mask
-        slots the rest. Returns (lg [B, K, V], new draft cache).
+        slots the rest. Returns (lg [B, K, V], new draft cache, the
+        forward's MoE expert rows).
 
         ``pfinfo = (prefilling, pf, cl)`` (chunked engine steps only):
         prefilling rows consume a ``cl``-token prompt chunk at cursor ``pf``
@@ -834,14 +852,14 @@ class SpecDecoder:
             tok = jnp.where(prefilling[:, None], chunk, tok)
             pos = jnp.where(prefilling, pf, pos)
             ssm_idx = jnp.where(prefilling, cl - 1, ssm_idx)
-        logits, dcache, _ = self._draft_forward(
+        logits, dcache, aux = self._draft_forward(
             params, tok, dcache, pos, tables, collect_ssm=d_has_ssm)
         if d_has_ssm:
             dcache = gather_ssm_states(dc, dcache, ssm_idx)
         a = n - m
         sl = (a - 1)[:, None] + jnp.arange(k)[None, :]
         lg = jax.vmap(lambda row, s: row[s])(logits, sl)   # [B, K, V]
-        return lg, dcache
+        return lg, dcache, aux["moe_rows"]
 
     # ------------------------------------------------------------- shared
     def _build_spec_step(self, mode: str, chunked: bool = False,
@@ -867,11 +885,11 @@ class SpecDecoder:
 
         def propose_pard(params, gen, n, m, dcache, tables, temp, dkeys,
                          pfinfo):
-            lg, dcache = self._pard_depth_logits(params, gen, n, m, dcache,
-                                                 tables, pfinfo)
+            lg, dcache, rows = self._pard_depth_logits(
+                params, gen, n, m, dcache, tables, pfinfo)
             greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
             if greedy_only:
-                return greedy, None, dcache, 1              # 1 draft forward
+                return greedy, None, dcache, 1, rows        # 1 draft forward
             scaled = acceptance.scale_logits(lg, temp)      # [B, K, V]
 
             def samp():
@@ -880,7 +898,7 @@ class SpecDecoder:
                 return jnp.where((temp > 0)[:, None], s, greedy)
 
             props = jax.lax.cond(jnp.any(temp > 0), samp, lambda: greedy)
-            return props, scaled, dcache, 1                 # 1 draft forward
+            return props, scaled, dcache, 1, rows           # 1 draft forward
 
         def propose_vsd(params, gen, n, m, dcache, tables, temp, dkeys,
                         pfinfo):
@@ -894,8 +912,9 @@ class SpecDecoder:
                                 _chunk_window(gen, pf, cl, k + 1), tok)
                 pos = jnp.where(prefilling, pf, pos)
                 ssm_idx = jnp.where(prefilling, cl - 1, ssm_idx)
-            logits, dcache, _ = self._draft_forward(
+            logits, dcache, aux = self._draft_forward(
                 params, tok, dcache, pos, tables, collect_ssm=d_has_ssm)
+            rows = aux["moe_rows"]
             a = n - m
             if d_has_ssm:
                 # roll SSM state back to "after the last real token"; the AR
@@ -916,16 +935,17 @@ class SpecDecoder:
                 props.append(pj)
                 if j == k - 1:
                     break
-                lgn, dcache, _ = self._draft_forward(params, pj[:, None],
-                                                     dcache, cur_pos, tables)
+                lgn, dcache, aux = self._draft_forward(
+                    params, pj[:, None], dcache, cur_pos, tables)
+                rows = rows + aux["moe_rows"]
                 cur_pos = cur_pos + 1
                 lg_list.append(lgn[:, 0])
             props = jnp.stack(props, axis=1)                # [B, K]
             if greedy_only:
-                return props, None, snapshot, k             # K draft forwards
+                return props, None, snapshot, k, rows       # K draft forwards
             scaled = acceptance.scale_logits(
                 jnp.stack(lg_list, axis=1), temp)           # [B, K, V]
-            return props, scaled, snapshot, k               # K draft forwards
+            return props, scaled, snapshot, k, rows         # K draft forwards
 
         propose = propose_pard if mode == "pard" else propose_vsd
 
@@ -952,7 +972,7 @@ class SpecDecoder:
                     next_keys = jnp.where(prefilling[:, None], state.rngs,
                                           next_keys)
             with jax.named_scope(spans.DRAFT):
-                props, scaled_q, dcache, n_draft = propose(
+                props, scaled_q, dcache, n_draft, rows = propose(
                     params, gen, n, m, dcache, tables, temp, dkeys, pfinfo)
 
             with jax.named_scope(spans.TARGET):
@@ -964,8 +984,9 @@ class SpecDecoder:
                     vin = jnp.where(prefilling[:, None],
                                     _chunk_window(gen, pf, cl, k + 1), vin)
                     vpos = jnp.where(prefilling, pf, vpos)
-                logits, tcache_new, _ = self._target_forward(
+                logits, tcache_new, aux = self._target_forward(
                     params, vin, tcache, vpos, tables, collect_ssm=t_has_ssm)
+                rows = rows + aux["moe_rows"]
 
             # acceptance (core/acceptance.py): greedy rule for temp == 0
             # rows, Leviathan sampling for temp > 0 rows — row-selected so
@@ -1040,7 +1061,8 @@ class SpecDecoder:
                     state, gen=gen, n=new_n, m=new_m, tcache=tcache_new,
                     dcache=dcache, rngs=next_keys,
                     pf_pos=(state.pf_pos if not chunked else
-                            jnp.where(prefilling, pf + cl, state.pf_pos)))
+                            jnp.where(prefilling, pf + cl, state.pf_pos)),
+                    moe_rows=None if state.moe_rows is None else rows)
                 return (new_state, jnp.where(frozen, 0, a), acc_hist,
                         round_hist, rank, n_draft)
 
@@ -1138,8 +1160,8 @@ class SpecDecoder:
                 # top-max_b per depth covers every template's ranks;
                 # _topk_indices and argmax share lowest-index tie-breaking, so
                 # rank 0 IS the flat path's argmax (degenerate-chain identity).
-                lg, dcache = self._pard_depth_logits(params, gen, n, m, dcache,
-                                                     tables, pfinfo)
+                lg, dcache, rows = self._pard_depth_logits(
+                    params, gen, n, m, dcache, tables, pfinfo)
                 topk = _topk_indices(lg, max_b)                    # [B,D,MB]
                 di = jnp.maximum(node_depth - 1, 0)
                 per_node = jnp.take_along_axis(
@@ -1189,9 +1211,10 @@ class SpecDecoder:
                     win_len = jnp.where(prefilling, cl, win_len)
                 tinfo = TreeAttnInfo(win_start=win_start, anc=win_anc,
                                      win_len=win_len)
-                logits, tcache_new, _ = self._target_forward(
+                logits, tcache_new, aux = self._target_forward(
                     params, vin, tcache, win_start, tables,
                     positions=positions, tree_info=tinfo)
+                rows = rows + aux["moe_rows"]
 
             with jax.named_scope(spans.ACCEPT):
                 # acceptance (core/acceptance.py), row-selected greedy/sampled;
@@ -1272,7 +1295,8 @@ class SpecDecoder:
                     state, gen=gen, n=new_n, m=new_m, tcache=tcache_new,
                     dcache=dcache, rngs=next_keys,
                     pf_pos=(state.pf_pos if not chunked else
-                            jnp.where(prefilling, pf + cl, state.pf_pos)))
+                            jnp.where(prefilling, pf + cl, state.pf_pos)),
+                    moe_rows=None if state.moe_rows is None else rows)
                 return new_state, jnp.where(frozen, 0, a), hist, round_hist, \
                     rank, 1
 

@@ -263,3 +263,103 @@ def decode_attention(q, k, v, kv_len, q_pos, *, k_scale=None, v_scale=None,
         k_scale=as_pages(k_scale, block_k) if quant else None,
         v_scale=as_pages(v_scale, block_k) if quant else None,
         window=window, softcap=softcap, scale=scale, interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA, absorbed) decode / flat verify
+# ---------------------------------------------------------------------------
+
+def _latent_kernel(bt_ref, kvlen_ref, qpos_ref, q_ref, kv_ref, o_ref, m_s,
+                   l_s, acc_s, *, scale, block_k, rank, width):
+    # bt_ref is consumed only by the BlockSpec index maps
+    bi, ki = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(ki == 0)
+    def _init():
+        init_scratch(m_s, l_s, acc_s)
+
+    kv_len = kvlen_ref[bi]
+    k_start = ki * block_k
+
+    @pl.when(k_start < kv_len)
+    def _compute():
+        qp_rows = qpos_ref[0]                               # [rows, 1]
+        k_pos = k_start + jax.lax.broadcasted_iota(
+            jnp.int32, (qp_rows.shape[0], block_k), 1)
+        mask = (k_pos < kv_len) & (k_pos <= qp_rows)
+        c = kv_ref[0, :, :rank]                             # [block, rank]
+        # one page feeds both products: the scores over the whole row
+        # (latent part + rope part) and the values from its latent part
+        dims = (((1,), (1,)), ((), ()))
+        s = jax.lax.dot_general(q_ref[0, :, :rank], c, dims,
+                                preferred_element_type=jnp.float32)
+        s += jax.lax.dot_general(q_ref[0, :, rank:], kv_ref[0, :, rank:width],
+                                 dims, preferred_element_type=jnp.float32)
+        s = jnp.where(mask, s * scale, NEG_INF)
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_s[...] = acc_s[...] * alpha + jnp.dot(
+            p.astype(c.dtype), c, preferred_element_type=jnp.float32)
+        m_s[...] = m_new
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _final():
+        denom = jnp.where(l_s[...] == 0.0, 1.0, l_s[...])
+        o_ref[0] = (acc_s[...] / denom).astype(o_ref.dtype)
+
+
+def paged_latent_attention(q, pages, block_tables, kv_len, q_pos, *, rank,
+                           scale, interpret=False):
+    """Absorbed multi-head latent attention over a paged latent pool.
+
+    q: [B, Tq, H, W] absorbed queries ``[q_nope W_uk | q_rope]``, W = rank
+    + rope width; pages: [NB, block, P] pool rows ``[c_kv | k_rope | pad]``,
+    P >= W (a pool stacked over layers viewed as one,
+    ``attention.layer_pages``);
+    block_tables: [B, MBS] int32; kv_len: [B] int32; q_pos: [B, Tq].
+    Returns [B, Tq, H, rank]: softmax(scale * q . row) . c_kv.
+
+    Every query head shares the one latent "head": a row's Tq x H queries
+    are one [Tq*H, W] block (row i*H + j = position i, head j), resident in
+    VMEM across the sweep. Grid (batch, table entry); each grid step DMAs
+    one page, whose whole rows are the keys and whose first ``rank``
+    channels are the values. The matmuls take bfloat16 operands with
+    float32 accumulation; the online softmax is float32. Pages past a
+    row's length are skipped and their DMA elided, as in
+    ``decode_attention_paged``."""
+    b, tq, h, w = q.shape
+    block, pw = pages.shape[1], pages.shape[2]
+    rows = tq * h
+
+    def entry(bi, ki, bt, kl):
+        return bt[bi, last_block(ki, kl[bi], block)]
+
+    def row_map(bi, ki, *_):
+        return (bi, 0, 0)
+
+    def page_map(bi, ki, *refs):
+        return (entry(bi, ki, *refs), 0, 0)
+
+    kern = functools.partial(_latent_kernel, scale=scale, block_k=block,
+                             rank=rank, width=w)
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, block_tables.shape[1]),
+            in_specs=[pl.BlockSpec((1, rows, 1), row_map),        # q_pos
+                      pl.BlockSpec((1, rows, w), row_map),        # q
+                      pl.BlockSpec((1, block, pw), page_map)],    # page
+            out_specs=pl.BlockSpec((1, rows, rank), row_map),
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, rank), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, rows, rank), q.dtype),
+        interpret=interpret,
+        name="paged_latent_attention",
+    )(block_tables.astype(jnp.int32), kv_len.astype(jnp.int32),
+      query_rows(q_pos.astype(jnp.int32), h), q.reshape(b, rows, w), pages)
+    return out.reshape(b, tq, h, rank)

@@ -297,6 +297,47 @@ def decode_attention_paged(q, k_pages, v_pages, block_tables, kv_len, q_pos,
         (2, 2, 2, None, None, None, 2, 2), k_pages.shape[2])
 
 
+def paged_latent_attention(q, pages, block_tables, kv_len, q_pos, *, rank,
+                           scale, interpret=None):
+    """Absorbed MLA against a paged latent pool [NB, block, rank + dr]
+    (decode_attention.paged_latent_attention). Its one latent "head" is
+    shared by every query head, so under a mesh the call replicates."""
+    interpret = _interpret(interpret)
+    kern = functools.partial(_dec.paged_latent_attention, rank=rank,
+                             scale=scale, interpret=interpret)
+    return _over_kv_heads(kern, (q, pages, block_tables, kv_len, q_pos),
+                          (None,) * 5, 1)
+
+
+GMM_ROWS = 128          # row tile of the grouped matmul
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, interpret=None):
+    """Rows of ``lhs`` [m, k], sorted by group, times their group's matrix
+    of ``rhs`` [G, k, n]: group g's ``group_sizes[g]`` rows after the
+    groups before it (megablox ``gmm``, a Pallas kernel). Rows past the
+    last group are not computed and come back undefined. The kernel
+    visits only the row tiles that hold a group's rows, so its time tracks
+    the rows routed, not ``m``. Tiles: 128 rows, the whole contraction,
+    and 256 or 128 output lanes (the fastest of the tilings tried on a
+    v5e at DeepSeek-V2's expert widths, PERF.md). It cannot be
+    partitioned, so under an activation mesh it replicates."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    interpret = _interpret(interpret)
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    tn = next((t for t in (256, 128) if n % t == 0), n)
+    lhs, _ = _pad_axis(lhs, 0, GMM_ROWS)
+
+    def call(x, w, sizes):
+        return gmm(x, w, sizes, preferred_element_type=lhs.dtype,
+                   tiling=(GMM_ROWS, k, tn), interpret=interpret)
+
+    out = _over_kv_heads(call, (lhs, rhs, group_sizes.astype(jnp.int32)),
+                         (None, None, None), 1)
+    return out[:m]
+
+
 def tree_attention(q, k, v, kv_len, q_pos, win_start, anc, *, win_len=None,
                    k_scale=None, v_scale=None, window=0, softcap=0.0,
                    scale=None, block_k=256, interpret=None):

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 
 from ..models.attention import (PardMaskInfo, TreeAttnInfo,
                                 attend_reference, dequantize_kv,
-                                gather_pages)
+                                gather_pages, latent_attend_reference)
 from ..models.ssm import ssd_scan_ref
 
 
@@ -68,6 +68,15 @@ def decode_attention_paged_ref(q, k_pages, v_pages, block_tables, kv_len,
     return decode_attention_ref(q, k, v, kv_len, q_pos, k_scale=k_scale,
                                 v_scale=v_scale, window=window,
                                 softcap=softcap, scale=scale)
+
+
+def paged_latent_attention_ref(q, pages, block_tables, kv_len, q_pos, *,
+                               rank, scale):
+    """Absorbed-MLA oracle: q [B,Tq,H,W] against a latent pool [NB, block,
+    W] (keys the whole rows, values their first ``rank`` channels), each
+    row's pages gathered into a contiguous view, in float32."""
+    return latent_attend_reference(q, pages, block_tables, kv_len, q_pos,
+                                   rank=rank, scale=scale)
 
 
 def tree_attention_ref(q, k, v, kv_len, q_pos, win_start, anc, *,

@@ -43,7 +43,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from .layers import apply_rope, softcap
+from . import scopes
+from .layers import apply_rope, softcap, yarn_mscale
 
 Array = jax.Array
 
@@ -653,6 +654,15 @@ def init_mla(key, cfg):
     return p
 
 
+def mla_pool_width(cfg) -> int:
+    """Lanes of a paged MLA pool row: ``[c_kv | k_rope]`` padded with zeros
+    to a multiple of 128. A TPU lays a row-major array out in tiles of 128
+    lanes, so the padding is memory it would hold anyway; unpadded, its
+    default layout for the pool is not row-major, and a kernel reading the
+    pool would have it relaid out whole at every call."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
 def init_mla_cache(cfg, batch, max_len, dtype=jnp.bfloat16):
     width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
     c = {"ckv": jnp.zeros((batch, max_len, width), dtype)}
@@ -667,6 +677,50 @@ def _rms(x, scale, eps):
     return (x.astype(jnp.float32) * jax.lax.rsqrt(v + eps) * scale).astype(x.dtype)
 
 
+def mla_softmax_scale(cfg) -> float:
+    """DeepSeek-V2's ``softmax_scale``: (qk_nope + qk_rope)^-1/2, times
+    mscale(factor, mscale_all_dim)^2 under YaRN."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        m = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def latent_attend_reference(q, pages, block_tables, kv_len, q_pos, *,
+                            rank, scale):
+    """Absorbed MLA attention against a paged latent pool, in jnp: each
+    row's pages gathered into a contiguous view (the reference read path).
+
+    q: [B, Tq, H, W] — the absorbed query ``[q_nope W_uk | q_rope]``, W =
+    rank + dr; pages: [NB, bs, P >= W] rows ``[c_kv | k_rope | pad]``;
+    block_tables: [B, MBS]; kv_len: [B]; q_pos: [B, Tq]. The keys are the
+    rows' first W channels, the values their first ``rank``, one "head"
+    shared by every query head. Returns [B, Tq, H, rank] in q's dtype."""
+    kv = gather_pages(pages, block_tables)[..., :q.shape[-1]].astype(
+        jnp.float32)                                            # [B, S, W]
+    s = kv.shape[1]
+    logits = jnp.einsum("bqhw,bkw->bhqk", q.astype(jnp.float32), kv) * scale
+    kv_pos = jnp.arange(s)[None, None, :]
+    allowed = ((kv_pos <= q_pos[:, :, None])
+               & (kv_pos < jnp.asarray(kv_len)[:, None, None]))  # [B,Tq,S]
+    logits = jnp.where(allowed[:, None], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bhqk,bkr->bqhr", probs, kv[..., :rank])
+    return out.astype(q.dtype)
+
+
+def _latent_attend(q, pages, block_tables, kv_len, q_pos, *, rank, scale):
+    """``latent_attend_reference``'s semantics: the Pallas latent kernel
+    where the platform runs kernels, else the jnp reference."""
+    if _use_pallas():
+        from ..kernels import ops
+        return ops.paged_latent_attention(q, pages, block_tables, kv_len,
+                                          q_pos, rank=rank, scale=scale)
+    return latent_attend_reference(q, pages, block_tables, kv_len, q_pos,
+                                   rank=rank, scale=scale)
+
+
 def mla_apply(params, cfg, x, positions, *, cache=None, cache_pos=None,
               mask_info=None, block_tables=None, kv_block_size=0,
               tree_info=None, layer=None):
@@ -674,6 +728,7 @@ def mla_apply(params, cfg, x, positions, *, cache=None, cache_pos=None,
     h = cfg.n_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     r_kv = cfg.kv_lora_rank
+    scale = mla_softmax_scale(cfg)
 
     if "w_dq" in params:
         cq = _rms(jnp.einsum("btd,dr->btr", x, params["w_dq"].astype(x.dtype)),
@@ -682,20 +737,51 @@ def mla_apply(params, cfg, x, positions, *, cache=None, cache_pos=None,
     else:
         q = jnp.einsum("btd,dhk->bthk", x, params["w_q"].astype(x.dtype))
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, yarn_cfg=cfg)
 
     ckv_full = jnp.einsum("btd,dr->btr", x, params["w_dkv"].astype(x.dtype))
     ckv, k_rope = ckv_full[..., :r_kv], ckv_full[..., r_kv:]
     ckv = _rms(ckv, params["kv_lora_norm"], cfg.norm_eps)
     # rope on the shared key channel (1 "head")
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0, :]
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        yarn_cfg=cfg)[:, :, 0, :]
     compressed = jnp.concatenate([ckv, k_rope], axis=-1)     # [B,T,r_kv+dr]
+    if block_tables is not None:
+        # a paged pool row carries zero lanes past r_kv + dr
+        compressed = jnp.pad(compressed, ((0, 0), (0, 0), (
+            0, mla_pool_width(cfg) - compressed.shape[-1])))
+
+    from ..kernels import ops
+    if (cache is not None and block_tables is not None
+            and "ckv_scale" not in cache and tree_info is None
+            and t <= _PAGED_KERNEL_MAX_TQ):
+        # paged decode / verify / prefill chunk: attention in absorbed form
+        # over the latent pages themselves. W_uk folds into the query and
+        # W_uv applies after attention, so the scores are
+        # [q_nope W_uk | q_rope] . [c_kv | k_rope] and the output
+        # softmax . c_kv: one shared "head" of width r_kv + dr, read once,
+        # with no per-head K or V built from the pool.
+        pages = write_cache_paged(cache["ckv"], compressed, cache_pos,
+                                  block_tables, kv_block_size, layer)
+        with jax.named_scope(scopes.LATENT_ATTENTION):
+            q_lat = jnp.einsum("bthn,rhn->bthr", q_nope,
+                               params["w_uk"].astype(x.dtype))
+            o_lat = _latent_attend(
+                jnp.concatenate([q_lat, q_rope], axis=-1),
+                *layer_pages(pages, block_tables, layer), cache_pos + t,
+                positions, rank=r_kv, scale=scale)
+            out = jnp.einsum("bthr,rhv->bthv", o_lat,
+                             params["w_uv"].astype(x.dtype))
+        y = ops.rowparallel_einsum("bthk,hkd->btd", out,
+                                   params["wo"].astype(x.dtype),
+                                   x_axis=-2, w_axis=0)
+        return ops.gather_activation(y), {"ckv": pages}
 
     if cache is not None and block_tables is not None:
-        # paged MLA: the compressed KV pages gather into a per-row view;
-        # the projection to full K/V below is shared with the other paths.
-        # Quantized pools dequantize at the gather (MLA's mixed head dims
-        # never hit the fused GQA kernels).
+        # paged MLA otherwise (tree verification, quantized pools, long
+        # prefill windows): the compressed KV pages gather into a per-row
+        # view, projected to full K/V below as on the other paths.
+        # Quantized pools dequantize at the gather.
         if "ckv_scale" in cache:
             qc, sc = quantize_kv(compressed, cache["ckv"].dtype)
             pages = write_cache_paged(cache["ckv"], qc, cache_pos,
@@ -735,7 +821,7 @@ def mla_apply(params, cfg, x, positions, *, cache=None, cache_pos=None,
         kv_pos = positions
         kv_len = t
 
-    ckv_all, k_rope_all = kv_src[..., :r_kv], kv_src[..., r_kv:]
+    ckv_all, k_rope_all = kv_src[..., :r_kv], kv_src[..., r_kv:r_kv + dr]
     k_nope = jnp.einsum("bsr,rhk->bshk", ckv_all.astype(x.dtype),
                         params["w_uk"].astype(x.dtype))
     v = jnp.einsum("bsr,rhk->bshk", ckv_all.astype(x.dtype),
@@ -744,12 +830,10 @@ def mla_apply(params, cfg, x, positions, *, cache=None, cache_pos=None,
                                 (b, kv_src.shape[1], h, dr))
     k = jnp.concatenate([k_nope, k_rope_b], axis=-1)
     qfull = jnp.concatenate([q_nope, q_rope], axis=-1)
-    scale = 1.0 / math.sqrt(dn + dr)
 
     out = attend(qfull, k, v, positions, kv_pos, kv_len, causal=True,
                  scale=scale, mask_info=mask_info if cache is None else None,
                  tree_info=tree_info if cache is not None else None)
-    from ..kernels import ops
     y = ops.rowparallel_einsum("bthk,hkd->btd", out,
                                params["wo"].astype(x.dtype),
                                x_axis=-2, w_axis=0)

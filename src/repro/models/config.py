@@ -55,6 +55,12 @@ class ModelConfig:
     # --- attention flavour -------------------------------------------------
     attn_kind: str = "gqa"      # gqa | mla
     rope_theta: float = 10000.0
+    # YaRN rope scaling (DeepSeek-V2's DeepseekV2YarnRotaryEmbedding);
+    # yarn_factor 0 = plain rope. Applied to MLA's rope channels, with the
+    # published betas and original context (layers.YARN_*) and mscale
+    # equal to mscale_all_dim, so that cos and sin are not rescaled.
+    yarn_factor: float = 0.0
+    yarn_mscale_all_dim: float = 0.0
     sliding_window: int = 0             # 0 = no local layers
     local_global_period: int = 0        # gemma2: 2 -> alternate local/global
     attn_softcap: float = 0.0           # gemma2 attention logit softcap
@@ -87,6 +93,11 @@ class ModelConfig:
     first_dense_layers: int = 0         # deepseek-v2: leading dense layers
     first_dense_d_ff: int = 0
     moe_capacity_factor: float = 1.25
+    moe_norm_topk: bool = True          # renormalise the top-k gates
+    # expert parallelism: the router spans all ``moe_num_experts``, this
+    # device holds experts 0 .. moe_experts_held - 1 (0 = all of them) and
+    # computes their part of the layer for the tokens routed to them
+    moe_experts_held: int = 0
 
     # --- SSM / hybrid ------------------------------------------------------
     ssm_state: int = 0
@@ -113,6 +124,10 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // self.n_heads
+
+    @property
+    def resolved_experts_held(self) -> int:
+        return self.moe_experts_held or self.moe_num_experts
 
     @property
     def padded_vocab(self) -> int:
@@ -154,6 +169,7 @@ class ModelConfig:
                            qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32)
         if self.moe_num_experts:
             changes.update(moe_num_experts=4, moe_top_k=min(self.moe_top_k, 2),
+                           moe_experts_held=min(self.moe_experts_held, 4),
                            moe_num_shared=min(self.moe_num_shared, 1),
                            moe_d_ff=min(self.moe_d_ff or self.d_ff, 128),
                            first_dense_layers=min(self.first_dense_layers, 1))
@@ -235,17 +251,25 @@ class ScanPlan:
 
 
 def scan_plan(cfg: ModelConfig) -> ScanPlan:
+    """The decomposition with the fewest distinct layer bodies (prefix
+    layers + period length), the shorter prefix on a tie: deepseek-v2's
+    leading dense layer then runs unrolled and its MoE layers as one
+    scanned period, rather than the whole stack as a single period."""
     plan = layer_plan(cfg)
     n = len(plan)
-    # find smallest period p and prefix q such that plan[q:] is p-periodic
-    for prefix_len in range(0, n + 1):
+    best = None
+    for prefix_len in range(n + 1):
+        if best is not None and prefix_len >= len(best.prefix) + len(
+                best.period):
+            break            # a longer prefix alone has as many bodies
         rest = plan[prefix_len:]
-        if not rest:
-            return ScanPlan(plan, (), 0)
-        for p in range(1, len(rest) + 1):
-            if len(rest) % p:
-                continue
-            period = rest[:p]
-            if all(rest[i] == period[i % p] for i in range(len(rest))):
-                return ScanPlan(plan[:prefix_len], period, len(rest) // p)
-    raise AssertionError("unreachable")
+        # smallest period p such that plan[prefix_len:] is p-periodic
+        p = next((p for p in range(1, len(rest) + 1)
+                  if len(rest) % p == 0
+                  and all(rest[i] == rest[i % p] for i in range(len(rest)))),
+                 0)
+        if best is None or prefix_len + p < len(best.prefix) + len(
+                best.period):
+            best = ScanPlan(plan[:prefix_len], rest[:p],
+                            len(rest) // p if p else 0)
+    return best

@@ -12,6 +12,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from . import scopes
+
 Array = jax.Array
 
 # ---------------------------------------------------------------------------
@@ -60,14 +62,59 @@ def make_norm(cfg):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float) -> Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention-temperature factor (DeepSeek-V2 ``yarn_get_mscale``)."""
+    if scale <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
 
 
-def apply_rope(x: Array, positions: Array, theta: float) -> Array:
-    """x: [..., seq, heads, head_dim]; positions: [..., seq] (int)."""
+# DeepSeek-V2's published YaRN constants
+YARN_ORIGINAL_MAX_POS = 4096
+YARN_BETA_FAST = 32
+YARN_BETA_SLOW = 1
+
+
+def yarn_correction_range(cfg, dim: int):
+    """The rope channel pairs [low, high] over which YaRN ramps from the
+    original frequencies to the interpolated ones (DeepSeek-V2
+    ``yarn_find_correction_range``): below ``low`` a pair turns more than
+    ``YARN_BETA_FAST`` times over the original context and keeps its
+    frequency, above ``high`` less than ``YARN_BETA_SLOW`` times and is
+    divided by the factor."""
+    def pair(rotations):
+        return dim * math.log(YARN_ORIGINAL_MAX_POS
+                              / (rotations * 2 * math.pi)) / (
+            2 * math.log(cfg.rope_theta))
+    low = math.floor(pair(YARN_BETA_FAST))
+    high = math.ceil(pair(YARN_BETA_SLOW))
+    return max(low, 0), min(high, dim - 1)
+
+
+def rope_freqs(head_dim: int, theta: float, yarn_cfg=None) -> Array:
+    """Inverse frequencies of the ``head_dim // 2`` rope pairs; with a
+    ``yarn_cfg`` (a ModelConfig with ``yarn_factor`` set) YaRN's blend of
+    the original and the factor-divided frequencies."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    if yarn_cfg is None or not yarn_cfg.yarn_factor:
+        return freqs
+    low, high = yarn_correction_range(yarn_cfg, head_dim)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / yarn_cfg.yarn_factor * ramp + freqs * (1.0 - ramp)
+
+
+def apply_rope(x: Array, positions: Array, theta: float,
+               yarn_cfg=None) -> Array:
+    """x: [..., seq, heads, head_dim]; positions: [..., seq] (int).
+    ``yarn_cfg``: YaRN frequencies. DeepSeek-V2 also scales cos and sin by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim), which is 1:
+    its published config gives the two mscales the same value."""
     head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)                    # [hd/2]
+    freqs = rope_freqs(head_dim, theta, yarn_cfg)          # [hd/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., seq, hd/2]
     angles = angles[..., None, :]                          # broadcast over heads
     cos, sin = jnp.cos(angles), jnp.sin(angles)
@@ -166,63 +213,102 @@ def mlp_apply(params, x: Array, act: str = "silu") -> Array:
 
 
 # ---------------------------------------------------------------------------
-# MoE — GShard-style dense dispatch with capacity (TPU-friendly, static shapes)
+# MoE: a router over every expert, and the experts this device holds
 # ---------------------------------------------------------------------------
 
 def init_moe(key, cfg):
-    e = cfg.moe_num_experts
+    """Router over all ``moe_num_experts``; the expert weights of the
+    ``resolved_experts_held`` experts this device holds (expert
+    parallelism: the others live on other devices)."""
+    e, held = cfg.moe_num_experts, cfg.resolved_experts_held
     d, f = cfg.d_model, (cfg.moe_d_ff or cfg.d_ff)
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     s_in, s_out = 1.0 / math.sqrt(d), 1.0 / math.sqrt(f)
     p = {
         "router": jax.random.normal(k1, (d, e), jnp.float32) * s_in,
-        "we_i": jax.random.normal(k2, (e, d, f), jnp.float32) * s_in,
-        "we_g": jax.random.normal(k3, (e, d, f), jnp.float32) * s_in,
-        "we_o": jax.random.normal(k4, (e, f, d), jnp.float32) * s_out,
+        "we_i": jax.random.normal(k2, (held, d, f), jnp.float32) * s_in,
+        "we_g": jax.random.normal(k3, (held, d, f), jnp.float32) * s_in,
+        "we_o": jax.random.normal(k4, (held, f, d), jnp.float32) * s_out,
     }
     if cfg.moe_num_shared:
         p["shared"] = init_mlp(k5, d, f * cfg.moe_num_shared)
     return p
 
 
-def moe_apply(params, x: Array, cfg, return_aux: bool = False,
-              dropless: bool = False, group_size: int = 256):
-    """x: [B, T, D]. Top-k routing with GROUPED GShard one-hot dispatch:
-    tokens are split into groups of ``group_size``; each group dispatches to
-    per-group expert capacity ``cap = factor * g * k / e``. Everything is
-    einsum/one-hot — no sort, no scatter — which is what GSPMD partitions
-    well (a distributed argsort at 1M tokens compiles pathologically, and
-    the ungrouped one-hot dispatch tensor [n, e, n*k/e] is O(n^2)).
-
-    Dispatch-einsum overhead is G*g*e*cap*d = n*e*cap*d, a few percent of
-    the expert FLOPs at g=256.
-
-    ``dropless=True`` (decode/verify) uses ONE group with capacity = n so no
-    token can ever be dropped — routing must be independent of batch
-    composition or lossless speculative decoding would diverge from AR.
-    """
-    b, t, d = x.shape
-    e, k = cfg.moe_num_experts, cfg.moe_top_k
-    xt = x.reshape(b * t, d)
-    n = b * t
-    logits = jnp.einsum("nd,de->ne", xt, params["router"].astype(x.dtype)).astype(jnp.float32)
+def moe_route(params, xt: Array, cfg):
+    """Softmax over every expert's logit, then the top-k. The gates are
+    renormalised over the k (``moe_norm_topk``) or, as DeepSeek-V2-Lite
+    publishes, left as they are (its ``routed_scaling_factor`` is 1).
+    xt: [n, d] -> (probs [n, e] f32, gates [n, k] f32, expert ids [n, k])."""
+    logits = jnp.einsum("nd,de->ne", xt, params["router"].astype(xt.dtype)
+                        ).astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)                  # [n, e]
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)            # [n, k]
-    gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-9)
+    gate_vals, gate_idx = jax.lax.top_k(probs, cfg.moe_top_k)  # [n, k]
+    if cfg.moe_norm_topk:
+        gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True)
+                                 + 1e-9)
+    return probs, gate_vals, gate_idx
 
-    if dropless:
-        g = n
-        cap = n
-    else:
-        g = min(group_size, n)
-        while n % g:
-            g //= 2
-        cap = max(4, int(cfg.moe_capacity_factor * g * k / e))
-        cap = min(cap, g)
+
+def moe_held_experts(params, xt: Array, gate_vals: Array, gate_idx: Array,
+                     first_expert: int = 0):
+    """The held experts' part of the layer, dropless: every (token, expert)
+    pair routed to an expert held here is computed, and no other. The
+    pairs are sorted by expert and each held expert's weights multiply its
+    run of rows in one grouped matmul (``ops.grouped_matmul``); pairs
+    routed to experts held elsewhere sort last, past every group, and are
+    not computed. Held experts are ``first_expert`` .. ``first_expert + E``,
+    E the leading axis of the expert weights.
+
+    xt: [n, d]; gate_vals, gate_idx: [n, k].
+    Returns (y [n, d], rows: int32 scalar count of the rows computed)."""
+    n, d = xt.shape
+    k = gate_idx.shape[1]
+    held = params["we_i"].shape[0]
+    local = gate_idx.reshape(-1) - first_expert                # [n*k]
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.sum(group[:, None] == jnp.arange(held)[None, :], axis=0,
+                    dtype=jnp.int32)                            # [E]
+    rows = jnp.sum(sizes)
+    xs = jnp.take(xt, order // k, axis=0)                       # [n*k, d]
+    dt = xt.dtype
+    from ..kernels import ops
+    hi = ops.grouped_matmul(xs, params["we_i"].astype(dt), sizes)
+    hg = ops.grouped_matmul(xs, params["we_g"].astype(dt), sizes)
+    ye = ops.grouped_matmul(jax.nn.silu(hg) * hi, params["we_o"].astype(dt),
+                            sizes)                               # [n*k, d]
+    # rows past the groups hold no result: zero them, then return each
+    # pair to its (token, slot) place and sum a token's slots
+    ye = jnp.where((jnp.arange(n * k) < rows)[:, None], ye, 0)
+    back = jnp.argsort(order)
+    w = jnp.where(mine, gate_vals.reshape(-1), 0.0)             # [n*k]
+    y = jnp.sum((jnp.take(ye, back, axis=0).astype(jnp.float32)
+                 * w[:, None]).reshape(n, k, d), axis=1)
+    return y.astype(dt), rows
+
+
+def _moe_capacity(params, xt, gate_vals, gate_idx, cfg, group_size):
+    """GShard-style grouped one-hot dispatch with per-group expert capacity
+    ``cap = factor * g * k / e`` (training). Everything is einsum/one-hot —
+    no sort, no scatter — which is what GSPMD partitions well (a
+    distributed argsort at 1M tokens compiles pathologically, and the
+    ungrouped one-hot dispatch tensor [n, e, n*k/e] is O(n^2)). Dispatch
+    overhead is G*g*e*cap*d = n*e*cap*d, a few percent of the expert FLOPs
+    at g=256. Tokens past an expert's capacity are dropped. Returns
+    (y [n, d], rows computed, one-hot [n, k, e])."""
+    n, d = xt.shape
+    e, k = cfg.moe_num_experts, cfg.moe_top_k
+    held = params["we_i"].shape[0]
+    g = min(group_size, n)
+    while n % g:
+        g //= 2
+    cap = min(max(4, int(cfg.moe_capacity_factor * g * k / e)), g)
     ng = n // g
 
     idx_g = gate_idx.reshape(ng, g, k)
-    gate_g = gate_vals.reshape(ng, g, k).astype(x.dtype)
+    gate_g = gate_vals.reshape(ng, g, k).astype(xt.dtype)
     x_g = xt.reshape(ng, g, d)
 
     # rank of each (token, slot) within its expert, per group
@@ -231,34 +317,62 @@ def moe_apply(params, x: Array, cfg, return_aux: bool = False,
     rank = (jnp.cumsum(flat, axis=1) - flat).reshape(ng, g, k, e)
     pos = jnp.sum(rank * onehot, axis=-1)                    # [G, g, k]
     keep = pos < cap
-    gate_g = gate_g * keep.astype(x.dtype)
+    gate_g = gate_g * keep.astype(xt.dtype)
 
-    pos_oh = jax.nn.one_hot(jnp.where(keep, pos, cap), cap, dtype=x.dtype)
-    oh = onehot.astype(x.dtype)                              # [G, g, k, e]
-    disp = jnp.einsum("Ggke,Ggkc->Ggec", oh, pos_oh)         # [G, g, e, cap]
+    pos_oh = jax.nn.one_hot(jnp.where(keep, pos, cap), cap, dtype=xt.dtype)
+    oh = onehot[..., :held].astype(xt.dtype)                 # held experts
+    disp = jnp.einsum("Ggke,Ggkc->Ggec", oh, pos_oh)         # [G, g, E, cap]
     comb = jnp.einsum("Ggec,Ggk,Ggke->Ggec", disp, gate_g, oh)
 
-    xe = jnp.einsum("Ggec,Ggd->Gecd", disp, x_g)             # [G, e, cap, d]
-    hi = jnp.einsum("Gecd,edf->Gecf", xe, params["we_i"].astype(x.dtype))
-    hg = jnp.einsum("Gecd,edf->Gecf", xe, params["we_g"].astype(x.dtype))
+    xe = jnp.einsum("Ggec,Ggd->Gecd", disp, x_g)             # [G, E, cap, d]
+    hi = jnp.einsum("Gecd,edf->Gecf", xe, params["we_i"].astype(xt.dtype))
+    hg = jnp.einsum("Gecd,edf->Gecf", xe, params["we_g"].astype(xt.dtype))
     he = jax.nn.silu(hg) * hi
     from ..kernels import ops
     # d_ff-sharded row-parallel down-projection seam (throughput ruleset)
     ye = ops.rowparallel_einsum("Gecf,efd->Gecd", he,
-                                params["we_o"].astype(x.dtype),
+                                params["we_o"].astype(xt.dtype),
                                 x_axis=-1, w_axis=1)
     y = jnp.einsum("Ggec,Gecd->Ggd", comb, ye).reshape(n, d)
+    return y, jnp.int32(ng * held * cap), onehot.reshape(n, k, e)
 
-    if "shared" in params:
-        y = y + mlp_apply(params["shared"], xt)
+
+def moe_apply(params, x: Array, cfg, return_aux: bool = False,
+              dropless: bool = False, group_size: int = 256):
+    """x: [B, T, D]. Top-k routing over every expert, then the part of the
+    layer that the held experts give, plus the shared experts.
+
+    ``dropless=True`` (every cached forward: serving prefill chunks,
+    decode and verify) computes each routed pair on a held expert
+    (``moe_held_experts``): routing must be independent of batch
+    composition or lossless speculative decoding would diverge from AR.
+    Otherwise (training) the capacity dispatch of ``_moe_capacity``.
+    ``aux["moe_rows"]``: the expert rows computed."""
+    b, t, d = x.shape
+    e, k = cfg.moe_num_experts, cfg.moe_top_k
+    xt = x.reshape(b * t, d)
+    n = b * t
+    with jax.named_scope(scopes.MOE_ROUTE):
+        probs, gate_vals, gate_idx = moe_route(params, xt, cfg)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        if dropless:
+            y, rows = moe_held_experts(params, xt, gate_vals, gate_idx)
+            onehot = None
+        else:
+            y, rows, onehot = _moe_capacity(params, xt, gate_vals, gate_idx,
+                                            cfg, group_size)
+        if "shared" in params:
+            y = y + mlp_apply(params["shared"], xt)
+    from ..kernels import ops
     y = ops.gather_activation(y).reshape(b, t, d)
 
     if return_aux:
         # Switch-style load balance loss
+        if onehot is None:
+            onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.int32)
         me = jnp.mean(probs, axis=0)                         # [e]
-        ce = jnp.mean(jnp.sum(onehot.reshape(n, k, e), axis=1
-                              ).astype(jnp.float32), axis=0)
+        ce = jnp.mean(jnp.sum(onehot, axis=1).astype(jnp.float32), axis=0)
         aux = e * jnp.sum(me * ce)
-        return y, {"load_balance_loss": aux,
-                   "expert_fraction": ce}
+        return y, {"load_balance_loss": aux, "expert_fraction": ce,
+                   "moe_rows": rows}
     return y

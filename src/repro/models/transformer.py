@@ -190,15 +190,14 @@ def _apply_layer(lp, cfg: ModelConfig, spec: LayerSpec, x, positions, *,
     if spec.mlp != MLP_NONE:
         h2 = norm(lp["norm2"], x)
         if spec.mlp == MLP_MOE:
-            # dropless routing on decode/verify paths: routing must not
-            # depend on batch shape or speculative decoding loses
-            # losslessness. Long prefills use capacity routing (capacity=n
-            # would make the expert batch O(n^2) — industry standard is to
-            # accept capacity drops at prefill).
-            dropless = cache_pos is not None and x.shape[1] <= 64
+            # dropless routing on every cached forward (prefill, decode,
+            # verify): routing must not depend on batch composition or
+            # speculative decoding loses losslessness. Training keeps
+            # capacity routing.
             m, moe_aux = L.moe_apply(lp["mlp"], h2, cfg, return_aux=True,
-                                     dropless=dropless)
+                                     dropless=cache_pos is not None)
             aux["load_balance_loss"] = moe_aux["load_balance_loss"]
+            aux["moe_rows"] = moe_aux["moe_rows"]
         else:
             m = L.mlp_apply(lp["mlp"], h2, act=cfg.mlp_act)
         if cfg.post_block_norms:
@@ -245,7 +244,9 @@ def forward(params, cfg: ModelConfig, tokens: Array, positions=None, *,
                   speculative candidate tree; pass explicit depth-based
                   ``positions`` alongside (DESIGN.md §6)
 
-    Returns (logits [B, T, padded_vocab], new_caches, aux).
+    Returns (logits [B, T, padded_vocab], new_caches, aux); ``aux``
+    holds the MoE load-balance loss, the pre-final-norm ``hidden`` states
+    and ``moe_rows``, the expert rows the MoE layers computed.
     """
     plan = scan_plan(cfg)
     b, t = tokens.shape
@@ -260,6 +261,7 @@ def forward(params, cfg: ModelConfig, tokens: Array, positions=None, *,
 
     _, norm = L.make_norm(cfg)
     aux_total = jnp.zeros((), jnp.float32)
+    rows = jnp.zeros((), jnp.int32)          # expert rows computed
     new_caches = {"prefix": [], "scan": []}
 
     def run(lp, spec, x, cache, layer=None):
@@ -276,6 +278,7 @@ def forward(params, cfg: ModelConfig, tokens: Array, positions=None, *,
         x, nc, aux = run(params["prefix"][i], spec, x, cache)
         new_caches["prefix"].append(nc)
         aux_total = aux_total + aux.get("load_balance_loss", 0.0)
+        rows = rows + aux.get("moe_rows", 0)
 
     # ---- scanned periods ----
     if plan.n_repeats:
@@ -293,7 +296,7 @@ def forward(params, cfg: ModelConfig, tokens: Array, positions=None, *,
                    else [{} for _ in period])
 
         def body(carry, xs):
-            x, aux_acc, pools = carry
+            x, aux_acc, rows_acc, pools = carry
             lps, cs = xs[:2]
             new_cs, new_pools = [], []
             for j, spec in enumerate(period):
@@ -307,7 +310,8 @@ def forward(params, cfg: ModelConfig, tokens: Array, positions=None, *,
                     new_pools.append({})
                 new_cs.append(nc)
                 aux_acc = aux_acc + aux.get("load_balance_loss", 0.0)
-            return (x, aux_acc, tuple(new_pools)), tuple(new_cs)
+                rows_acc = rows_acc + aux.get("moe_rows", 0)
+            return (x, aux_acc, rows_acc, tuple(new_pools)), tuple(new_cs)
 
         if remat:
             body = jax.checkpoint(body)
@@ -317,8 +321,8 @@ def forward(params, cfg: ModelConfig, tokens: Array, positions=None, *,
               tuple({} if k else c for c, k in zip(scan_in, carried)))
         if any(carried):
             xs += (jnp.arange(plan.n_repeats, dtype=jnp.int32),)
-        (x, aux_total, pools), scan_caches = jax.lax.scan(
-            body, (x, aux_total, pools), xs)
+        (x, aux_total, rows, pools), scan_caches = jax.lax.scan(
+            body, (x, aux_total, rows, pools), xs)
         new_caches["scan"] = [p if k else c for p, c, k
                               in zip(pools, scan_caches, carried)]
 
@@ -336,4 +340,4 @@ def forward(params, cfg: ModelConfig, tokens: Array, positions=None, *,
     from ..kernels import ops
     logits = ops.gather_activation(logits)
     return logits, (new_caches if caches is not None else None), \
-        {"load_balance_loss": aux_total, "hidden": hidden}
+        {"load_balance_loss": aux_total, "hidden": hidden, "moe_rows": rows}

@@ -23,7 +23,8 @@ Stepping is split into a non-blocking ``dispatch`` and a blocking
 (retirement mask, per-row template re-selection, commit-limit freeze)
 folded in AHEAD of the inner step — and returns a ``StepHandle`` of device
 futures immediately; ``harvest`` materializes every per-step output
-``(a, rank, rhist, live, n, gen)`` in a single batched ``jax.device_get``
+``(a, rank, rhist, live, n, gen, moe_rows)`` in a single batched
+``jax.device_get``
 instead of one transfer per array. The handle's arrays are ordinary jit
 OUTPUTS, distinct buffers from the ones inside the returned ``DecodeState``
 — donating the state into the next dispatch therefore never invalidates a
@@ -63,6 +64,8 @@ class StepHandle:
     live: Any                 # [B] bool — rows the step commits tokens for
     n: Any                    # [B] post-step committed counts
     gen: Any                  # [B, L] post-step token buffer
+    moe_rows: Any             # int32 scalar: expert rows the step computed
+    #                           (None without MoE layers)
     n_draft: int
     tree_sel: Optional[np.ndarray] = None
     # scheduler-stamped: rid per slot at dispatch time (-1 = empty). A slot
@@ -87,6 +90,7 @@ class StepResult:
     live: np.ndarray
     n: np.ndarray
     gen: np.ndarray
+    moe_rows: int = 0
 
 
 def _zero_ssm_rows(cfg: ModelConfig, cache, slot: int):
@@ -210,7 +214,9 @@ class Executor:
             tree_idx=(jnp.zeros((max_batch,), jnp.int32)
                       if dec.tree is not None else None),
             pf_pos=jnp.zeros((max_batch,), jnp.int32),
-            pf_len=jnp.zeros((max_batch,), jnp.int32))
+            pf_len=jnp.zeros((max_batch,), jnp.int32),
+            moe_rows=(jnp.zeros((), jnp.int32) if dec.counts_moe_rows
+                      else None))
         if mesh is not None:
             self._state_sh = self._state_shardings()
             self.state = jax.device_put(self.state, self._state_sh)
@@ -343,6 +349,9 @@ class Executor:
         from host mirrors, which run one step ahead of unharvested
         results — and re-returns ``n``/``gen`` as explicit outputs so a
         harvest needs no read of the (soon-to-be-donated) state.
+        ``_step_fn`` adds the step's ``state.moe_rows`` as one more
+        output, for the same reason (None, no output, without MoE
+        layers).
 
         The (target, draft) params are the step's FIRST argument, never
         closure constants: at published widths constants would embed
@@ -401,11 +410,12 @@ class Executor:
             # partial/gather_activation hints bake in (§11/§13)
             from ..kernels import ops as _ops
             with _ops.activation_mesh(self.mesh, self.tp_ruleset):
-                self.state, a, rank, rhist, live, n, gen = step_fn(*args)
+                self.state, a, rank, rhist, live, n, gen, rows = step_fn(
+                    *args)
         else:
-            self.state, a, rank, rhist, live, n, gen = step_fn(*args)
+            self.state, a, rank, rhist, live, n, gen, rows = step_fn(*args)
         return StepHandle(a=a, rank=rank, rhist=rhist, live=live, n=n,
-                          gen=gen, n_draft=self._n_draft,
+                          gen=gen, moe_rows=rows, n_draft=self._n_draft,
                           tree_sel=None if tree_sel is None
                           else np.asarray(tree_sel))
 
@@ -415,16 +425,18 @@ class Executor:
         to call after later dispatches: the handle's arrays are distinct
         jit-output buffers, untouched by the state donation."""
         if handle.a is None:                          # mode="ar"
-            live, n, gen = jax.device_get(
-                (handle.live, handle.n, handle.gen))
+            live, n, gen, rows = jax.device_get(
+                (handle.live, handle.n, handle.gen, handle.moe_rows))
             return StepResult(None, None, None, np.asarray(live),
-                              np.asarray(n), np.asarray(gen))
-        a, rank, rhist, live, n, gen = jax.device_get(
+                              np.asarray(n), np.asarray(gen),
+                              0 if rows is None else int(rows))
+        a, rank, rhist, live, n, gen, rows = jax.device_get(
             (handle.a, handle.rank, handle.rhist, handle.live, handle.n,
-             handle.gen))
+             handle.gen, handle.moe_rows))
         return StepResult(np.asarray(a), np.asarray(rank),
                           np.asarray(rhist), np.asarray(live),
-                          np.asarray(n), np.asarray(gen))
+                          np.asarray(n), np.asarray(gen),
+                          0 if rows is None else int(rows))
 
     def step(self, any_prefilling: bool = True):
         """One SYNCHRONOUS fused step (dispatch + immediate harvest, no
@@ -477,8 +489,14 @@ class Executor:
                self.replica, self.tp_ruleset)
         if key in self._step_fns:
             return self._step_fns[key]
-        fused = self._build_fused(variant, apply_tree=apply_tree,
+        inner = self._build_fused(variant, apply_tree=apply_tree,
                                   greedy_only=greedy_only)
+
+        def fused(params, state, retire, tree_sel, limits):
+            # the step's expert-row count, also as an output of its own
+            out = inner(params, state, retire, tree_sel, limits)
+            return out + (out[0].moe_rows,)
+
         if self.mesh is None:
             fn = jax.jit(fused, donate_argnums=(1,))
         else:
@@ -490,7 +508,7 @@ class Executor:
                 fused, donate_argnums=(1,),
                 in_shardings=(param_sh, self._state_sh, repl, repl, repl),
                 out_shardings=(self._state_sh, aux, aux, aux,
-                               repl, repl, repl))
+                               repl, repl, repl, repl))
         self._step_fns[key] = fn
         return fn
 

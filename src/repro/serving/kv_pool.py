@@ -46,7 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import ssm as ssm_mod
-from ..models.attention import kv_dtype_is_quantized, resolve_kv_dtype
+from ..models.attention import (kv_dtype_is_quantized, mla_pool_width,
+                                resolve_kv_dtype)
 from ..models.config import (ATTN_CROSS, ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA,
                              SSM, ModelConfig, scan_plan)
 
@@ -88,8 +89,8 @@ def _paged_layer_cache(cfg: ModelConfig, spec, num_blocks, block_size, batch,
                                     jnp.float32)
         return c
     if spec.mixer == ATTN_MLA:
-        width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
-        c = {"ckv": jnp.zeros((num_blocks, block_size, width), dtype)}
+        c = {"ckv": jnp.zeros((num_blocks, block_size, mla_pool_width(cfg)),
+                              dtype)}
         if quant:
             # one scale per compressed-KV vector
             c["ckv_scale"] = jnp.ones((num_blocks, block_size), jnp.float32)

@@ -353,7 +353,10 @@ class Scheduler:
             **spans.counters(),
             # paged KV: pages the occupied rows' contexts cover, and the
             # pages the attention kernels' grid steps over (see dispatch)
-            kv_pages_live=0, kv_pages_grid=0)
+            kv_pages_live=0, kv_pages_grid=0,
+            # expert rows the MoE layers computed on this device, summed
+            # from each step's device count at harvest
+            moe_rows=0)
         if bank is not None:
             self.stats["tree_hist"] = np.zeros(len(bank), np.int64)
             self.stats["tree_switches"] = 0
@@ -720,6 +723,7 @@ class Scheduler:
         previous harvest may legitimately run one final in-flight step)."""
         live = res.live
         n_live = int(live.sum())
+        self.stats["moe_rows"] += res.moe_rows
         if res.a is not None:
             self.stats["accepted"] += int(res.a.sum())
             self.stats["live_steps"] += n_live

@@ -24,6 +24,9 @@ from typing import Dict, Optional
 
 import jax
 
+# inside a forward (models/attention.py, models/layers.py)
+from ..models.scopes import LATENT_ATTENTION, MOE_EXPERTS, MOE_ROUTE
+
 PREFIX = "repro."
 PHASES = ("admit", "sync_tables", "dispatch", "harvest_wait", "harvest")
 
@@ -35,6 +38,7 @@ COMMIT = "commit"              # the gen write, n/m and the state update
 TREE_COMPACT = "tree_compact"  # compact_tree_caches
 STAGED = "staged_mutations"    # the fused wrapper's retire/tree/limit fold
 SCOPES = (DRAFT, TARGET, ACCEPT, COMMIT, TREE_COMPACT, STAGED)
+LAYER_SCOPES = (LATENT_ATTENTION, MOE_ROUTE, MOE_EXPERTS)
 
 
 def counters() -> Dict[str, int]:

@@ -2,6 +2,7 @@
 device scopes in the fused step's op metadata, the Pallas kernels' names,
 the host phase counters and spans, the host-overhead samples of a loop run
 one step a call, and the paged-KV page counts."""
+import dataclasses
 import glob
 import os
 import re
@@ -81,6 +82,11 @@ KERNELS = {
             q, kp, vp, bt, kl, qp, interpret=True),
         (_sds(2, 5, 4, 32), _sds(9, 16, 2, 32), _sds(9, 16, 2, 32),
          _i32(2, 4), _i32(2), _i32(2, 5))),
+    "paged_latent_attention": (
+        lambda q, pages, bt, kl, qp: decode_attention.paged_latent_attention(
+            q, pages, bt, kl, qp, rank=32, scale=0.2, interpret=True),
+        (_sds(2, 5, 4, 48), _sds(9, 16, 128), _i32(2, 4), _i32(2),
+         _i32(2, 5))),
     "paged_tree_attention": (
         lambda q, kp, vp, bt, kl, qp, ws, anc:
             tree_attention.tree_attention_paged(
@@ -200,3 +206,79 @@ def test_kv_page_counts_match_a_hand_count(models):
     rep = eng.sched.replicas[0]
     assert rep.slot_pf[0] == rep.slot_pf_len[0]      # row 0 decoded
     assert rep.slot_pf[1] < rep.slot_pf_len[1]       # row 1 prefilled
+
+
+@pytest.mark.parametrize("mode", ["ar", "pard"])
+def test_dense_step_carries_no_moe_count(models, mode):
+    """Neither model has a MoE layer: the state holds no ``moe_rows`` and
+    the fused step returns no count (the step a dense model compiled
+    before the counter existed); ``stats["moe_rows"]`` stays 0."""
+    eng = _engine(models, mode=mode)
+    assert eng.ex.state.moe_rows is None
+    assert eng.ex.dispatch().moe_rows is None
+    _submit(eng, (6, 9))
+    eng.run()
+    assert eng.stats["moe_rows"] == 0
+
+
+# ---------------------------------------------- latent attention + experts
+def _mla_moe_engine(held=0, zero_router=False, **kw):
+    """deepseek-v2-lite cut to two layers (dense, then MoE over 4 experts,
+    top 2) serving itself; ``held`` experts on this device (0: all)."""
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b").reduced(),
+                              moe_experts_held=held)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    if zero_router:
+        # every logit equal: top-2 picks experts 0 and 1 for every token
+        mlp = params["scan"][1]["mlp"]            # the MoE layer, stacked
+        mlp["router"] = jnp.zeros_like(mlp["router"])
+    fields = dict(mode="pard", k=4, max_batch=2, max_len=256,
+                  kv_block_size=16)
+    fields.update(kw)
+    return cfg, Engine(params, cfg, params, cfg,
+                       config=EngineConfig(**fields))
+
+
+def test_mla_moe_step_carries_its_layer_scopes():
+    """Inside both forwards of the compiled step: the absorbed latent
+    attention, the router and the held experts each under their scope."""
+    _, eng = _mla_moe_engine()
+    text = eng.ex.step_hlo()
+    paths = [m for m in re.findall(r'op_name="([^"]*)"', text)
+             if m.startswith("jit(fused)/")]
+    for fwd in (spans.DRAFT, spans.TARGET):
+        inner = {part for m in paths if m.split("/")[1] == fwd
+                 for part in m.split("/")[2:]}
+        assert set(spans.LAYER_SCOPES) <= inner, (fwd, sorted(inner))
+
+
+@pytest.mark.parametrize("held,zero_router,per_token", [
+    (0, False, 2),      # every expert held: all top-2 pairs
+    (1, True, 1),       # expert 0 alone, which every token picks
+    (1, False, None),   # expert 0 alone, routed as the weights decide
+])
+def test_moe_rows_count_the_pairs_routed_to_held_experts(held, zero_router,
+                                                         per_token):
+    """``stats["moe_rows"]``: the expert rows the step's forwards computed,
+    one per (token, slot) routed to a held expert, every row of the batch
+    included (pads and frozen rows run through the forward too). Per step
+    the draft forward carries max_batch x 2k tokens and the verify forward
+    max_batch x (k + 1), through the one MoE layer."""
+    from repro.models import layers as L
+    cfg, eng = _mla_moe_engine(held, zero_router)
+    _submit(eng, (7, 20), max_new=12)
+    eng.run()
+    steps = eng.stats["steps"]
+    tokens = steps * 2 * (2 * 4 + 4 + 1)
+    if per_token is not None:
+        assert eng.stats["moe_rows"] == tokens * per_token
+    else:
+        # a random router sends some pairs elsewhere, none twice
+        assert 0 < eng.stats["moe_rows"] < tokens * cfg.moe_top_k
+    # the layer's own count agrees with a host count of its routing
+    p = jax.tree.map(lambda w: w[0], eng.ex.dec.tp["scan"][1]["mlp"])
+    x = jax.random.normal(jax.random.PRNGKey(3), (40, cfg.d_model))
+    _, _, idx = L.moe_route(p, x, cfg)
+    _, rows = L.moe_held_experts(p, x, *L.moe_route(p, x, cfg)[1:])
+    assert int(rows) == int((np.asarray(idx) < cfg.resolved_experts_held
+                             ).sum())

@@ -165,3 +165,101 @@ def test_paged_step_updates_pools_in_place_on_v5e(one_chip, monkeypatch, kind,
         set_attention_backend(None)
     assert "tpu_custom_call" in text
     assert whole_buffer_fills_and_copies(text, shapes) == []
+
+
+def _cell_step(one_chip, monkeypatch, config, layers, blocks):
+    """The fused serving step of a benchmark configuration
+    (``benchmarks/chip/configs/<config>.json``, through its reference's
+    ``model_config`` and weight layout, as shapes), at published widths cut
+    to ``layers``, with the cell's engine sizes and a pool of ``blocks``
+    pages, compiled for one v5e with the Pallas kernels in it. Returns the
+    compiled text and the (dtype, dims) of the state's pool leaves."""
+    import json
+    import pathlib
+    import sys
+
+    from repro.models.attention import set_attention_backend
+    from repro.models.config import ModelConfig
+    from repro.serving.engine import Engine, EngineConfig
+    from repro.serving.executor import NO_LIMIT
+    bench = pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    sys.path.insert(0, str(bench))
+    from chipbench import registry
+    cfg = json.loads((bench / "configs" / f"{config}.json").read_text())
+    cfg["num_hidden_layers"] = layers
+    ref = registry.reference(bench, cfg["model_type"])
+    model = ModelConfig(**ref.model_config(cfg))
+    params = jax.eval_shape(lambda k: ref.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    # the engine allocates its pools: 2 pages here, ``blocks`` compiled
+    eng = Engine(params, model, params, model, config=EngineConfig(
+        mode="pard", k=8, max_batch=32, max_len=2322, kv_block_size=64,
+        kv_dtype="bf16", kv_num_blocks=2))
+
+    def pool(cache):
+        def sds(axis):       # the page axis: 0, or 1 past the layer axis
+            return lambda x: jax.ShapeDtypeStruct(
+                x.shape[:axis] + (blocks,) + x.shape[axis + 1:], x.dtype,
+                sharding=one_chip)
+        return {"prefix": jax.tree.map(sds(0), cache["prefix"]),
+                "scan": jax.tree.map(sds(1), cache["scan"])}
+
+    st = eng.ex.state
+    state = dataclasses.replace(st, tcache=pool(st.tcache),
+                                dcache=pool(st.dcache))
+    b = eng.ex.max_batch
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (eng.ex._params, jnp.zeros((b,), bool), jnp.zeros((b,), jnp.int32),
+         jnp.full((b,), NO_LIMIT, jnp.int32)))
+    monkeypatch.setattr(ops, "_interpret", lambda flag: False)
+    set_attention_backend("pallas")
+    try:
+        text = eng.ex._step_fn("decode", False, False).lower(
+            args[0], state, *args[1:]).compile().as_text()
+    finally:
+        set_attention_backend(None)
+    shapes = {(str(x.dtype), ",".join(map(str, x.shape)))
+              for c in (state.tcache, state.dcache)
+              for x in jax.tree.leaves(c)}
+    return text, shapes
+
+
+def test_latent_moe_step_compiles_for_v5e(one_chip, monkeypatch):
+    """``dsv2-lite-ep8-selfdraft`` at its published widths (depth cut to
+    the dense layer and two MoE layers, the benchmark cell's pool of 1,185
+    pages): the fused PARD step holds the latent kernel for every layer
+    kind and model, grouped-matmul kernels for the held experts, no
+    per-head K or V built from the pool (no array spans a row's gathered
+    37 x 64 positions), and no whole-pool broadcast or copy."""
+    import re
+
+    from hlo_text import whole_buffer_fills_and_copies
+    text, shapes = _cell_step(one_chip, monkeypatch,
+                              "dsv2-lite-ep8-selfdraft", 3, 1185)
+    calls = re.findall(r"%(\S+) = \S+ custom-call\([^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    # target and draft, the unrolled dense layer and the scanned MoE body
+    assert sum(c.startswith("paged_latent_attention") for c in calls) == 4
+    assert sum(c.startswith("gmm") for c in calls) == 6   # 3 a forward
+    assert not any(c.startswith("paged_decode_attention") for c in calls)
+    dims = re.findall(r"= \w+\[([\d,]+)\]", text)
+    assert not [d for d in dims if "2368" in d.split(",")]
+    assert {s for s in shapes if s[1].endswith(",64,640")} == shapes
+    assert whole_buffer_fills_and_copies(text, shapes) == []
+
+
+def test_gqa_step_keeps_its_kernel_for_v5e(one_chip, monkeypatch):
+    """``dsq-1.5b-selfdraft`` (depth cut to 2 layers) still runs the GQA
+    paged decode kernel, target and draft, with none of the latent or
+    expert kernels, and updates its pools in place."""
+    import re
+
+    from hlo_text import whole_buffer_fills_and_copies
+    text, shapes = _cell_step(one_chip, monkeypatch, "dsq-1.5b-selfdraft",
+                              2, 1185)
+    calls = re.findall(r"%(\S+) = \S+ custom-call\([^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert len(calls) == 2
+    assert all(c.startswith("paged_decode_attention") for c in calls)
+    assert whole_buffer_fills_and_copies(text, shapes) == []
