@@ -1,4 +1,4 @@
-"""Speculative-verify / decode attention — Pallas TPU kernel.
+"""Speculative-verify / decode attention — Pallas TPU kernels.
 
 The PARD serving hot path: a small query block (1 AR token or the K+1
 verification window) attends to a long KV cache. This is the kernel the
@@ -13,29 +13,45 @@ table: ``as_pages`` reshapes it into ``[B * S/block, block, Hkv, D]`` (free:
 row-major contiguous) and numbers the blocks in order, so ONE pallas_call
 serves both layouts.
 
-Grid: (batch, table entry). Each grid step DMAs one whole block — every KV
-head of ``block`` slots — so the K/V block ``(1, block, Hkv, D)`` spans the
-array's two minor dims, which is what the TPU tiling rule requires for a
-head dim of 64. The queries of one row are regrouped to ``[Hkv, Tq*G, D]``
-(kv head major; row ``i*G + j`` = query position ``i``, group member ``j``)
-and stay resident in VMEM across the sweep; a static loop over the kv heads
-runs the online softmax per head against its ``[block, D]`` slice.
+The page sweep (``sweep_pages``), shared by the GQA and the latent kernel:
+the grid is one step per row. The pools stay in HBM (``pl.ANY``); the row's
+live pages, ``ceil(kv_len / block)`` table entries and no more, are copied
+into VMEM in groups of ``P`` pages (``group_pages``: the largest power of
+two whose group of every operand's pages fits ``GROUP_BYTES``), each page
+whole — every kv head of ``block`` slots — in one copy through the
+scalar-prefetched table. Groups alternate between the two slots of a VMEM
+buffer: while one group is scored, the copies of the next one (or of the
+next row's first group) are in flight. A group is scored as ONE
+``[rows, P * block]`` block per kv head — one QK and one PV matmul, one
+online-softmax update — so the per-step cost of the grid and of the
+softmax is paid per group, not per page. A row of ``kv_len`` 0 copies
+nothing and writes zeros.
 
-Per-row scalars (``kv_len``, and the block table) are scalar-prefetched into
-SMEM; per-query-row vectors (``q_pos``) arrive pre-expanded to ``[B, Tq*G, 1]``
-so their VMEM block spans the full minor dims as well. Blocks past a row's
-length are skipped (``pl.when``) and their index map repeats the row's last
-live block, so the pipeline issues no DMA for them either: swept bytes track
-the actual cache fill, not the allocated max_len.
+Mosaic copies a page out of an HBM pool only where the page's minor dims
+fill whole tiles (``sweep_group``). GQA pools that do not — a quantized
+pool (its scales are ``[.., Hkv]`` wide), a head dim of 64 — take one page
+a grid step instead, grid (batch, table entry), through BlockSpecs whose
+index maps read the table; entries past a row's length repeat its last
+live page (``last_block``), so no DMA is issued for them, but each still
+costs a grid step. The tree kernel (tree_attention.py) sweeps that way too.
+
+The queries of one row are regrouped to ``[Hkv, Tq*G, D]`` (kv head major;
+row ``i*G + j`` = query position ``i``, group member ``j``) and stay
+resident in VMEM across the sweep; a static loop over the kv heads runs the
+online softmax per head against its ``[P * block, D]`` slice. Per-row
+scalars (``kv_len``, and the block table) are scalar-prefetched into SMEM;
+per-query-row vectors (``q_pos``) arrive pre-expanded to ``[B, Tq*G, 1]``
+so their VMEM block spans the full minor dims.
 
 Validity is PER ROW, so one launch serves the serving engine's fused mixed
 batches (DESIGN.md §8): decoding rows sweep their long cache while
-prefilling rows' chunks (q_pos = cursor + i, kv_len = cursor + chunk) skip
-every block past their short fill.
+prefilling rows' chunks (q_pos = cursor + i, kv_len = cursor + chunk) copy
+only the few pages of their short fill.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +59,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+# A buffer slot's copy budget: a group takes the most pages, a power of
+# two, whose bytes over every operand the kernel copies fit in it. On a
+# v5e at the served shapes (64-slot pages) groups of 8 pages were faster
+# than 4 or 16, both for a bf16 K and V pool of 2 x 128 (64 KiB a page)
+# and for a 640-lane latent pool (80 KiB a page) (PERF.md).
+GROUP_BYTES = 768 * 1024
 
 
 # ---------------------------------------------------------------------------
@@ -120,11 +143,12 @@ def init_scratch(m_s, l_s, acc_s):
 
 def sweep_block(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_s, l_s, acc_s, mask,
                 *, scale, softcap):
-    """One KV block of the online softmax for every kv head. ``mask`` is the
-    [Tq*G, block] validity of this block (the same for every head)."""
+    """One KV block ``[1, n, Hkv, D]`` (a page, or a group of pages) of the
+    online softmax for every kv head. ``mask`` is the [Tq*G, n] validity of
+    this block (the same for every head)."""
     for h in range(q_ref.shape[1]):
         q = q_ref[0, h].astype(jnp.float32)                 # [rows, d]
-        k = k_ref[0, :, h, :].astype(jnp.float32)           # [block, d]
+        k = k_ref[0, :, h, :].astype(jnp.float32)           # [n, d]
         v = v_ref[0, :, h, :].astype(jnp.float32)
         if ks_ref is not None:
             # dequant fused into the sweep: the block expands against its
@@ -160,13 +184,187 @@ def split_quant_refs(rest, quant):
 
 
 # ---------------------------------------------------------------------------
+# The page sweep, shared by the GQA and the latent kernel
+# ---------------------------------------------------------------------------
+
+def page_bytes(pools):
+    """Bytes of one table entry over the page pools ``[NB, block, ...]`` a
+    kernel copies."""
+    return sum(math.prod(p.shape[1:]) * p.dtype.itemsize for p in pools)
+
+
+def group_pages(nbytes, entries):
+    """P, the pages the sweep copies and scores at once: the largest power
+    of two with ``P * nbytes <= GROUP_BYTES`` for pages of ``nbytes`` (at
+    least 1), capped at the block table's ``entries``."""
+    p = 1
+    while 2 * p * nbytes <= GROUP_BYTES:
+        p *= 2
+    return min(p, entries)
+
+
+def _copyable(pool):
+    """Whether a kernel can copy single pages out of ``pool`` [NB, ...] in
+    HBM. Mosaic slices an HBM array only where the page's two minor dims
+    fill its tiles whole: the minor dim a multiple of 128 lanes, the one
+    before it a power of two of at least the rows one sublane packs (2 for
+    bf16, 4 for 8-bit types) or a multiple of a full tile (8 sublanes)."""
+    if pool.ndim < 3 or pool.shape[-1] % 128:
+        return False
+    rows, pack = pool.shape[-2], 4 // pool.dtype.itemsize
+    return rows % (8 * pack) == 0 or (rows >= pack and rows & (rows - 1) == 0)
+
+
+def sweep_group(pools, entries):
+    """P for pools the page sweep copies itself (``group_pages``), 0 for
+    pools it cannot (``_copyable``): those take one page a grid step over
+    every table entry, through a BlockSpec."""
+    if not all(_copyable(p) for p in pools):
+        return 0
+    return group_pages(page_bytes(pools), entries)
+
+
+def sweep_scratch(pools, group):
+    """The sweep's scratch: a two-slot VMEM buffer ``[2, group * block,
+    ...]`` per pool, a DMA semaphore per slot, and the SMEM slot index."""
+    return ([pltpu.VMEM((2, group * p.shape[1]) + p.shape[2:], p.dtype)
+             for p in pools]
+            + [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)])
+
+
+def sweep_pages(bt_ref, kvlen_ref, pools, bufs, sem, slot_ref, *, group,
+                score):
+    """Sweep the grid step's row over its live pages, ``group`` at a time
+    (module docstring). ``pools``: the HBM page pools ``[NB, block, ...]``
+    the table indexes; ``bufs``, ``sem``, ``slot_ref``: ``sweep_scratch``,
+    the slot carried from row to row. ``score(slot, k_start)`` scores the
+    group held in buffer slot ``slot``, whose first key sits at absolute
+    position ``k_start``."""
+    bi, n_rows = pl.program_id(0), pl.num_programs(0)
+    block, entries = pools[0].shape[1], bt_ref.shape[1]
+
+    def live_pages(r):
+        return jnp.minimum(pl.cdiv(kvlen_ref[r], block), entries)
+
+    def each_copy(r, g, slot, act):
+        # one copy per live page of the group and pool: a page past the
+        # row's kv_len is never copied
+        n = live_pages(r)
+        for j in range(group):
+            e = g * group + j
+
+            @pl.when(e < n)
+            def _():
+                page = bt_ref[r, e]
+                for pool, buf in zip(pools, bufs):
+                    act(pltpu.make_async_copy(
+                        pool.at[page], buf.at[slot, pl.ds(j * block, block)],
+                        sem.at[slot]))
+
+    def start(cp):
+        cp.start()
+
+    def wait(cp):
+        cp.wait()
+
+    @pl.when(bi == 0)
+    def _first_row():
+        # a group's dead pages keep what its slot held before, which is
+        # masked out; zeroed once here, it is never uninitialised memory,
+        # whose NaNs would survive the mask as 0 * NaN in the PV product
+        for buf in bufs:
+            buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        slot_ref[0] = 0
+        each_copy(0, 0, 0, start)
+
+    n_groups = pl.cdiv(live_pages(bi), group)
+    has_next = bi + 1 < n_rows
+
+    @pl.when((n_groups == 0) & has_next)
+    def _pass_on():
+        each_copy(bi + 1, 0, slot_ref[0], start)
+
+    def step(g, carry):
+        slot = slot_ref[0]
+        nxt = 1 - slot
+
+        @pl.when(g + 1 < n_groups)
+        def _():
+            each_copy(bi, g + 1, nxt, start)
+
+        @pl.when((g + 1 == n_groups) & has_next)
+        def _():
+            each_copy(bi + 1, 0, nxt, start)
+
+        each_copy(bi, g, slot, wait)
+        score(slot, g * group * block)
+        slot_ref[0] = nxt
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, step, 0)
+
+
+def _sweep_call(kern, pools, block_tables, group, *, in_specs, out_spec,
+                out_shape, scratch, interpret, name):
+    """The pallas_call both kernels make: one grid step per row, the block
+    table and ``kv_len`` scalar-prefetched, the pools left in HBM."""
+    return pl.pallas_call(
+        functools.partial(kern, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(block_tables.shape[0],),
+            in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)] * len(
+                pools),
+            out_specs=out_spec,
+            scratch_shapes=sweep_scratch(pools, group) + scratch),
+        out_shape=out_shape,
+        # rows run in order: each prefetches the next one's first group
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=name,
+    )
+
+
+def key_mask(qpos_ref, kv_len, k_start, n, window=0):
+    """[rows, n] validity of the ``n`` keys from position ``k_start`` for
+    each query row: inside the row's fill, causal, and inside the sliding
+    ``window`` when one is given."""
+    qp_rows = qpos_ref[0]                                   # [rows, 1]
+    k_pos = k_start + jax.lax.broadcasted_iota(
+        jnp.int32, (qp_rows.shape[0], n), 1)
+    mask = (k_pos < kv_len) & (k_pos <= qp_rows)
+    if window:
+        mask &= k_pos > qp_rows - window
+    return mask
+
+
+# ---------------------------------------------------------------------------
 # Decode / flat verify
 # ---------------------------------------------------------------------------
 
-def _kernel(bt_ref, kvlen_ref, qpos_ref, q_ref, k_ref, v_ref, *rest, scale,
-            window, softcap, block_k, quant):
-    # bt_ref (the scalar-prefetched block table) is consumed only by the
-    # BlockSpec index maps
+def _kernel(bt_ref, kvlen_ref, qpos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+            v_buf, sem, slot_ref, m_s, l_s, acc_s, *, scale, window, softcap,
+            group):
+    kv_len = kvlen_ref[pl.program_id(0)]
+    init_scratch(m_s, l_s, acc_s)
+
+    def score(slot, k_start):
+        mask = key_mask(qpos_ref, kv_len, k_start, k_buf.shape[1], window)
+        sweep_block(q_ref, k_buf.at[pl.ds(slot, 1)], v_buf.at[pl.ds(slot, 1)],
+                    None, None, m_s, l_s, acc_s, mask, scale=scale,
+                    softcap=softcap)
+
+    sweep_pages(bt_ref, kvlen_ref, [k_hbm, v_hbm], [k_buf, v_buf], sem,
+                slot_ref, group=group, score=score)
+    write_output(o_ref, l_s, acc_s)
+
+
+def _page_kernel(bt_ref, kvlen_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
+                 scale, window, softcap, block_k, quant):
+    # one page a grid step through BlockSpecs, for pools the sweep cannot
+    # copy itself (``sweep_group``); bt_ref (the scalar-prefetched block
+    # table) is consumed only by the BlockSpec index maps
     ks_ref, vs_ref, (o_ref, m_s, l_s, acc_s) = split_quant_refs(rest, quant)
     bi, ki = pl.program_id(0), pl.program_id(1)
 
@@ -179,12 +377,7 @@ def _kernel(bt_ref, kvlen_ref, qpos_ref, q_ref, k_ref, v_ref, *rest, scale,
 
     @pl.when(k_start < kv_len)
     def _compute():
-        qp_rows = qpos_ref[0]                               # [rows, 1]
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (qp_rows.shape[0], block_k), 1)
-        mask = (k_pos < kv_len) & (k_pos <= qp_rows)
-        if window:
-            mask &= k_pos > qp_rows - window
+        mask = key_mask(qpos_ref, kv_len, k_start, block_k, window)
         sweep_block(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_s, l_s, acc_s,
                     mask, scale=scale, softcap=softcap)
 
@@ -204,6 +397,12 @@ def decode_attention_paged(q, k_pages, v_pages, block_tables, kv_len, q_pos,
     k_scale/v_scale: optional [NB, block, Hkv] float32 per-slot dequant
     scales when the pools are quantized (int8 / fp8); the scale blocks
     ride the same table indirection as their pages.
+
+    The page sweep serves pools whose pages it can copy (``sweep_group``):
+    bf16 and float32 pools at a head dim of 128. Others (a quantized pool,
+    whose scales are [.., Hkv] wide; a head dim of 64) take one page a
+    grid step over every table entry through BlockSpecs; entries past a
+    row's length repeat its last live page, so no DMA is issued for them.
     """
     b, tq, hq, d = q.shape
     block, hkv = k_pages.shape[1], k_pages.shape[2]
@@ -212,36 +411,47 @@ def decode_attention_paged(q, k_pages, v_pages, block_tables, kv_len, q_pos,
     quant = k_scale is not None
     if scale is None:
         scale = 1.0 / (d ** 0.5)
+    pools = [k_pages, v_pages]
+    if quant:
+        pools += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+    group = sweep_group(pools, block_tables.shape[1])
 
-    def entry(bi, ki, bt, kl):
-        return bt[bi, last_block(ki, kl[bi], block)]
-
-    def row_map(bi, ki, *_):
+    def row_map(bi, *_):
         return (bi, 0, 0)
 
-    def head_map(bi, ki, *_):
+    def head_map(bi, *_):
         return (bi, 0, 0, 0)
 
     in_specs = [pl.BlockSpec((1, rows, 1), row_map),                # q_pos
                 pl.BlockSpec((1, hkv, rows, d), head_map)]          # q
-    in_specs += kv_block_specs(hkv, d, block, quant, entry)
+    out_spec = pl.BlockSpec((1, hkv, rows, d), head_map)
+    out_shape = jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype)
     args = [block_tables.astype(jnp.int32), kv_len.astype(jnp.int32),
             query_rows(q_pos.astype(jnp.int32), g), group_queries(q, hkv),
-            k_pages, v_pages]
-    if quant:
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+            *pools]
+    if group:
+        out = _sweep_call(
+            functools.partial(_kernel, scale=scale, window=window,
+                              softcap=softcap),
+            pools, block_tables, group, in_specs=in_specs, out_spec=out_spec,
+            out_shape=out_shape, scratch=attention_scratch(hkv, rows, d),
+            interpret=interpret, name="paged_decode_attention")(*args)
+        return ungroup_output(out, tq)
 
-    kern = functools.partial(_kernel, scale=scale, window=window,
+    def entry(bi, ki, bt, kl):
+        return bt[bi, last_block(ki, kl[bi], block)]
+
+    kern = functools.partial(_page_kernel, scale=scale, window=window,
                              softcap=softcap, block_k=block, quant=quant)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, block_tables.shape[1]),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, hkv, rows, d), head_map),
+            in_specs=in_specs + kv_block_specs(hkv, d, block, quant, entry),
+            out_specs=out_spec,
             scratch_shapes=attention_scratch(hkv, rows, d)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
+        out_shape=out_shape,
         interpret=interpret,
         name="paged_decode_attention",
     )(*args)
@@ -269,31 +479,22 @@ def decode_attention(q, k, v, kv_len, q_pos, *, k_scale=None, v_scale=None,
 # Latent (MLA, absorbed) decode / flat verify
 # ---------------------------------------------------------------------------
 
-def _latent_kernel(bt_ref, kvlen_ref, qpos_ref, q_ref, kv_ref, o_ref, m_s,
-                   l_s, acc_s, *, scale, block_k, rank, width):
-    # bt_ref is consumed only by the BlockSpec index maps
-    bi, ki = pl.program_id(0), pl.program_id(1)
+def _latent_kernel(bt_ref, kvlen_ref, qpos_ref, q_ref, pool_ref, o_ref, buf,
+                   sem, slot_ref, m_s, l_s, acc_s, *, scale, rank, width,
+                   group):
+    kv_len = kvlen_ref[pl.program_id(0)]
+    init_scratch(m_s, l_s, acc_s)
 
-    @pl.when(ki == 0)
-    def _init():
-        init_scratch(m_s, l_s, acc_s)
-
-    kv_len = kvlen_ref[bi]
-    k_start = ki * block_k
-
-    @pl.when(k_start < kv_len)
-    def _compute():
-        qp_rows = qpos_ref[0]                               # [rows, 1]
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (qp_rows.shape[0], block_k), 1)
-        mask = (k_pos < kv_len) & (k_pos <= qp_rows)
-        c = kv_ref[0, :, :rank]                             # [block, rank]
-        # one page feeds both products: the scores over the whole row
+    def score(slot, k_start):
+        mask = key_mask(qpos_ref, kv_len, k_start, buf.shape[1])
+        kv = buf.at[slot]                                   # [n, lanes]
+        c = kv[:, :rank]                                    # [n, rank]
+        # one group feeds both products: the scores over the whole row
         # (latent part + rope part) and the values from its latent part
         dims = (((1,), (1,)), ((), ()))
         s = jax.lax.dot_general(q_ref[0, :, :rank], c, dims,
                                 preferred_element_type=jnp.float32)
-        s += jax.lax.dot_general(q_ref[0, :, rank:], kv_ref[0, :, rank:width],
+        s += jax.lax.dot_general(q_ref[0, :, rank:], kv[:, rank:width],
                                  dims, preferred_element_type=jnp.float32)
         s = jnp.where(mask, s * scale, NEG_INF)
         m_prev = m_s[...]
@@ -305,10 +506,10 @@ def _latent_kernel(bt_ref, kvlen_ref, qpos_ref, q_ref, kv_ref, o_ref, m_s,
             p.astype(c.dtype), c, preferred_element_type=jnp.float32)
         m_s[...] = m_new
 
-    @pl.when(ki == pl.num_programs(1) - 1)
-    def _final():
-        denom = jnp.where(l_s[...] == 0.0, 1.0, l_s[...])
-        o_ref[0] = (acc_s[...] / denom).astype(o_ref.dtype)
+    sweep_pages(bt_ref, kvlen_ref, [pool_ref], [buf], sem, slot_ref,
+                group=group, score=score)
+    denom = jnp.where(l_s[...] == 0.0, 1.0, l_s[...])
+    o_ref[0] = (acc_s[...] / denom).astype(o_ref.dtype)
 
 
 def paged_latent_attention(q, pages, block_tables, kv_len, q_pos, *, rank,
@@ -316,50 +517,36 @@ def paged_latent_attention(q, pages, block_tables, kv_len, q_pos, *, rank,
     """Absorbed multi-head latent attention over a paged latent pool.
 
     q: [B, Tq, H, W] absorbed queries ``[q_nope W_uk | q_rope]``, W = rank
-    + rope width; pages: [NB, block, P] pool rows ``[c_kv | k_rope | pad]``,
-    P >= W (a pool stacked over layers viewed as one,
+    + rope width; pages: [NB, block, lanes] pool rows ``[c_kv | k_rope |
+    pad]``, lanes >= W (a pool stacked over layers viewed as one,
     ``attention.layer_pages``);
     block_tables: [B, MBS] int32; kv_len: [B] int32; q_pos: [B, Tq].
     Returns [B, Tq, H, rank]: softmax(scale * q . row) . c_kv.
 
     Every query head shares the one latent "head": a row's Tq x H queries
     are one [Tq*H, W] block (row i*H + j = position i, head j), resident in
-    VMEM across the sweep. Grid (batch, table entry); each grid step DMAs
-    one page, whose whole rows are the keys and whose first ``rank``
-    channels are the values. The matmuls take bfloat16 operands with
-    float32 accumulation; the online softmax is float32. Pages past a
-    row's length are skipped and their DMA elided, as in
-    ``decode_attention_paged``."""
+    VMEM across the sweep. The page sweep copies the row's live pages a
+    group at a time; a group's whole rows are the keys and their first
+    ``rank`` channels the values. The matmuls take bfloat16 operands with
+    float32 accumulation; the online softmax is float32."""
     b, tq, h, w = q.shape
-    block, pw = pages.shape[1], pages.shape[2]
     rows = tq * h
 
-    def entry(bi, ki, bt, kl):
-        return bt[bi, last_block(ki, kl[bi], block)]
-
-    def row_map(bi, ki, *_):
+    def row_map(bi, *_):
         return (bi, 0, 0)
 
-    def page_map(bi, ki, *refs):
-        return (entry(bi, ki, *refs), 0, 0)
-
-    kern = functools.partial(_latent_kernel, scale=scale, block_k=block,
-                             rank=rank, width=w)
-    out = pl.pallas_call(
-        kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, block_tables.shape[1]),
-            in_specs=[pl.BlockSpec((1, rows, 1), row_map),        # q_pos
-                      pl.BlockSpec((1, rows, w), row_map),        # q
-                      pl.BlockSpec((1, block, pw), page_map)],    # page
-            out_specs=pl.BlockSpec((1, rows, rank), row_map),
-            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
-                            pltpu.VMEM((rows, 1), jnp.float32),
-                            pltpu.VMEM((rows, rank), jnp.float32)]),
+    kern = functools.partial(_latent_kernel, scale=scale, rank=rank, width=w)
+    out = _sweep_call(
+        kern, [pages], block_tables,
+        group_pages(page_bytes([pages]), block_tables.shape[1]),
+        in_specs=[pl.BlockSpec((1, rows, 1), row_map),              # q_pos
+                  pl.BlockSpec((1, rows, w), row_map)],             # q
+        out_spec=pl.BlockSpec((1, rows, rank), row_map),
         out_shape=jax.ShapeDtypeStruct((b, rows, rank), q.dtype),
-        interpret=interpret,
-        name="paged_latent_attention",
+        scratch=[pltpu.VMEM((rows, 1), jnp.float32),
+                 pltpu.VMEM((rows, 1), jnp.float32),
+                 pltpu.VMEM((rows, rank), jnp.float32)],
+        interpret=interpret, name="paged_latent_attention",
     )(block_tables.astype(jnp.int32), kv_len.astype(jnp.int32),
       query_rows(q_pos.astype(jnp.int32), h), q.reshape(b, rows, w), pages)
     return out.reshape(b, tq, h, rank)

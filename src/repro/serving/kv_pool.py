@@ -213,6 +213,20 @@ def kv_capacity_bytes(cfg: ModelConfig, tree) -> int:
     return int(sum(leaf.nbytes for leaf in _attn_leaves(cfg, tree)))
 
 
+def kernel_pools(cfg: ModelConfig, tree):
+    """The first attention layer's pool leaves as a paged attention kernel
+    is handed them, ``[NB, bs, ...]`` shapes and dtypes (a scanned leaf
+    without its repeats axis); empty without an attention layer."""
+    plan = scan_plan(cfg)
+    for entries, specs, lead in ((tree["prefix"], plan.prefix, 0),
+                                 (tree["scan"], plan.period, 1)):
+        for entry, spec in zip(entries, specs):
+            if spec.mixer in ATTN_MIXERS:
+                return [jax.ShapeDtypeStruct(x.shape[lead:], x.dtype)
+                        for x in jax.tree.leaves(entry)]
+    return []
+
+
 def kv_bytes_per_block(cfg: ModelConfig, tree, num_blocks: int) -> int:
     """Bytes one pool block costs across all attention leaves (scanned
     leaves count each repeat, since the pool exists per repeat-layer)."""

@@ -58,6 +58,7 @@ facade wiring the two together.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Dict, List, Optional
@@ -65,6 +66,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..core.spec_decode import SpecDecoder, TemplateBank
+from ..kernels.decode_attention import sweep_group
 from . import kv_pool, spans
 from .config import SamplingParams
 from .executor import NO_LIMIT, Executor, StepHandle, StepResult
@@ -247,6 +249,17 @@ class _Replica:
         # step may be in flight are applied at the NEXT dispatch boundary
         self.pending_retire = np.zeros(max_batch, bool)
         self._occ_cache: Optional[np.ndarray] = None
+
+    @functools.cached_property
+    def kv_group(self) -> int:
+        """Pages the target forward's paged attention kernel sweeps at once,
+        by the kernels' own rule (0: one a grid step over the whole table,
+        as the tree kernel of tree verification does)."""
+        if self.dec.tree is not None:
+            return 0
+        return sweep_group(kv_pool.kernel_pools(self.ex.tc,
+                                                self.ex.state.tcache),
+                           self.alloc.tables.shape[1])
 
     def occupied_mask(self) -> np.ndarray:
         """[B] bool — slots holding a live request. Built once per slot
@@ -677,16 +690,20 @@ class Scheduler:
                         any_prefilling: bool) -> None:
         """Add this step's paged-KV page counts, from host mirrors only.
 
-        ``kv_pages_grid``: ``max_batch`` x the block table's width — what
-        the paged attention kernels' grid steps over
-        (``grid=(b, block_tables.shape[1])``; ``last_block`` stops the DMA
-        past a row's ``kv_len``, not the grid step). ``kv_pages_live``: the
-        pages the target forward's ``kv_len`` (window start + window width)
-        covers in each occupied row, the window starting at the last
-        committed token (``slot_last_n - 1``) or at the prefill cursor.
-        Their ratio is the share of the grid that does work. Exact in the
-        synchronous loop; in the pipelined loop ``slot_last_n`` is one
-        step stale (it advances at harvest)."""
+        ``kv_pages_grid``: the pages the paged attention kernels' sweep
+        covers. It copies each row's live pages in groups of P
+        (``decode_attention.sweep_group`` on the target pool's pages), so
+        it covers each occupied row's live pages rounded up to whole
+        groups. Where the target's kernel takes one page a grid step
+        (P = 0: a quantized pool, a head dim of 64, tree verification) the
+        grid steps over ``max_batch`` x the block table's width.
+
+        ``kv_pages_live``: the pages the target forward's ``kv_len``
+        (window start + window width) covers in each occupied row, the
+        window starting at the last committed token (``slot_last_n - 1``)
+        or at the prefill cursor. Their ratio is the share of the sweep
+        that does work. Exact in the synchronous loop; in the pipelined
+        loop ``slot_last_n`` is one step stale (it advances at harvest)."""
         if self.mode == "ar":
             width = self.chunk if any_prefilling else 1
         else:
@@ -697,7 +714,10 @@ class Scheduler:
         pages = np.minimum(-(-(start + width) // rep.alloc.block_size),
                            table_w)
         self.stats["kv_pages_live"] += int(pages[occ].sum())
-        self.stats["kv_pages_grid"] += self.max_batch * table_w
+        group = rep.kv_group
+        self.stats["kv_pages_grid"] += (
+            int((-(-pages[occ] // group) * group).sum()) if group
+            else self.max_batch * table_w)
 
     def process(self, handle: StepHandle) -> None:
         """Harvest one in-flight step (ONE batched device transfer) and

@@ -75,6 +75,8 @@ def _paged_setup(b, hkv, d, bs, mbs, key=0):
     (2, 9, 4, 2, 64, 32, 4),     # PARD verify window (K+1 = 9)
     (3, 1, 4, 4, 32, 16, 5),     # plain AR decode
     (1, 8, 8, 2, 32, 64, 3),     # 2K draft window
+    (2, 9, 12, 2, 128, 16, 37),  # swept, groups of 16 over 37 entries
+    (3, 16, 4, 2, 128, 64, 5),   # swept, groups of 4 over 5 entries
 ])
 def test_decode_attention_paged(b, tq, hq, hkv, d, bs, mbs):
     q = rand(b, tq, hq, d, k=4)
@@ -124,6 +126,100 @@ def test_decode_attention_paged_ignores_garbage_block():
     out2 = ops.decode_attention_paged(q, poisoned_k, poisoned_v, tables,
                                       kv_len, q_pos)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=0)
+
+
+def _sweep_fills(bs, group, mbs):
+    """One row per edge of the sweep: empty, one slot, a page less one, a
+    page, a group less one, a group, a group and one, the whole table."""
+    return [0, 1, bs - 1, bs, group * bs - 1, group * bs, group * bs + 1,
+            mbs * bs]
+
+
+def _seen(q_pos, kv_len):
+    """[B, Tq] queries that see at least one key (the rest are padding:
+    the kernels write zeros there, the oracle an average)."""
+    return np.asarray((q_pos >= 0) & (kv_len[:, None] > 0))
+
+
+@pytest.mark.parametrize("kv", ["f32", "bf16", "int8", "fp8"])
+@pytest.mark.parametrize("tables", ["shuffled", "shared"])
+@pytest.mark.parametrize("window,softcap", [(0, 0.0), (40, 30.0)])
+@pytest.mark.parametrize("reverse", [False, True])
+def test_decode_attention_paged_sweeps_mixed_fills(kv, tables, window,
+                                                    softcap, reverse):
+    """Rows of every edge fill in one call, through a table of 37 entries
+    that P does not divide, in shuffled or shared (every row the same
+    pages) order, with and without window and softcap, the empty row first
+    or last. The f32 and bf16 pools (2 kv heads of 128) are swept in
+    groups (16 and 32 pages); the 8-bit pools and their scales take one
+    page a grid step. Table entries past a row's fill point at the garbage
+    block, filled with NaN: a page copied past ``kv_len`` would leak it."""
+    from repro.kernels import decode_attention
+    from repro.models.attention import quantize_kv
+    tq, hq, hkv, d, bs, mbs = 9, 12, 2, 128, 16, 37
+    dtype = jnp.float32 if kv == "f32" else jnp.bfloat16
+    nb = 1 + mbs
+    k_pages = rand(nb, bs, hkv, d, k=50, dtype=dtype)
+    v_pages = rand(nb, bs, hkv, d, k=51, dtype=dtype)
+    group = decode_attention.sweep_group([k_pages, v_pages], mbs)
+    assert group == (16 if kv == "f32" else 32)
+    fills = _sweep_fills(bs, group, mbs)[::-1 if reverse else 1]
+    b = len(fills)
+    rng = np.random.default_rng(5)
+    perm = [rng.permutation(np.arange(1, nb)) for _ in range(b)]
+    tab = np.stack([perm[0] if tables == "shared" else p for p in perm])
+    kv_len = jnp.asarray(fills, jnp.int32)
+    tab[np.arange(mbs)[None, :] * bs >= np.asarray(fills)[:, None]] = 0
+    tab = jnp.asarray(tab, jnp.int32)
+    ks = vs = None
+    if kv in ("int8", "fp8"):
+        qd = jnp.int8 if kv == "int8" else jnp.float8_e4m3fn
+        k_pages, ks = quantize_kv(k_pages, qd)
+        v_pages, vs = quantize_kv(v_pages, qd)
+        assert decode_attention.sweep_group(
+            [k_pages, v_pages, ks, vs], mbs) == 0
+    q = rand(b, tq, hq, d, k=52, dtype=dtype)
+    q_pos = (kv_len - tq)[:, None] + jnp.arange(tq)[None, :]
+    args = dict(k_scale=ks, v_scale=vs, window=window, softcap=softcap)
+    want = ref.decode_attention_paged_ref(q, k_pages, v_pages, tab, kv_len,
+                                          q_pos, **args)
+    poison = jnp.asarray(jnp.nan, k_pages.dtype) if kv != "int8" else 127
+    out = ops.decode_attention_paged(q, k_pages.at[0].set(poison),
+                                     v_pages.at[0].set(poison), tab, kv_len,
+                                     q_pos, **args)
+    out, want = np.asarray(out, np.float32), np.asarray(want, np.float32)
+    seen = _seen(q_pos, kv_len)
+    tol = 2e-5 if kv == "f32" else 2e-2
+    np.testing.assert_allclose(out[seen], want[seen], atol=tol, rtol=tol)
+    assert not out[~seen].any()
+
+
+@pytest.mark.parametrize("layer", [0, 3])
+def test_decode_attention_sweep_reads_stacked_pool_at_layer(layer):
+    """The swept kernel (bf16, 2 kv heads of 128) reads the layer loop's
+    stacked pool viewed as one through offset tables: the oracle on
+    pool[layer]."""
+    from repro.models.attention import _paged_attend, set_attention_backend
+    n_layers, b, tq, hq, hkv, d, bs, mbs = 4, 3, 16, 4, 2, 128, 64, 5
+    nb = 1 + b * mbs
+    k_pages = rand(n_layers, nb, bs, hkv, d, k=63, dtype=jnp.bfloat16)
+    v_pages = rand(n_layers, nb, bs, hkv, d, k=64, dtype=jnp.bfloat16)
+    perm = np.random.default_rng(layer).permutation(np.arange(1, nb))
+    tables = jnp.asarray(perm.reshape(b, mbs), jnp.int32)
+    q = rand(b, tq, hq, d, k=65, dtype=jnp.bfloat16)
+    kv_len = jnp.asarray([bs * mbs, 4 * bs + 1, tq], jnp.int32)
+    q_pos = (kv_len - tq)[:, None] + jnp.arange(tq)[None, :]
+    set_attention_backend("pallas")
+    try:
+        out = jax.jit(lambda i: _paged_attend(
+            q, k_pages, v_pages, tables, q_pos, kv_len, layer=i))(
+                jnp.int32(layer))
+    finally:
+        set_attention_backend(None)
+    want = ref.decode_attention_paged_ref(q, k_pages[layer], v_pages[layer],
+                                          tables, kv_len, q_pos)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
 
 
 @pytest.mark.parametrize("kernel", ["decode", "tree"])
@@ -203,6 +299,7 @@ def _latent_setup(b, h, tq, bs, mbs, dtype, k=70, layers=None):
     (2, 9, 4, 16, 4),      # PARD verify window (K+1 = 9), last page partial
     (3, 16, 2, 8, 5),      # 2K draft window
     (2, 1, 8, 16, 3),      # plain AR decode
+    (3, 9, 4, 64, 37),     # groups of 32 (bf16) or 16 (f32), 37 entries
 ])
 def test_paged_latent_attention(dtype, b, tq, h, bs, mbs):
     q, pages, tables, kv_len, q_pos = _latent_setup(b, h, tq, bs, mbs, dtype)
@@ -229,6 +326,42 @@ def test_paged_latent_attention_ignores_garbage_block_and_pad_lanes():
     out2 = ops.paged_latent_attention(q, poisoned, tables, kv_len, q_pos,
                                       rank=LAT_RANK, scale=0.2)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("reverse", [False, True])
+def test_paged_latent_attention_sweeps_mixed_fills(dtype, reverse):
+    """Rows of every edge fill in one call (``_sweep_fills``), 64-slot pages
+    of 128 lanes in groups of 16 (f32) or 32 (bf16) over 37 entries. Entries
+    past a row's fill point at the garbage block and the pad lanes past
+    rank + rope hold NaN: neither may reach the output."""
+    from repro.kernels import decode_attention
+    tq, h, bs, mbs = 9, 4, 64, 37
+    w = LAT_RANK + LAT_ROPE
+    nb = 1 + mbs
+    pages = jnp.pad(rand(nb, bs, w, k=95, dtype=dtype),
+                    [(0, 0), (0, 0), (0, LAT_LANES - w)])
+    group = decode_attention.group_pages(
+        decode_attention.page_bytes([pages]), mbs)
+    assert group == (32 if dtype == jnp.bfloat16 else 16)
+    fills = _sweep_fills(bs, group, mbs)[::-1 if reverse else 1]
+    b = len(fills)
+    rng = np.random.default_rng(6)
+    tab = np.stack([rng.permutation(np.arange(1, nb)) for _ in range(b)])
+    tab[np.arange(mbs)[None, :] * bs >= np.asarray(fills)[:, None]] = 0
+    tab = jnp.asarray(tab, jnp.int32)
+    kv_len = jnp.asarray(fills, jnp.int32)
+    q = rand(b, tq, h, w, k=96, dtype=dtype)
+    q_pos = (kv_len - tq)[:, None] + jnp.arange(tq)[None, :]
+    want = ref.paged_latent_attention_ref(q, pages, tab, kv_len, q_pos,
+                                          rank=LAT_RANK, scale=0.2)
+    poisoned = pages.at[0].set(jnp.nan).at[..., w:].set(jnp.nan)
+    out = ops.paged_latent_attention(q, poisoned, tab, kv_len, q_pos,
+                                     rank=LAT_RANK, scale=0.2)
+    out, want = np.asarray(out, np.float32), np.asarray(want, np.float32)
+    seen = _seen(q_pos, kv_len)
+    np.testing.assert_allclose(out[seen], want[seen], atol=TOL[dtype])
+    assert not out[~seen].any()
 
 
 @pytest.mark.parametrize("layer", [0, 3])

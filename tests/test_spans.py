@@ -208,6 +208,37 @@ def test_kv_page_counts_match_a_hand_count(models):
     assert rep.slot_pf[1] < rep.slot_pf_len[1]       # row 1 prefilled
 
 
+@pytest.mark.parametrize("block,group,tree", [(16, 32, None), (64, 8, None),
+                                              (64, 8, (2, 2, 1))])
+def test_kv_pages_grid_counts_whole_groups(block, group, tree):
+    """A pool the page sweep copies itself (bf16, 2 kv heads of 128): a
+    page of K and V is ``block`` x 1 KiB, so ``GROUP_BYTES`` (768 KiB) holds
+    ``group`` pages. Occupied rows of 1, ``group`` and ``group`` + 1 live
+    pages, one row empty: ``kv_pages_grid`` adds the live pages rounded up
+    to whole groups, 1 + 1 + 2 groups. Tree verification's kernel steps
+    over every table entry of every row instead."""
+    cfg = dataclasses.replace(get_config("tiny-target"), num_layers=1,
+                              d_model=256, n_heads=4, n_kv_heads=2,
+                              head_dim=128)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    eng = Engine(params, cfg, params, cfg, config=EngineConfig(
+        mode="pard", k=4, max_batch=4, max_len=2048, kv_block_size=block,
+        tree=tree))
+    rep = eng.sched.replicas[0]
+    table_w = rep.alloc.tables.shape[1]
+    swept = decode_attention.sweep_group(
+        [eng.ex.state.tcache["scan"][0]["k"][0]] * 2, table_w)
+    assert swept == group
+    assert rep.kv_group == (0 if tree else group)
+    width = eng.sched.dec.verify_width
+    live = np.asarray([1, group, group + 1, 0])
+    # the window starts at the last committed token: kv_len = n - 1 + width
+    rep.slot_last_n[:] = live * block - width + 1
+    eng.sched._count_kv_pages(rep, live > 0, any_prefilling=False)
+    assert eng.stats["kv_pages_live"] == 2 * group + 2
+    assert eng.stats["kv_pages_grid"] == (4 * table_w if tree else 4 * group)
+
+
 @pytest.mark.parametrize("mode", ["ar", "pard"])
 def test_dense_step_carries_no_moe_count(models, mode):
     """Neither model has a MoE layer: the state holds no ``moe_rows`` and

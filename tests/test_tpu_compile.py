@@ -16,6 +16,7 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
 import dataclasses
+import math
 import os
 
 import jax
@@ -98,6 +99,47 @@ def test_paged_kernel_compiles_for_v5e(one_chip, model, tp, kernel, tq, kv):
     live = args + [x for x in scales if x is not None]
     compiled = jax.jit(step).lower(*live).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode_attention",
+                                    "paged_latent_attention"])
+@pytest.mark.parametrize("tq", [16, 9])
+def test_swept_kernel_compiles_for_v5e_at_cell_shapes(one_chip, kernel, tq):
+    """The page-swept kernels at the benchmark cells' shapes: 32 rows, a
+    table of 37 entries, the 16-token draft and 9-token verify windows.
+    GQA at ``dsq-1.5b-selfdraft`` widths (12/2 heads of 128, a bf16 pool
+    of 1,185 pages stacked over 28 layers), latent at
+    ``dsv2-lite-ep8-selfdraft``'s (16 heads, 512 + 64 lanes in a 640-lane
+    pool stacked over 16 layers). The program holds the kernel and no
+    temporary as large as one layer's pool: the pool is read in place."""
+    b, entries, pages = 32, 37, 1185
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    index = [s((b, entries), jnp.int32), s((b,), jnp.int32),
+             s((b, tq), jnp.int32)]
+    if kernel == "paged_decode_attention":
+        layers, page = 28, (BLOCK, 2, 128)
+        pool = s((layers * pages,) + page, jnp.bfloat16)
+        args = [s((b, tq, 12, 128), jnp.bfloat16), pool, pool] + index
+
+        def call(q, kp, vp, bt, kl, qp):
+            return ops.decode_attention_paged(q, kp, vp, bt, kl, qp,
+                                              interpret=False)
+    else:
+        layers, page = 16, (BLOCK, 640)
+        pool = s((layers * pages,) + page, jnp.bfloat16)
+        args = [s((b, tq, 16, 576), jnp.bfloat16), pool] + index
+
+        def call(q, pool, bt, kl, qp):
+            return ops.paged_latent_attention(q, pool, bt, kl, qp, rank=512,
+                                              scale=0.1, interpret=False)
+
+    compiled = jax.jit(call).lower(*args).compile()
+    assert f"%{kernel}" in compiled.as_text()
+    layer_bytes = pages * 2 * math.prod(page)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
 def test_ar_loss_grad_compiles_for_v5e(one_chip, monkeypatch):
