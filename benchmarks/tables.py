@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.core.eagle import EagleDecoder
 from repro.core.spec_decode import SpecDecoder
+from repro.core.spec_decode import row_tables
 from repro.models import forward, init_caches
 from repro.serving.engine import Engine
 
@@ -38,15 +39,18 @@ def _ar_eager_tps(params, cfg, prompt, max_new=12):
     analogue of unoptimized HF transformers (the paper's AR row)."""
     with jax.disable_jit():
         b, p = prompt.shape
-        caches = init_caches(cfg, b, 256)
+        nb, tables = row_tables(b, 256, 256, 64)
+        caches = init_caches(cfg, b, nb, 64)
+        kw = dict(block_tables=tables, kv_block_size=64)
         t0 = time.perf_counter()
         logits, caches, _ = forward(params, cfg, prompt, caches=caches,
-                                    cache_pos=jnp.zeros((b,), jnp.int32))
+                                    cache_pos=jnp.zeros((b,), jnp.int32),
+                                    **kw)
         cur = jnp.argmax(logits[:, -1], -1)[:, None]
         for i in range(max_new - 1):
             logits, caches, _ = forward(
                 params, cfg, cur.astype(jnp.int32), caches=caches,
-                cache_pos=jnp.full((b,), p + i, jnp.int32))
+                cache_pos=jnp.full((b,), p + i, jnp.int32), **kw)
             cur = jnp.argmax(logits[:, -1], -1)[:, None]
         jax.block_until_ready(cur)
         secs = time.perf_counter() - t0
@@ -303,10 +307,10 @@ def _recording_config(**overrides) -> dict:
 
 
 def serve() -> List:
-    """Serving-engine KV layouts: tokens/sec and cache HBM bytes for
-    ar/vsd/pard in both the contiguous and the block-paged layout. Uses the
-    tiny family (the point is the LAYOUT ratio — paged bytes track actual
-    fill — not absolute CPU throughput) and persists the trajectory to the
+    """Serving-engine KV pool: tokens/sec and cache HBM bytes for
+    ar/vsd/pard. Uses the tiny family (the point is the bytes — the peak in
+    use tracks actual fill, not the pool's capacity — not absolute CPU
+    throughput) and persists the trajectory to the
     canonical BENCH_serve.json at the repo root (common.update_bench_serve;
     the per-table results/ mirror is intentionally not written)."""
     from repro.serving.config import EngineConfig
@@ -319,28 +323,27 @@ def serve() -> List:
 
     rows, record = [], {"config": _recording_config()}
     for mode in ("ar", "vsd", "pard"):
-        for layout in ("contiguous", "paged"):
-            eng = Engine(tp, tc, dp, dc, config=EngineConfig(
-                mode=mode, k=4, max_batch=2, max_len=max_len,
-                kv_layout=layout, kv_block_size=64))
-            for r in reqs:                      # warm pass: compile steps
-                eng.submit(r, max_new)
-            eng.run()
-            eng.peak_kv_bytes_in_use = eng.kv_bytes_in_use()
-            for r in reqs:
-                eng.submit(r, max_new)
-            t0 = time.perf_counter()
-            comps = eng.run()
-            wall = time.perf_counter() - t0
-            tps = sum(c.generated for c in comps[len(reqs):]) / wall
-            cap = eng.kv_capacity_bytes()
-            peak = eng.peak_kv_bytes_in_use
-            rows.append((f"serve.{mode}.{layout}", 1e6 / tps,
-                         f"tps={tps:.1f};kv_capacity_mb={cap / 1e6:.2f};"
-                         f"kv_peak_mb={peak / 1e6:.2f}"))
-            record[f"{mode}.{layout}"] = dict(
-                tokens_per_sec=round(tps, 2), kv_capacity_bytes=cap,
-                kv_peak_bytes_in_use=peak)
+        eng = Engine(tp, tc, dp, dc, config=EngineConfig(
+            mode=mode, k=4, max_batch=2, max_len=max_len,
+            kv_block_size=64))
+        for r in reqs:                      # warm pass: compile steps
+            eng.submit(r, max_new)
+        eng.run()
+        eng.peak_kv_bytes_in_use = eng.kv_bytes_in_use()
+        for r in reqs:
+            eng.submit(r, max_new)
+        t0 = time.perf_counter()
+        comps = eng.run()
+        wall = time.perf_counter() - t0
+        tps = sum(c.generated for c in comps[len(reqs):]) / wall
+        cap = eng.kv_capacity_bytes()
+        peak = eng.peak_kv_bytes_in_use
+        rows.append((f"serve.{mode}.paged", 1e6 / tps,
+                     f"tps={tps:.1f};kv_capacity_mb={cap / 1e6:.2f};"
+                     f"kv_peak_mb={peak / 1e6:.2f}"))
+        record[f"{mode}.paged"] = dict(
+            tokens_per_sec=round(tps, 2), kv_capacity_bytes=cap,
+            kv_peak_bytes_in_use=peak)
     common.update_bench_serve("serve", record)
     emit(rows, "serve", persist=False)
     return rows
@@ -382,7 +385,7 @@ def serve_tree(temperature: float = 0.0) -> List:
     def run_engine(tree):
         eng = Engine(tp, tc, tp, tc, mode="pard", k=TREE_K, max_batch=2,
                      max_len=max_len, temperature=temperature,
-                     kv_layout="paged", kv_block_size=64, tree=tree)
+                     kv_block_size=64, tree=tree)
         for r in reqs:                          # warm pass: compile steps
             eng.submit(r, max_new)
         eng.run()
@@ -449,7 +452,7 @@ def serve_adaptive() -> List:
 
     def run_engine(tree, adaptive):
         eng = Engine(tp, tc, tp, tc, mode="pard", k=TREE_K, max_batch=2,
-                     max_len=max_len, kv_layout="paged", kv_block_size=64,
+                     max_len=max_len, kv_block_size=64,
                      tree=tree, adaptive_tree=adaptive)
         for r in reqs:                          # warm pass: compile steps
             eng.submit(r, max_new)
@@ -542,7 +545,7 @@ def serve_sched(prefix_share: int = 8) -> List:
 
     def run_engine(cache):
         eng = Engine(tp, tc, dp, dc, mode="pard", k=4, max_batch=2,
-                     max_len=512, kv_layout="paged", kv_block_size=16,
+                     max_len=512, kv_block_size=16,
                      prefix_cache=cache)
         for r in warm_reqs:                     # warm pass: compile + (for
             eng.submit(r, max_new)              # the cached engine) prime
@@ -626,7 +629,7 @@ def serve_pipelined() -> List:
 
     def run_engine(tree, pipelined):
         eng = Engine(tp, tc, tp, tc, mode="pard", k=TREE_K, max_batch=2,
-                     max_len=max_len, kv_layout="paged", kv_block_size=64,
+                     max_len=max_len, kv_block_size=64,
                      tree=tree)
         for r in reqs:                          # warm pass: compile steps
             eng.submit(r, max_new)
@@ -721,7 +724,7 @@ def serve_kv_quant() -> List:
     rows, record = [], {}
     for dtype in ("fp32", "int8", "fp8"):
         eng = Engine(tp, tc, dp, dc, mode="pard", k=4, max_batch=2,
-                     max_len=max_len, kv_layout="paged", kv_block_size=64,
+                     max_len=max_len, kv_block_size=64,
                      kv_dtype=dtype)
         for r in reqs:                          # warm pass: compile steps
             eng.submit(r, max_new)
@@ -814,7 +817,7 @@ def serve_sharded() -> List:
 
     def run_engine(n, ruleset="exact", audit=False):
         cfg = EngineConfig(mode="pard", k=4, max_batch=2, max_len=max_len,
-                           kv_layout="paged", kv_block_size=64, seed=3,
+                           kv_block_size=64, seed=3,
                            mesh=mesh_mod.make_host_mesh(model=n, data=1),
                            tp_ruleset=ruleset)
         eng = Engine(tgt, tc, dp, dc, config=cfg)
@@ -978,7 +981,7 @@ def serve_dp() -> List:
 
     def run_engine(n):
         cfg = EngineConfig(mode="pard", k=4, max_batch=2, max_len=512,
-                           kv_layout="paged", kv_block_size=64, seed=3,
+                           kv_block_size=64, seed=3,
                            prefix_cache=True, pipelined=True, dp=n)
         eng = Engine(tgt, tc, dpar, dc, config=cfg)
 

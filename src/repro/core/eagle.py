@@ -21,7 +21,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..models import forward
+from ..models import KV_BLOCK, forward, init_caches
 from ..models import layers as L
 from ..models import attention as attn
 from ..models.config import ModelConfig
@@ -45,10 +45,12 @@ def init_eagle(key, cfg: ModelConfig):
     return p
 
 
-def _layer_apply(lp, cfg, x, positions, cache, cache_pos):
+def _layer_apply(lp, cfg, x, positions, cache, cache_pos, tables, block_size):
     h = L.rmsnorm_apply(lp["norm1"], x, cfg.norm_eps)
     y, new_cache = attn.gqa_apply(lp["mixer"], cfg, h, positions,
-                                  cache=cache, cache_pos=cache_pos)
+                                  cache=cache, cache_pos=cache_pos,
+                                  block_tables=tables,
+                                  kv_block_size=block_size)
     x = x + y
     h2 = L.rmsnorm_apply(lp["norm2"], x, cfg.norm_eps)
     x = x + L.mlp_apply(lp["mlp"], h2)
@@ -56,14 +58,17 @@ def _layer_apply(lp, cfg, x, positions, cache, cache_pos):
 
 
 def eagle_forward(eagle_params, target_params, cfg: ModelConfig, tokens,
-                  feats, positions, *, cache=None, cache_pos=None):
+                  feats, positions, *, cache=None, cache_pos=None,
+                  tables=None, block_size=0):
     """tokens: [B, T] (x_t); feats: [B, T, D] (f_{t-1}, the target feature
-    at the PREVIOUS position). Returns (logits, new_feats f̂_t, cache)."""
+    at the PREVIOUS position). ``cache``: the head's KV pool
+    (attention.init_gqa_pool) read through ``tables``. Returns (logits,
+    new_feats f̂_t, cache)."""
     e = L.embed_apply(target_params["embed"], tokens, cfg, dtype=feats.dtype)
     x = jnp.concatenate([e, feats], axis=-1)
     x = jnp.einsum("btd,de->bte", x, eagle_params["fuse"].astype(feats.dtype))
     x, new_cache = _layer_apply(eagle_params["layer"], cfg, x, positions,
-                                cache, cache_pos)
+                                cache, cache_pos, tables, block_size)
     f_hat = x
     h = L.rmsnorm_apply(eagle_params["out_norm"], x, cfg.norm_eps)
     logits = L.unembed_apply(target_params["embed"], h, cfg)
@@ -94,7 +99,9 @@ def eagle_loss(eagle_params, target_params, cfg: ModelConfig, tokens,
 class EagleDecoder:
     """Greedy speculative decoding with an EAGLE head (chain, like the
     paper's Table 3 comparison). Target-side verification is identical to
-    SpecDecoder; the draft phase is K sequential head passes."""
+    SpecDecoder; the draft phase is K sequential head passes. The target
+    and the head keep their KV in pools of ``KV_BLOCK``-token pages, like
+    SpecDecoder's."""
 
     def __init__(self, target_params, cfg: ModelConfig, eagle_params, *,
                  k: int = 4, max_len: int = 1024):
@@ -103,12 +110,11 @@ class EagleDecoder:
         self._step = None
 
     def _build_step(self):
-        k, cfg = self.k, self.cfg
-        from ..models import init_caches
+        k, cfg, bs = self.k, self.cfg, KV_BLOCK
         from .acceptance import _row_take
         from .spec_decode import _row_write
 
-        def step(gen, n, done, tcache, ecache, feat_prev):
+        def step(gen, n, done, tcache, ecache, feat_prev, tables):
             # ---- draft: K sequential head passes --------------------------
             # The head's KV cache persists across iterations: entries for
             # ACCEPTED positions were computed from committed context, so the
@@ -122,7 +128,7 @@ class EagleDecoder:
                 lg, f_hat, ecache = eagle_forward(
                     self.ep, self.tp, cfg, cur.astype(jnp.int32), feats,
                     epos[:, None] + j, cache=ecache,
-                    cache_pos=epos + j)
+                    cache_pos=epos + j, tables=tables, block_size=bs)
                 pj = jnp.argmax(lg[:, -1], axis=-1).astype(jnp.int32)
                 props.append(pj)
                 cur = pj[:, None]
@@ -133,7 +139,9 @@ class EagleDecoder:
             last = jnp.take_along_axis(gen, (n - 1)[:, None], axis=1)
             vin = jnp.concatenate([last.astype(jnp.int32), props], axis=1)
             logits, tcache, aux = forward(self.tp, cfg, vin, caches=tcache,
-                                          cache_pos=n - 1)
+                                          cache_pos=n - 1,
+                                          block_tables=tables,
+                                          kv_block_size=bs)
             hidden = aux["hidden"]                                    # [B,K+1,D]
             tgt = jnp.argmax(logits[:, :k], axis=-1).astype(jnp.int32)
             match = (props == tgt).astype(jnp.int32)
@@ -161,16 +169,18 @@ class EagleDecoder:
         return jax.jit(step)
 
     def generate(self, prompt, max_new: int):
-        from ..models import init_caches
-        from .spec_decode import SpecStats
+        from .spec_decode import SpecStats, row_tables
         b, p = prompt.shape
-        k = self.k
-        tcache = init_caches(self.cfg, b, self.max_len)
-        ecache = attn.init_gqa_cache(self.cfg, b, self.max_len)
+        k, bs = self.k, KV_BLOCK
+        L_buf = p + max_new + 2 * k + 2
+        nb, tables = row_tables(b, L_buf, self.max_len, bs)
+        tcache = init_caches(self.cfg, b, nb, bs)
+        ecache = attn.init_gqa_pool(self.cfg, nb, bs)
 
         logits, tcache, aux = jax.jit(
             lambda t, c: forward(self.tp, self.cfg, t, caches=c,
-                                 cache_pos=jnp.zeros((t.shape[0],), jnp.int32))
+                                 cache_pos=jnp.zeros((t.shape[0],), jnp.int32),
+                                 block_tables=tables, kv_block_size=bs)
         )(prompt[:, :-1], tcache)
         hidden = aux["hidden"]                # f_0 .. f_{P-2}
         feat_prev = hidden[:, -1]             # f_{P-2}
@@ -183,13 +193,13 @@ class EagleDecoder:
         _, _, ecache = jax.jit(
             lambda t, f, pp, c: eagle_forward(
                 self.ep, self.tp, self.cfg, t, f, pp, cache=c,
-                cache_pos=jnp.zeros((t.shape[0],), jnp.int32))
+                cache_pos=jnp.zeros((t.shape[0],), jnp.int32),
+                tables=tables, block_size=bs)
         )(prompt[:, :-1], feats_in, pos, ecache)
 
         if self._step is None:
             self._step = self._build_step()
 
-        L_buf = p + max_new + 2 * k + 2
         gen = jnp.zeros((b, L_buf), jnp.int32)
         gen = gen.at[:, :p].set(prompt)
         n = jnp.full((b,), p, jnp.int32)
@@ -200,7 +210,7 @@ class EagleDecoder:
         while True:
             live = int(jnp.sum(~done))
             gen, n, tcache, ecache, feat_prev, a, hist = self._step(
-                gen, n, done, tcache, ecache, feat_prev)
+                gen, n, done, tcache, ecache, feat_prev, tables)
             iters += 1
             live_iters += live
             acc_total += int(jnp.sum(a))

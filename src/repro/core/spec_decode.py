@@ -6,9 +6,10 @@ All step functions use fixed shapes (jit-once):
     counts them. Commits write a full (K+1)-slot window at offset n — slots
     beyond the accepted count hold garbage that is overwritten before it can
     ever be read (reads are always < n).
-  * KV caches are contiguous; speculative rollback = the next call's
-    ``cache_pos`` simply re-covers the rejected entries (validity is
-    ``index < cache_pos + q_len``, so stale KV is invisible).
+  * KV caches are block-paged pools (serving/kv_pool.py, DESIGN.md §5);
+    speculative rollback = the next call's ``cache_pos`` simply re-covers
+    the rejected entries (validity is ``index < cache_pos + q_len``, so
+    stale KV is invisible).
   * SSM/hybrid layers cannot roll back by position: the verify forward runs
     with ``collect_ssm=True`` and the engine gathers the per-token state at
     the last accepted index (DESIGN.md §3).
@@ -43,8 +44,9 @@ and the tree uses multi-round recursive rejection sampling over sibling
 candidates — both in core/acceptance.py, both provably committing tokens
 from the target model's own sampling distribution. Sampling draws come from
 per-row PRNG keys (``DecodeState.rngs``), so a request's output depends
-only on its own seed and step count, never on batch composition or KV
-layout (the seeded-determinism tests in tests/test_sampled_tree.py).
+only on its own seed and step count, never on batch composition or how
+its prompt was prefilled (the seeded-determinism tests in
+tests/test_sampled_tree.py).
 """
 from __future__ import annotations
 
@@ -55,7 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import forward, init_caches
+from ..models import KV_BLOCK, blocks_for, forward, init_caches
 from ..models.attention import (TreeAttnInfo, paged_index,
                                 resolve_kv_dtype)
 from ..models.config import (ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA, MLP_MOE,
@@ -399,19 +401,13 @@ def compact_tree_caches(cfg: ModelConfig, caches, src_pos, dst_start, depth,
     the same rollback invariant as the flat path (kv_pool I4 routes frozen
     rows' copies to the garbage block).
 
-    Touches attention leaves only; SSM states cannot appear under a tree
+    Touches attention pools only; SSM states cannot appear under a tree
     target (positional rollback is a precondition, see _build_tree_step).
     """
     plan = scan_plan(cfg)
     dst_pos = dst_start[:, None] + jnp.arange(depth, dtype=jnp.int32)[None]
 
-    def move_contig(leaf):           # [B, S, ...]
-        taken = jax.vmap(lambda row, i: row[i])(leaf, src_pos)
-        zeros = (0,) * (leaf.ndim - 2)
-        return jax.vmap(lambda row, tk, p: jax.lax.dynamic_update_slice(
-            row, tk, (p,) + zeros))(leaf, taken, dst_start)
-
-    def move_paged(leaf, scanned):   # [(L,) NB, bs, ...]
+    def move(leaf, scanned):         # [(L,) NB, bs, ...]
         # every layer of a stacked pool in one scatter, indexed as
         # paged_index says: vmapped over the layer axis, the scatter is
         # lowered around a relayout of the whole pool
@@ -427,10 +423,7 @@ def compact_tree_caches(cfg: ModelConfig, caches, src_pos, dst_start, depth,
     def one(spec, entry, scanned):
         if spec.mixer not in _ATTN_MIXERS:
             return entry
-        if tables is None:
-            return jax.tree.map(jax.vmap(move_contig) if scanned
-                                else move_contig, entry)
-        return jax.tree.map(lambda x: move_paged(x, scanned), entry)
+        return jax.tree.map(lambda x: move(x, scanned), entry)
 
     return {
         "prefix": [one(s, caches["prefix"][i], False)
@@ -450,24 +443,27 @@ class DecodeState:
 
     Both ``SpecDecoder.generate_*`` (uniform batch, run-to-completion) and
     the continuous-batching serving engine (ragged slots, admission /
-    release between steps) advance a ``DecodeState`` through the SAME jitted
-    step functions (``SpecDecoder._build_ar_step`` /  ``_build_spec_step``).
+    release between steps) build it with ``SpecDecoder.init_state`` and
+    advance it through the SAME jitted step functions
+    (``SpecDecoder._build_ar_step`` / ``_build_spec_step`` /
+    ``_build_tree_step``).
 
       gen    [B, L]  committed tokens (prompt + generated)
       n      [B]     committed count (reads are always < n)
       m      [B]     draft progress: committed tokens already processed by
                      the draft (n - m = the new-token window)
       done   [B]     frozen rows — steps rewrite their gen/n/m unchanged
-      tcache, dcache cache pytrees (contiguous rows or paged pools)
-      tables [B, MBS] block tables for the paged KV layout, or None for
-                     contiguous (DESIGN.md §5); shared by target and draft
-                     since both cache the same absolute positions.
+      tcache, dcache cache pytrees: attention pools [(L,) NB, bs, ...],
+                     SSM states per row (models.init_caches)
+      tables [B, MBS] block tables into the pools (DESIGN.md §5); shared
+                     by target and draft since both cache the same
+                     absolute positions.
       temp   [B]     per-row sampling temperature (0 = greedy; one batch
                      mixes greedy and sampled requests)
       rngs   [B, 2]  per-row PRNG keys — each step splits every row's key
                      once, so a row's sampling stream depends only on its
                      own seed and its step count (seeded determinism across
-                     batch compositions and KV layouts).
+                     batch compositions).
       tree_idx [B]   per-row template index into the decoder's
                      ``TemplateBank`` (None when tree drafting is off): the
                      tree step gathers each row's packed tree metadata by
@@ -476,7 +472,7 @@ class DecodeState:
                      request between windows by a single scatter.
       pf_pos [B]     chunked-prefill cursor: prompt tokens already written
                      to the KV caches. A row with ``pf_pos < pf_len`` is in
-                     the PREFILLING phase: the chunked step builders feed it
+                     the PREFILLING phase: the step builders feed it
                      prompt chunks instead of draft/verify windows inside
                      the SAME jitted forward as the decoding rows
                      (DESIGN.md §8), commit nothing for it, and advance the
@@ -510,27 +506,27 @@ jax.tree_util.register_dataclass(
     DecodeState, [f.name for f in dataclasses.fields(DecodeState)], [])
 
 
-def prefill_row(params, cfg: ModelConfig, toks: Array, plen, caches, *,
-                tables=None, block_size=0, enc_out=None):
-    """Prefill ``toks`` [B, T] (right-padded past ``plen``) into ``caches``.
+def row_tables(batch: int, n_tokens: int, max_len: int, block_size: int):
+    """Pages for ``n_tokens`` positions in each of ``batch`` rows, as a
+    fresh serving allocator (kv_pool.BlockAllocator) hands them out row
+    after row: block 0 the garbage block, row r the next ``per_row`` pages,
+    one owner a page — the block tables of a uniform batch.
+    Returns (num_blocks, tables [batch, blocks_for(max_len)] int32)."""
+    per_row = blocks_for(n_tokens, block_size)
+    tables = np.zeros((batch, blocks_for(max_len, block_size)), np.int32)
+    tables[:, :per_row] = 1 + np.arange(batch * per_row).reshape(batch, -1)
+    return batch * per_row + 1, jnp.asarray(tables)
 
-    Shared by SpecDecoder prefills (uniform batch, ``plen=None``: every
-    token real, final SSM state already correct) and the engine's bucketed
-    per-request admission (T >= plen). Attention KV written at padded
-    positions >= plen is never valid (kv_len bookkeeping; in the paged
-    layout it lands in the row's own future blocks or the garbage block).
-    SSM state cannot be masked after the fact, so with padding present it is
-    rolled back to the state after the last REAL token (DESIGN.md §3).
-    """
-    has = _has_ssm(cfg) and plen is not None
+
+def prefill_row(params, cfg: ModelConfig, toks: Array, caches, *, tables,
+                block_size, enc_out=None):
+    """Prefill ``toks`` [B, T] (every token real) into the pools ``caches``
+    through the block ``tables`` at positions 0..T-1: the cache after the
+    prompt, SSM states after its last token."""
     _, cache, _ = forward(params, cfg, toks, caches=caches,
                           cache_pos=jnp.zeros((toks.shape[0],), jnp.int32),
                           block_tables=tables, kv_block_size=block_size,
-                          collect_ssm=has, enc_out=enc_out, last_only=True)
-    if has:
-        idx = jnp.broadcast_to(jnp.asarray(plen, jnp.int32) - 1,
-                               (toks.shape[0],))
-        cache = gather_ssm_states(cfg, cache, idx)
+                          enc_out=enc_out, last_only=True)
     return cache
 
 
@@ -565,13 +561,16 @@ class SpecDecoder:
 
     All public ``generate_*`` methods take ``prompt [B, P]`` (uniform length;
     the batched serving engine in serving/engine.py handles ragged requests)
-    and return (tokens [B, P + max_new], SpecStats).
+    and return (tokens [B, P + max_new], SpecStats). Every cache is a pool
+    of ``kv_block_size``-token pages (DESIGN.md §5): ``generate_*`` takes
+    its rows' block tables from the serving allocator exactly as the
+    engine's executor does.
     """
 
     def __init__(self, target_params, target_cfg: ModelConfig,
                  draft_params=None, draft_cfg: ModelConfig = None, *,
                  k: int = 8, max_len: int = 2048, temperature: float = 0.0,
-                 enc_out=None, draft_enc_out=None, kv_block_size: int = 0,
+                 enc_out=None, draft_enc_out=None, kv_block_size: int = KV_BLOCK,
                  tree: Optional[TreeTemplate] = None,
                  prefill_chunk: int = 8, kv_dtype: str = "bf16",
                  mesh=None, tp_ruleset: str = "exact"):
@@ -618,8 +617,9 @@ class SpecDecoder:
         self.temperature = temperature
         self.enc_out = enc_out
         self.draft_enc_out = draft_enc_out
-        # 0 = contiguous caches; > 0 = paged pools, steps consume the block
-        # tables carried in DecodeState.tables (the serving engine's layout)
+        # tokens per pool page; steps read and write the pools through the
+        # block tables carried in DecodeState.tables
+        assert kv_block_size >= 1, "kv_block_size must be >= 1"
         self.kv_block_size = kv_block_size
         # window width of the chunked AR step (engine mode="ar" only; spec
         # and tree chunk widths are bounded by the draft/verify windows —
@@ -647,9 +647,9 @@ class SpecDecoder:
     def window_slack(self) -> int:
         """Positions a step may touch beyond the committed count: the 2K
         draft mask window vs the verify window (K+1 flat, the bank's widest
-        template for a tree), +2 slack. Sizes cache rows and contiguous
-        allocations; the paged engine allocates per request via
-        ``row_slack`` instead (I3). AR decoders (no draft) additionally
+        template for a tree), +2 slack. Sizes ``generate_spec``'s rows; the
+        engine allocates per request via ``row_slack`` instead (I3). AR
+        decoders (no draft) additionally
         cover the chunked AR step's window: its decode rows carry
         ``prefill_chunk - 1`` pad slots whose KV writes land past the
         committed count and are re-covered next step."""
@@ -709,7 +709,7 @@ class SpecDecoder:
             self._jit_cache[name] = fn
         return self._jit_cache[name]
 
-    def _target_forward(self, params, tokens, caches, cache_pos, tables=None,
+    def _target_forward(self, params, tokens, caches, cache_pos, tables,
                         collect_ssm=False, positions=None, tree_info=None):
         return forward(params[0], self.tc, tokens, positions=positions,
                        caches=caches, cache_pos=cache_pos,
@@ -717,7 +717,7 @@ class SpecDecoder:
                        block_tables=tables, kv_block_size=self.kv_block_size,
                        tree_info=tree_info)
 
-    def _draft_forward(self, params, tokens, caches, cache_pos, tables=None,
+    def _draft_forward(self, params, tokens, caches, cache_pos, tables,
                        collect_ssm=False):
         return forward(params[1], self.dc, tokens, caches=caches,
                        cache_pos=cache_pos, enc_out=self.draft_enc_out,
@@ -731,12 +731,14 @@ class SpecDecoder:
         ``state.temp == 0`` commit the argmax; rows with temp > 0 sample
         from softmax(logits / temp) under their own PRNG key.
 
-        ``chunked=True`` (engine only): the window widens to
-        ``prefill_chunk`` slots so PREFILLING rows consume prompt chunks in
-        the same forward (DESIGN.md §8). Decoding rows carry their last
-        token at slot 0 plus pads whose KV writes land past the committed
-        count and are re-covered next step (causal masking keeps slot 0's
-        logits exact); the uniform-batch path keeps the 1-wide window."""
+        Two widths. ``chunked=True`` (the engine's mixed window): the
+        window widens to ``prefill_chunk`` slots so PREFILLING rows consume
+        prompt chunks in the same forward (DESIGN.md §8). Decoding rows
+        carry their last token at slot 0 plus pads whose KV writes land
+        past the committed count and are re-covered next step (causal
+        masking keeps slot 0's logits exact). ``chunked=False``: the 1-wide
+        pure-decode window (``generate_ar`` and the engine's decode-only
+        ticks)."""
         w = self.prefill_chunk if chunked else 1
 
         def step(params, state: DecodeState) -> DecodeState:
@@ -775,52 +777,77 @@ class SpecDecoder:
                               else aux["moe_rows"]))
         return step
 
-    def init_state(self, prompt: Array, gen_len: int,
-                   with_draft: bool = True, seed: int = 0) -> DecodeState:
-        """Contiguous-layout DecodeState for a uniform-length batch (the
-        engine builds its own paged state from serving.kv_pool). Row b's
-        PRNG key derives from (seed, b)."""
-        b, p = prompt.shape
-        gen = jnp.zeros((b, gen_len), jnp.int32)
-        gen = gen.at[:, :p].set(prompt)
+    def init_state(self, batch: int, gen_len: int, num_blocks: int, *,
+                   seed: int = 0, with_draft: bool = True) -> DecodeState:
+        """The one ``DecodeState`` constructor (``generate_*`` and the
+        serving ``Executor``): ``batch`` empty rows — frozen, greedy, every
+        table entry on the garbage block, ``n``/``m`` at dummy-safe values —
+        over a ``[batch, gen_len]`` token buffer and fresh pools of
+        ``num_blocks`` pages (``models.init_caches``; the draft's only with
+        ``with_draft`` and a draft model). Tables hold ``max_len``
+        positions. Row b's PRNG key derives from (seed, b). Callers arm
+        rows by writing ``gen``/``n``/``m``/``done``/``temp``/``tables``."""
+        bs, dtype = self.kv_block_size, resolve_kv_dtype(self.kv_dtype)
         return DecodeState(
-            gen=gen, n=jnp.full((b,), p, jnp.int32),
-            m=jnp.full((b,), p - 1, jnp.int32), done=jnp.zeros((b,), bool),
-            tcache=init_caches(self.tc, b, self.max_len,
-                               dtype=resolve_kv_dtype(self.kv_dtype)),
-            dcache=(init_caches(self.dc, b, self.max_len,
-                                dtype=resolve_kv_dtype(self.kv_dtype))
+            gen=jnp.zeros((batch, gen_len), jnp.int32),
+            n=jnp.full((batch,), 2, jnp.int32),
+            m=jnp.ones((batch,), jnp.int32),
+            done=jnp.ones((batch,), bool),
+            tcache=init_caches(self.tc, batch, num_blocks, bs, dtype=dtype),
+            dcache=(init_caches(self.dc, batch, num_blocks, bs, dtype=dtype)
                     if with_draft and self.dc is not None else None),
-            temp=jnp.full((b,), self.temperature, jnp.float32),
-            rngs=acceptance.make_row_keys(seed, np.arange(b)),
-            tree_idx=(jnp.zeros((b,), jnp.int32)
+            tables=jnp.zeros((batch, blocks_for(self.max_len, bs)),
+                             jnp.int32),
+            temp=jnp.zeros((batch,), jnp.float32),
+            rngs=acceptance.make_row_keys(seed, np.arange(batch)),
+            tree_idx=(jnp.zeros((batch,), jnp.int32)
                       if self.tree is not None else None),
-            pf_pos=jnp.zeros((b,), jnp.int32),
-            pf_len=jnp.zeros((b,), jnp.int32),
+            pf_pos=jnp.zeros((batch,), jnp.int32),
+            pf_len=jnp.zeros((batch,), jnp.int32),
             moe_rows=(jnp.zeros((), jnp.int32) if self.counts_moe_rows
                       else None))
+
+    def _uniform_state(self, prompt: Array, gen_len: int, n_tokens: int, *,
+                       with_draft: bool, seed: int) -> DecodeState:
+        """``init_state`` with every row armed for ``generate_*``: the
+        prompt committed in ``gen``, and pages for ``n_tokens`` positions a
+        row (``row_tables``)."""
+        b, p = prompt.shape
+        num_blocks, tables = row_tables(b, n_tokens, self.max_len,
+                                        self.kv_block_size)
+        state = self.init_state(b, gen_len, num_blocks, seed=seed,
+                                with_draft=with_draft)
+        return dataclasses.replace(
+            state, gen=state.gen.at[:, :p].set(prompt),
+            n=jnp.full((b,), p, jnp.int32),
+            m=jnp.full((b,), p - 1, jnp.int32), done=jnp.zeros((b,), bool),
+            temp=jnp.full((b,), self.temperature, jnp.float32),
+            tables=tables)
 
     def generate_ar(self, prompt: Array, max_new: int, seed: int = 0):
         """Plain autoregressive decoding (the losslessness reference):
         ``[B, P] -> ([B, P + max_new] tokens, SpecStats)``."""
         b, p = prompt.shape
-        state = self.init_state(prompt, p + max_new + 1, with_draft=False,
-                                seed=seed)
+        # the last step writes position p + max_new - 2
+        state = self._uniform_state(prompt, p + max_new + 1, p + max_new,
+                                    with_draft=False, seed=seed)
 
         # AR prefill covers the WHOLE prompt: its last logits commit the
         # first new token, so exactly max_new forwards produce max_new
         # tokens (unlike spec prefills, which stop at prompt[:-1] and let
         # the first verify window re-read x_{P-1})
-        def pre(params, toks, c, temp, keys):
+        def pre(params, toks, c, tables, temp, keys):
             logits, c, _ = self._target_forward(
-                params, toks, c, jnp.zeros((toks.shape[0],), jnp.int32))
+                params, toks, c, jnp.zeros((toks.shape[0],), jnp.int32),
+                tables)
             return _pick_next(logits[:, -1], temp, keys), c
         prefill = self._fn("ar_prefill", pre, donate=(2,))
         step = self._fn("ar_step", self._build_ar_step(), donate=(1,))
         params = (self.tp, self.dp)
 
         next_keys, use = acceptance.split_row_keys(state.rngs)
-        first, tcache = prefill(params, prompt, state.tcache, state.temp, use)
+        first, tcache = prefill(params, prompt, state.tcache, state.tables,
+                                state.temp, use)
         state = dataclasses.replace(
             state, gen=state.gen.at[:, p].set(first),
             n=state.n + 1, tcache=tcache, rngs=next_keys)
@@ -830,28 +857,26 @@ class SpecDecoder:
         stats = SpecStats(max_new, max_new * b, 0, max_new, None, 0.0, 1.0)
         return tokens, stats
 
-    def _pard_depth_logits(self, params, gen, n, m, dcache, tables,
-                           pfinfo=None):
+    def _pard_depth_logits(self, params, gen, n, m, dcache, tables, pfinfo):
         """ONE PARD draft forward (Eq. 7): proposal logits for every depth
         1..K. Slot A-1 (the last real token) proposes depth 1, the K-1 mask
         slots the rest. Returns (lg [B, K, V], new draft cache, the
         forward's MoE expert rows).
 
-        ``pfinfo = (prefilling, pf, cl)`` (chunked engine steps only):
-        prefilling rows consume a ``cl``-token prompt chunk at cursor ``pf``
-        through the SAME forward instead of the mask window — their proposal
-        logits are garbage and masked out by the caller's commit logic."""
+        ``pfinfo = (prefilling, pf, cl)``: prefilling rows consume a
+        ``cl``-token prompt chunk at cursor ``pf`` through the SAME forward
+        instead of the mask window — their proposal logits are garbage and
+        masked out by the caller's commit logic."""
         k, dc = self.k, self.dc
         d_has_ssm = _has_ssm(dc)
         tok = _draft_window(gen, n, m, k, dc.mask_token_id)
         pos = m
         ssm_idx = n - m - 1          # state after the last real token (A-1)
-        if pfinfo is not None:
-            prefilling, pf, cl = pfinfo
-            chunk = _chunk_window(gen, pf, cl, 2 * k)
-            tok = jnp.where(prefilling[:, None], chunk, tok)
-            pos = jnp.where(prefilling, pf, pos)
-            ssm_idx = jnp.where(prefilling, cl - 1, ssm_idx)
+        prefilling, pf, cl = pfinfo
+        chunk = _chunk_window(gen, pf, cl, 2 * k)
+        tok = jnp.where(prefilling[:, None], chunk, tok)
+        pos = jnp.where(prefilling, pf, pos)
+        ssm_idx = jnp.where(prefilling, cl - 1, ssm_idx)
         logits, dcache, aux = self._draft_forward(
             params, tok, dcache, pos, tables, collect_ssm=d_has_ssm)
         if d_has_ssm:
@@ -862,10 +887,9 @@ class SpecDecoder:
         return lg, dcache, aux["moe_rows"]
 
     # ------------------------------------------------------------- shared
-    def _build_spec_step(self, mode: str, chunked: bool = False,
-                         greedy_only: bool = False):
-        """One flat speculative step. ``chunked=True`` (the serving
-        engine's unified step, DESIGN.md §8) additionally consumes a
+    def _build_spec_step(self, mode: str, greedy_only: bool = False):
+        """One flat speculative step (``generate_spec`` and the serving
+        engine's unified step, DESIGN.md §8). It also consumes a
         ``chunk_width``-token prompt chunk for every PREFILLING row
         (``state.pf_pos < state.pf_len``) inside the same draft + verify
         forwards: prefilling rows substitute chunk tokens / cursor
@@ -906,12 +930,11 @@ class SpecDecoder:
             tok = _draft_window(gen, n, m, k, mask_id)[:, :k + 1]  # reals only
             pos = m
             ssm_idx = n - m - 1
-            if pfinfo is not None:
-                prefilling, pf, cl = pfinfo
-                tok = jnp.where(prefilling[:, None],
-                                _chunk_window(gen, pf, cl, k + 1), tok)
-                pos = jnp.where(prefilling, pf, pos)
-                ssm_idx = jnp.where(prefilling, cl - 1, ssm_idx)
+            prefilling, pf, cl = pfinfo
+            tok = jnp.where(prefilling[:, None],
+                            _chunk_window(gen, pf, cl, k + 1), tok)
+            pos = jnp.where(prefilling, pf, pos)
+            ssm_idx = jnp.where(prefilling, cl - 1, ssm_idx)
             logits, dcache, aux = self._draft_forward(
                 params, tok, dcache, pos, tables, collect_ssm=d_has_ssm)
             rows = aux["moe_rows"]
@@ -960,17 +983,15 @@ class SpecDecoder:
                 next_keys, use = acceptance.split_row_keys(state.rngs)
                 dkeys = acceptance.fold_row_keys(use, 0)
                 akeys = acceptance.fold_row_keys(use, 1)
-            pfinfo = None
-            if chunked:
-                prefilling, pf = _phase(state)
-                cl = jnp.minimum(cw, state.pf_len - pf)
-                pfinfo = (prefilling, pf, cl)
-                # a prefilling row does not consume its sampling stream, so
-                # a request's sampled trajectory is invariant to HOW its
-                # prompt was prefilled (chunk schedule, prefix-cache hits)
-                if not greedy_only:
-                    next_keys = jnp.where(prefilling[:, None], state.rngs,
-                                          next_keys)
+            prefilling, pf = _phase(state)
+            cl = jnp.minimum(cw, state.pf_len - pf)
+            pfinfo = (prefilling, pf, cl)
+            # a prefilling row does not consume its sampling stream, so a
+            # request's sampled trajectory is invariant to HOW its prompt
+            # was prefilled (chunk schedule, prefix-cache hits)
+            if not greedy_only:
+                next_keys = jnp.where(prefilling[:, None], state.rngs,
+                                      next_keys)
             with jax.named_scope(spans.DRAFT):
                 props, scaled_q, dcache, n_draft, rows = propose(
                     params, gen, n, m, dcache, tables, temp, dkeys, pfinfo)
@@ -980,10 +1001,9 @@ class SpecDecoder:
                 last = jnp.take_along_axis(gen, (n - 1)[:, None], axis=1)
                 vin = jnp.concatenate([last.astype(jnp.int32), props], axis=1)
                 vpos = n - 1
-                if chunked:
-                    vin = jnp.where(prefilling[:, None],
-                                    _chunk_window(gen, pf, cl, k + 1), vin)
-                    vpos = jnp.where(prefilling, pf, vpos)
+                vin = jnp.where(prefilling[:, None],
+                                _chunk_window(gen, pf, cl, k + 1), vin)
+                vpos = jnp.where(prefilling, pf, vpos)
                 logits, tcache_new, aux = self._target_forward(
                     params, vin, tcache, vpos, tables, collect_ssm=t_has_ssm)
                 rows = rows + aux["moe_rows"]
@@ -1016,7 +1036,7 @@ class SpecDecoder:
             with jax.named_scope(spans.COMMIT):
                 # frozen rows commit nothing: done rows stay done, prefilling
                 # rows consumed a prompt chunk instead of a verify window
-                frozen = (done | prefilling) if chunked else done
+                frozen = done | prefilling
 
                 # committed tokens this iteration: d_1..d_a, then commit_tok
                 j = jnp.arange(k + 1)[None, :]
@@ -1039,8 +1059,7 @@ class SpecDecoder:
                     # processed); prefilling rows keep the state after their
                     # chunk's last REAL token (pads excluded — DESIGN.md §3
                     # unchanged)
-                    ssm_idx = (a if not chunked
-                               else jnp.where(prefilling, cl - 1, a))
+                    ssm_idx = jnp.where(prefilling, cl - 1, a)
                     tcache_new = gather_ssm_states(tc, tcache_new, ssm_idx)
                 # frozen rows keep old caches? their cache contents are
                 # untouched at positions < n and never read beyond; safe to
@@ -1060,8 +1079,7 @@ class SpecDecoder:
                 new_state = dataclasses.replace(
                     state, gen=gen, n=new_n, m=new_m, tcache=tcache_new,
                     dcache=dcache, rngs=next_keys,
-                    pf_pos=(state.pf_pos if not chunked else
-                            jnp.where(prefilling, pf + cl, state.pf_pos)),
+                    pf_pos=jnp.where(prefilling, pf + cl, state.pf_pos),
                     moe_rows=None if state.moe_rows is None else rows)
                 return (new_state, jnp.where(frozen, 0, a), acc_hist,
                         round_hist, rank, n_draft)
@@ -1069,8 +1087,7 @@ class SpecDecoder:
         return step
 
     # --------------------------------------------------------------- tree
-    def _build_tree_step(self, chunked: bool = False,
-                         greedy_only: bool = False):
+    def _build_tree_step(self, greedy_only: bool = False):
         """One tree-verification step over PER-ROW templates (DESIGN.md
         §6/§7).
 
@@ -1103,8 +1120,8 @@ class SpecDecoder:
         positions; losing branches (and slots past a row's template) are
         re-covered by the next window's cache_pos like flat-K rejects.
 
-        ``chunked=True``: prefilling rows ride the same two forwards with
-        prompt chunks (DESIGN.md §8). In the packed tree window a chunk is
+        Prefilling rows ride the same two forwards with prompt chunks
+        (DESIGN.md §8). In the packed tree window a chunk is
         just a CAUSAL "tree": ancestor bitmask = all-lower-bits, win_len =
         the chunk's real token count, positions = cursor + slot — so the
         tree kernels serve mixed prefill/decode batches unchanged.
@@ -1143,17 +1160,15 @@ class SpecDecoder:
             cmap, nslots = bank_cmap[sel], bank_nslots[sel]
             node_depth = depth[:, 1:]                              # [B, N]
 
-            pfinfo = None
-            if chunked:
-                prefilling, pf = _phase(state)
-                cl = jnp.minimum(cw, state.pf_len - pf)
-                pfinfo = (prefilling, pf, cl)
-                # prefilling rows keep their sampling stream untouched (see
-                # _build_spec_step): sampled output is prefill-schedule- and
-                # prefix-cache-invariant
-                if not greedy_only:
-                    next_keys = jnp.where(prefilling[:, None], state.rngs,
-                                          next_keys)
+            prefilling, pf = _phase(state)
+            cl = jnp.minimum(cw, state.pf_len - pf)
+            pfinfo = (prefilling, pf, cl)
+            # prefilling rows keep their sampling stream untouched (see
+            # _build_spec_step): sampled output is prefill-schedule- and
+            # prefix-cache-invariant
+            if not greedy_only:
+                next_keys = jnp.where(prefilling[:, None], state.rngs,
+                                      next_keys)
 
             with jax.named_scope(spans.DRAFT):
                 # draft: depth distributions -> per-row template tokens. One
@@ -1192,23 +1207,22 @@ class SpecDecoder:
                 vin = jnp.concatenate([last.astype(jnp.int32), props], axis=1)
                 positions = (n - 1)[:, None] + depth
                 win_start, win_anc, win_len = n - 1, anc, nslots
-                if chunked:
-                    # prefilling rows: a cl-token causal chunk through the
-                    # same packed window (pads past cl are invisible and
-                    # re-covered). Slice at the chunk width cw — guaranteed
-                    # inside the gen buffer by the slack validation — and pad
-                    # to the window: slicing at s (up to 32) could clamp near
-                    # max_len and silently shift the chunk.
-                    chunk = _chunk_window(gen, pf, cl, cw)
-                    chunk = jnp.pad(chunk, ((0, 0), (0, s - cw)))
-                    vin = jnp.where(prefilling[:, None], chunk, vin)
-                    positions = jnp.where(
-                        prefilling[:, None],
-                        pf[:, None] + jnp.arange(s)[None, :], positions)
-                    win_start = jnp.where(prefilling, pf, win_start)
-                    win_anc = jnp.where(prefilling[:, None],
-                                        chain_anc[None, :], win_anc)
-                    win_len = jnp.where(prefilling, cl, win_len)
+                # prefilling rows: a cl-token causal chunk through the same
+                # packed window (pads past cl are invisible and re-covered).
+                # Slice at the chunk width cw — guaranteed inside the gen
+                # buffer by the slack validation — and pad to the window:
+                # slicing at s (up to 32) could clamp near max_len and
+                # silently shift the chunk.
+                chunk = _chunk_window(gen, pf, cl, cw)
+                chunk = jnp.pad(chunk, ((0, 0), (0, s - cw)))
+                vin = jnp.where(prefilling[:, None], chunk, vin)
+                positions = jnp.where(
+                    prefilling[:, None],
+                    pf[:, None] + jnp.arange(s)[None, :], positions)
+                win_start = jnp.where(prefilling, pf, win_start)
+                win_anc = jnp.where(prefilling[:, None],
+                                    chain_anc[None, :], win_anc)
+                win_len = jnp.where(prefilling, cl, win_len)
                 tinfo = TreeAttnInfo(win_start=win_start, anc=win_anc,
                                      win_len=win_len)
                 logits, tcache_new, aux = self._target_forward(
@@ -1250,7 +1264,7 @@ class SpecDecoder:
             with jax.named_scope(spans.COMMIT):
                 # frozen rows commit nothing: done rows stay done, prefilling
                 # rows consumed a prompt chunk instead of a verify window
-                frozen = (done | prefilling) if chunked else done
+                frozen = done | prefilling
 
                 dflt = jnp.arange(1, d + 1, dtype=jnp.int32)[None, :]
                 # rejected depths and frozen rows: identity copy (src == dst)
@@ -1294,8 +1308,7 @@ class SpecDecoder:
                 new_state = dataclasses.replace(
                     state, gen=gen, n=new_n, m=new_m, tcache=tcache_new,
                     dcache=dcache, rngs=next_keys,
-                    pf_pos=(state.pf_pos if not chunked else
-                            jnp.where(prefilling, pf + cl, state.pf_pos)),
+                    pf_pos=jnp.where(prefilling, pf + cl, state.pf_pos),
                     moe_rows=None if state.moe_rows is None else rows)
                 return new_state, jnp.where(frozen, 0, a), hist, round_hist, \
                     rank, 1
@@ -1319,18 +1332,20 @@ class SpecDecoder:
         # must NOT see it twice, so it is excluded here).
         assert p >= 2, "prompts must have at least 2 tokens"
         L = p + max_new + self.window_slack   # room for the final window
-        state = self.init_state(prompt, L, seed=seed)
+        state = self._uniform_state(prompt, L, L, with_draft=True, seed=seed)
         if tree_idx is not None:
             idx = np.asarray(tree_idx, np.int32)
             assert idx.shape == (b,) and idx.min() >= 0 \
                 and idx.max() < len(self.tree), idx
             state = dataclasses.replace(state, tree_idx=jnp.asarray(idx))
 
-        prefill_t = self._fn("sp_prefill_t", lambda p, t, c: prefill_row(
-            p, self.tc, t, None, c, enc_out=self.enc_out), donate=(2,))
-        prefill_d = self._fn("sp_prefill_d", lambda p, t, c: prefill_row(
-            p, self.dc, t, None, c, enc_out=self.draft_enc_out),
-            donate=(2,))
+        bs = self.kv_block_size
+        prefill_t = self._fn("sp_prefill_t", lambda p, t, c, tb: prefill_row(
+            p, self.tc, t, c, tables=tb, block_size=bs,
+            enc_out=self.enc_out), donate=(2,))
+        prefill_d = self._fn("sp_prefill_d", lambda p, t, c, tb: prefill_row(
+            p, self.dc, t, c, tables=tb, block_size=bs,
+            enc_out=self.draft_enc_out), donate=(2,))
         # donate the whole state: the steady state then updates gen + both
         # cache pools in place (no per-iteration multi-MB buffer copies)
         # greedy batches compile the sampled machinery out entirely (no
@@ -1347,8 +1362,10 @@ class SpecDecoder:
                             donate=(1,))
 
         state = dataclasses.replace(
-            state, tcache=prefill_t(self.tp, prompt[:, :-1], state.tcache),
-            dcache=prefill_d(self.dp, prompt[:, :-1], state.dcache))
+            state, tcache=prefill_t(self.tp, prompt[:, :-1], state.tcache,
+                                    state.tables),
+            dcache=prefill_d(self.dp, prompt[:, :-1], state.dcache,
+                             state.tables))
 
         params = (self.tp, self.dp)
         iters, draft_calls, target_calls = 0, 0, 0

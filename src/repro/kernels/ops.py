@@ -255,6 +255,10 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
 def decode_attention(q, k, v, kv_len, q_pos, *, k_scale=None, v_scale=None,
                      window=0, softcap=0.0, scale=None, block_k=256,
                      interpret=None):
+    """Decode/verify attention over per-row K/V ``[B, S, Hkv, D]``. The
+    serving forward reaches it only through ``attention.attend`` on a
+    row's pool pages gathered into a per-row view (windows wider than the
+    paged kernel's); the pool itself goes to ``decode_attention_paged``."""
     interpret = _interpret(interpret)
     b, tq, hq, d = q.shape
     block_k = min(block_k, max(8, 1 << (k.shape[1] - 1).bit_length()))
@@ -341,7 +345,10 @@ def grouped_matmul(lhs, rhs, group_sizes, *, interpret=None):
 def tree_attention(q, k, v, kv_len, q_pos, win_start, anc, *, win_len=None,
                    k_scale=None, v_scale=None, window=0, softcap=0.0,
                    scale=None, block_k=256, interpret=None):
-    """Tree-verification attention against a contiguous cache. ``anc`` is
+    """Tree-verification attention over per-row K/V ``[B, S, Hkv, D]`` —
+    in serving, a row's pool pages gathered into a per-row view (MLA tree
+    verification's gather path); the pool itself goes to
+    ``tree_attention_paged``. ``anc`` is
     the [B, Tq] uint32 packed ancestor bitmask (bit j = window slot j
     visible); ``win_start`` the cache index of window slot 0; ``win_len``
     the optional [B] per-row count of meaningful window slots (per-request

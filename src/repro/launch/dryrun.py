@@ -25,16 +25,16 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import ASSIGNED, get_config
 from repro.launch import roofline
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_production_mesh, replica_submeshes
 from repro.launch.steps import (SHAPES, input_specs, make_decode_step,
                                 make_prefill_step, make_train_step,
                                 make_verify_step, opt_state_shapes,
                                 param_shapes)
-from repro.sharding.specs import cache_specs, data_spec, param_specs
+from repro.sharding.specs import data_spec, paged_cache_specs, param_specs
 from repro.training.optimizer import AdamW
 
 # long_500k policy (DESIGN.md §4): runs natively for SSM/hybrid; gemma2 runs
@@ -71,13 +71,17 @@ def lower_one(arch: str, shape: str, *, multi_pod: bool = False,
       expert_parallel  — MoE experts sharded over the model axis
                          (all-to-all dispatch)
       no_remat         — training without activation checkpointing
-      seq_shard_verify — (with mode=pard_verify) long-context: shard the
-                         KV sequence over BOTH data and model axes
+
+    The serving steps lower as one data-parallel replica serves them
+    (DESIGN.md §12): its share of the global batch, over its own KV pool
+    (``paged_cache_specs``: KV heads over "model", the rest replicated)
+    with per-row block tables in the batch, on one row of "model" devices.
+    Replicas run no collective, so per-device numbers are one replica's;
+    ``rec["mesh"]`` stays the whole mesh for the roofline's chip count.
     """
     cfg = get_config(arch)
     sh = SHAPES[shape]
     kind = sh["kind"]
-    b, s = sh["global_batch"], sh["seq_len"]
     if mesh is None:
         mesh = make_production_mesh(multi_pod=multi_pod)
     window = 0
@@ -115,10 +119,14 @@ def lower_one(arch: str, shape: str, *, multi_pod: bool = False,
         else:
             pspec = param_specs(params, mesh, fsdp=False, expert_parallel=ep)
         cache_dtype = jnp.int8 if variant == "kv8" else jnp.bfloat16
-        ins = input_specs(cfg, shape, mode=mode, cache_dtype=cache_dtype)
+        tp = mesh.shape["model"]
+        replicas = mesh.devices.size // tp
+        mesh = replica_submeshes(
+            Mesh(mesh.devices.reshape(replicas, tp), ("data", "model")))[0]
+        ins = input_specs(cfg, shape, mode=mode, cache_dtype=cache_dtype,
+                          replicas=replicas)
         caches = ins["caches"]
-        cspec = cache_specs(caches, cfg, mesh, b,
-                            seq_model_shard=variant == "seq_shard_verify")
+        cspec = paged_cache_specs(caches, mesh)
         bspec = {k: data_spec(mesh, v.shape[0], len(v.shape))
                  for k, v in ins["batch"].items()}
         if kind == "prefill":

@@ -217,16 +217,18 @@ def _param_bytes(cfg: ModelConfig) -> float:
 
 
 def _kv_cache_bytes_per_device(cfg: ModelConfig, batch, seq, mesh) -> float:
-    """KV bytes one decode step must stream, per device, honouring the
-    cache_specs sharding (batch over data when divisible, else seq; kv heads
-    over model when divisible, else REPLICATED — the command-r-35b kv=8 case
-    reads the full per-batch-shard cache on every device)."""
+    """KV bytes one decode step must stream, per device, as data-parallel
+    serving holds it: each replica (one row of "model" devices) serves its
+    share of the rows, rounded up, at their full length; kv heads over
+    model when divisible, else REPLICATED (paged_cache_specs — the
+    command-r-35b kv=8 case reads its replica's whole pool on every
+    device)."""
     model = mesh[-1]
     data = 1
     for m in mesh[:-1]:
         data *= m
-    b_local = batch / data if batch % data == 0 else batch
-    s_local = seq if batch % data == 0 else seq / data
+    b_local = -(-batch // data)
+    s_local = seq
     total = 0.0
     for spec in layer_plan(cfg):
         if spec.mixer in ("attn_global", "attn_local"):

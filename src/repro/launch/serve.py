@@ -123,13 +123,6 @@ def main():
                          "(the rest use --temperature): one batch mixes "
                          "greedy and sampled rows")
     ap.add_argument("--seed", type=int, default=0)
-    layout = ap.add_mutually_exclusive_group()
-    layout.add_argument("--paged", dest="kv_layout", action="store_const",
-                        const="paged", help="block-paged KV cache (default)")
-    layout.add_argument("--contiguous", dest="kv_layout",
-                        action="store_const", const="contiguous",
-                        help="full-length per-slot KV rows")
-    ap.set_defaults(kv_layout="paged")
     ap.add_argument("--kv-block-size", type=int, default=64)
     ap.add_argument("--kv-dtype", default="bf16",
                     choices=["bf16", "fp32", "int8", "fp8"],
@@ -138,9 +131,10 @@ def main():
                          "dequantize inside the attention kernels "
                          "(DESIGN.md §10)")
     ap.add_argument("--kv-num-blocks", type=int, default=None,
-                    help="paged pool size (default: worst-case coverage)")
+                    help="KV pool size in blocks (default: worst-case "
+                         "coverage)")
     ap.add_argument("--prefix-cache", action="store_true",
-                    help="refcounted prompt-prefix reuse in the paged pool "
+                    help="refcounted prompt-prefix reuse in the KV pool "
                          "(DESIGN.md §8): same-prefix requests map cached "
                          "blocks copy-free and only prefill their tails")
     ap.add_argument("--prefix-share", type=int, default=1, metavar="N",
@@ -243,7 +237,7 @@ def main():
     print(f"host overhead (harvest->dispatch) "
           f"p50={lat['host_overhead_p50_ms']:.2f}ms "
           f"p95={lat['host_overhead_p95_ms']:.2f}ms")
-    print(f"kv layout={args.kv_layout} dtype={args.kv_dtype} "
+    print(f"kv block_size={args.kv_block_size} dtype={args.kv_dtype} "
           f"capacity={eng.kv_capacity_bytes() / 1e6:.2f}MB "
           f"peak_in_use={eng.peak_kv_bytes_in_use / 1e6:.2f}MB")
     if args.prefix_cache:

@@ -9,7 +9,10 @@ Four lowered entry points per architecture (matching the assigned shapes):
                   the same cache (the paper's serving hot path; used by the
                   §Perf analysis and --mode pard_verify)
 
-Every input is a ShapeDtypeStruct (``input_specs``) — nothing allocates.
+The cached steps run over the served KV layout: pools of ``KV_BLOCK``-token
+pages (models.init_caches) read through per-row block tables, which ride
+in the batch as ``tables``. Every input is a ShapeDtypeStruct
+(``input_specs``) — nothing allocates.
 """
 from __future__ import annotations
 
@@ -20,9 +23,10 @@ import jax
 import jax.numpy as jnp
 
 from ..core.adaptation import ar_loss
-from ..models import (encode, forward, frontend_embed_spec, init_caches,
-                      init_params)
+from ..models import (KV_BLOCK, blocks_for, encode, forward,
+                      frontend_embed_spec, init_caches, init_params)
 from ..models.config import ModelConfig, SSM, scan_plan
+from ..serving.kv_pool import default_num_blocks
 from ..training.optimizer import AdamW
 
 SHAPES = {
@@ -64,7 +68,8 @@ def make_prefill_step(cfg: ModelConfig):
         logits, caches, _ = forward(
             params, cfg, batch["tokens"], caches=caches,
             cache_pos=jnp.zeros((b,), jnp.int32), enc_out=enc_out,
-            last_only=True)
+            last_only=True, block_tables=batch["tables"],
+            kv_block_size=KV_BLOCK)
         return logits[:, -1], caches
     return prefill_step
 
@@ -77,7 +82,8 @@ def make_decode_step(cfg: ModelConfig, *, window: int = 0):
         enc_out = _enc_out(params, cfg, batch)
         logits, caches, _ = forward(
             params, cfg, batch["tokens"], caches=caches,
-            cache_pos=batch["cache_pos"], enc_out=enc_out)
+            cache_pos=batch["cache_pos"], enc_out=enc_out,
+            block_tables=batch["tables"], kv_block_size=KV_BLOCK)
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return nxt, caches
     return decode_step
@@ -94,7 +100,8 @@ def make_verify_step(cfg: ModelConfig, *, k: int = PARD_K, window: int = 0):
         enc_out = _enc_out(params, cfg, batch)
         logits, caches, _ = forward(
             params, cfg, batch["tokens"], caches=caches,
-            cache_pos=batch["cache_pos"], enc_out=enc_out, collect_ssm=collect)
+            cache_pos=batch["cache_pos"], enc_out=enc_out, collect_ssm=collect,
+            block_tables=batch["tables"], kv_block_size=KV_BLOCK)
         tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # [B, K+1]
         return tgt, caches
     return verify_step
@@ -136,16 +143,25 @@ def opt_state_shapes(cfg: ModelConfig, opt: AdamW):
 
 def cache_shapes(cfg: ModelConfig, batch: int, max_len: int,
                  dtype=jnp.bfloat16):
-    return jax.eval_shape(
-        functools.partial(init_caches, cfg, batch, max_len, dtype=dtype))
+    """The KV pools of ``batch`` rows of ``max_len`` positions: the
+    worst-case pool (every row full, plus the garbage block) of
+    ``KV_BLOCK``-token pages."""
+    num_blocks = default_num_blocks(batch, max_len, KV_BLOCK)
+    return jax.eval_shape(functools.partial(
+        init_caches, cfg, batch, num_blocks, KV_BLOCK, dtype=dtype))
 
 
 def input_specs(cfg: ModelConfig, shape_name: str, *, mode: str = "default",
-                k: int = PARD_K, cache_dtype=jnp.bfloat16) -> Dict[str, Any]:
-    """Returns {fn-kwargs-name: ShapeDtypeStruct} for the lowered step."""
+                k: int = PARD_K, cache_dtype=jnp.bfloat16,
+                replicas: int = 1) -> Dict[str, Any]:
+    """Returns {fn-kwargs-name: ShapeDtypeStruct} for the lowered step. A
+    serving step holds one of ``replicas`` data-parallel replicas' share of
+    the global batch (rounded up) and a pool for those rows."""
     sh = SHAPES[shape_name]
     b, s = sh["global_batch"], sh["seq_len"]
     kind = sh["kind"]
+    if kind != "train":
+        b = -(-b // replicas)
     batch: Dict[str, Any] = {}
     fe = frontend_embed_spec(cfg, b)
 
@@ -155,8 +171,11 @@ def input_specs(cfg: ModelConfig, shape_name: str, *, mode: str = "default",
             batch["frontend_embed"] = fe
         return {"batch": batch}
 
+    # each row's block table: its own blocks_for(s) pages of the pool
+    tables = jax.ShapeDtypeStruct((b, blocks_for(s, KV_BLOCK)), jnp.int32)
     if kind == "prefill":
         batch["tokens"] = jax.ShapeDtypeStruct((b, s), jnp.int32)
+        batch["tables"] = tables
         if fe is not None:
             batch["frontend_embed"] = fe
         return {"caches": cache_shapes(cfg, b, s, dtype=cache_dtype),
@@ -166,6 +185,7 @@ def input_specs(cfg: ModelConfig, shape_name: str, *, mode: str = "default",
     q = 1 if mode != "pard_verify" else k + 1
     batch["tokens"] = jax.ShapeDtypeStruct((b, q), jnp.int32)
     batch["cache_pos"] = jax.ShapeDtypeStruct((b,), jnp.int32)
+    batch["tables"] = tables
     if fe is not None:
         batch["frontend_embed"] = fe
     return {"caches": cache_shapes(cfg, b, s, dtype=cache_dtype),

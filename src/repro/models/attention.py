@@ -7,24 +7,18 @@ mask (Fig. 4/5 of the paper) enters through ``mask_info`` — per-token
 (segment, base) metadata — and is computed functionally, never materialised
 by the caller.
 
-KV caches come in two layouts (DESIGN.md §5):
-
-  * contiguous — one full-length buffer per batch row, indexed by absolute
-    position; speculative rollback is just resetting ``cache_pos`` (stale
-    entries are masked out by the validity test ``kv_index < kv_len``);
-  * paged — fixed-size KV blocks in a shared pool ``[num_blocks, block,
-    ...]`` with a per-row block table ``[B, max_blocks]`` mapping absolute
-    position ``p`` to ``(table[b, p // block], p % block)``. Block 0 is the
-    reserved garbage block: unallocated table entries point at it, so writes
-    past a row's allocation land there and are never attended (reads are
-    bounded by ``kv_len``). The serving pool lives in serving/kv_pool.py.
-    Inside the layer loop a scanned layer's pool stays stacked over the
-    layers, ``[L, num_blocks, block, ...]``, and the paged functions take
-    the ``layer`` to write and read (models/transformer.py ``forward``).
-
-The paged layout is selected by passing ``block_tables``/``kv_block_size``
-through ``forward`` — the same rollback-by-``cache_pos`` semantics hold
-because validity is still ``kv_index < kv_len``.
+KV caches are block-paged pools (DESIGN.md §5): fixed-size KV blocks in a
+shared pool ``[num_blocks, block, ...]`` with a per-row block table
+``[B, max_blocks]`` mapping absolute position ``p`` to
+``(table[b, p // block], p % block)``. Block 0 is the reserved garbage
+block: unallocated table entries point at it, so writes past a row's
+allocation land there and are never attended (reads are bounded by
+``kv_len``). Speculative rollback is just resetting ``cache_pos``: stale
+entries are masked out by the validity test ``kv_index < kv_len``. Inside
+the layer loop a scanned layer's pool stays stacked over the layers,
+``[L, num_blocks, block, ...]``, and the paged functions take the
+``layer`` to write and read (models/transformer.py ``forward``). The
+serving allocator lives in serving/kv_pool.py.
 
 Chunked prefill (DESIGN.md §8) needs nothing new here: every path is
 driven by PER-ROW ``cache_pos``/positions, so one forward mixes decoding
@@ -230,6 +224,13 @@ def attend(q, k, v, q_pos, kv_pos, kv_len, *, causal=True, window=0,
     """Masked multi-head attention: the Pallas kernels where ``_pallas_ok``
     picks them, else the jnp reference (``attend_reference``).
 
+    Either an uncached forward (training, scoring, the encoder: k/v are
+    the forward's own keys) or a cached window against a row's pool pages
+    gathered into a per-row view — ``_paged_attend`` and ``mla_apply``
+    where the paged kernels do not apply. Only that gathered view reaches
+    the contiguous-operand kernel wrappers (``ops.decode_attention`` /
+    ``ops.tree_attention``).
+
     q:      [B, Tq, Hq, Dk]
     k, v:   [B, Tk, Hkv, Dk] / [B, Tk, Hkv, Dv]
     q_pos:  [B, Tq] absolute positions of queries
@@ -349,26 +350,18 @@ def init_gqa(key, cfg, cross: bool = False):
     return p
 
 
-def init_gqa_cache(cfg, batch, max_len, dtype=jnp.bfloat16):
+def init_gqa_pool(cfg, num_blocks, block_size, dtype=jnp.bfloat16):
+    """One GQA layer's KV pool: ``k``/``v`` ``[NB, bs, Hkv, hd]``.
+    Quantized dtypes add per-(slot, head) dequant scales that ride through
+    the same block-table indirection (DESIGN.md §10); scale 1 keeps the
+    garbage block dequantizing to exact zeros."""
     hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-    c = {"k": jnp.zeros((batch, max_len, hkv, hd), dtype),
-         "v": jnp.zeros((batch, max_len, hkv, hd), dtype)}
+    c = {"k": jnp.zeros((num_blocks, block_size, hkv, hd), dtype),
+         "v": jnp.zeros((num_blocks, block_size, hkv, hd), dtype)}
     if kv_dtype_is_quantized(dtype):
-        c["k_scale"] = jnp.ones((batch, max_len, hkv), jnp.float32)
-        c["v_scale"] = jnp.ones((batch, max_len, hkv), jnp.float32)
+        c["k_scale"] = jnp.ones((num_blocks, block_size, hkv), jnp.float32)
+        c["v_scale"] = jnp.ones((num_blocks, block_size, hkv), jnp.float32)
     return c
-
-
-def _write_cache(buf, new, cache_pos):
-    """buf: [B, max, ...]; new: [B, T, ...]; cache_pos: [B] int32."""
-    b, t = new.shape[0], new.shape[1]
-
-    def row(buf_r, new_r, p):
-        return jax.lax.dynamic_update_slice(
-            buf_r, new_r.astype(buf_r.dtype),
-            (p,) + (0,) * (buf_r.ndim - 1))
-
-    return jax.vmap(row)(buf, new, cache_pos)
 
 
 def write_cache_paged(pages, new, cache_pos, block_tables, block_size,
@@ -412,7 +405,7 @@ def paged_index(block_tables, pos, block_size, heads=None):
 
 
 def gather_pages(pages, block_tables):
-    """Per-row contiguous view of a paged pool (the reference read path).
+    """Per-row view of a paged pool (the reference read path).
 
     pages: [NB, bs, ...]; block_tables: [B, MBS] -> [B, MBS * bs, ...].
     """
@@ -452,12 +445,10 @@ def _paged_attend(q, k_pages, v_pages, block_tables, q_pos, kv_len, *,
 
     Uses the Pallas paged decode kernel for small query windows on the
     pallas backend (the kernel's mask is causal, so only when ``causal``);
-    otherwise gathers the row's blocks into a contiguous view and reuses
-    the standard ``attend`` core (semantic reference). The gathered
-    temporary is the same size as a contiguous cache buffer, so the
-    reference path's peak memory matches the contiguous layout — the
-    paged layout's HBM win is the persistent pool, and the per-step copy
-    is avoided wherever the kernel path is active (TPU decode/verify).
+    otherwise gathers the row's blocks into a per-row view and reuses the
+    standard ``attend`` core (semantic reference). The gathered temporary
+    holds every row's whole table, so the per-step copy is avoided only
+    where the kernel path is active (TPU decode/verify).
     """
     b, tq = q.shape[:2]
     kernel = (causal and tq <= _PAGED_KERNEL_MAX_TQ
@@ -504,8 +495,10 @@ def _paged_attend(q, k_pages, v_pages, block_tables, q_pos, kv_len, *,
 def gqa_apply(params, cfg, x, positions, *, layer_window=0, cache=None,
               cache_pos=None, mask_info=None, causal=True, use_rope=True,
               block_tables=None, kv_block_size=0, tree_info=None, layer=None):
-    """Self attention. Returns (y, new_cache). ``layer``: with paged caches,
-    the leaves are pools stacked over layers, written and read there."""
+    """Self attention. Returns (y, new_cache). Without a cache, attention
+    over the forward's own keys; with one, ``cache`` holds KV pools written
+    and read through ``block_tables`` (``layer``: pools stacked over
+    layers, written and read at that layer)."""
     b, t, _ = x.shape
     q = jnp.einsum("btd,dhk->bthk", x, params["wq"].astype(x.dtype))
     k = jnp.einsum("btd,dhk->bthk", x, params["wk"].astype(x.dtype))
@@ -527,7 +520,7 @@ def gqa_apply(params, cfg, x, positions, *, layer_window=0, cache=None,
                      window=layer_window, attn_softcap=cfg.attn_softcap,
                      scale=scale, mask_info=mask_info)
         new_cache = None
-    elif block_tables is not None:
+    else:
         if "k_scale" in cache:
             # quantized pool: quantize on append, so prefill chunks, decode
             # windows and tree-window compaction all produce quantized
@@ -555,27 +548,6 @@ def gqa_apply(params, cfg, x, positions, *, layer_window=0, cache=None,
                             tree_info=tree_info,
                             k_scale=new_cache.get("k_scale"),
                             v_scale=new_cache.get("v_scale"), layer=layer)
-    else:
-        if "k_scale" in cache:
-            k, sk = quantize_kv(k, cache["k"].dtype)
-            v, sv = quantize_kv(v, cache["v"].dtype)
-            new_cache = {"k_scale": _write_cache(cache["k_scale"], sk,
-                                                 cache_pos),
-                         "v_scale": _write_cache(cache["v_scale"], sv,
-                                                 cache_pos)}
-        else:
-            new_cache = {}
-        new_cache["k"] = _write_cache(cache["k"], k, cache_pos)
-        new_cache["v"] = _write_cache(cache["v"], v, cache_pos)
-        new_k, new_v = new_cache["k"], new_cache["v"]
-        max_len = new_k.shape[1]
-        kv_pos = jnp.broadcast_to(jnp.arange(max_len)[None, :], (b, max_len))
-        kv_len = cache_pos + t
-        out = attend(q, new_k, new_v, positions, kv_pos, kv_len, causal=causal,
-                     window=layer_window, attn_softcap=cfg.attn_softcap,
-                     scale=scale, tree_info=tree_info,
-                     k_scale=new_cache.get("k_scale"),
-                     v_scale=new_cache.get("v_scale"))
     # sharded serving seam (DESIGN.md §11/§13): exact ruleset all-gathers
     # the head-sharded context BEFORE the wo contraction (bitwise cross-mesh
     # identity); throughput ruleset contracts it row-parallel at canonical
@@ -663,12 +635,14 @@ def mla_pool_width(cfg) -> int:
     return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
 
 
-def init_mla_cache(cfg, batch, max_len, dtype=jnp.bfloat16):
-    width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
-    c = {"ckv": jnp.zeros((batch, max_len, width), dtype)}
+def init_mla_pool(cfg, num_blocks, block_size, dtype=jnp.bfloat16):
+    """One MLA layer's latent pool ``ckv`` ``[NB, bs, mla_pool_width]``;
+    quantized dtypes add one scale per compressed-KV vector (the latent IS
+    the "head")."""
+    c = {"ckv": jnp.zeros((num_blocks, block_size, mla_pool_width(cfg)),
+                          dtype)}
     if kv_dtype_is_quantized(dtype):
-        # one scale per compressed-KV vector (the latent IS the "head")
-        c["ckv_scale"] = jnp.ones((batch, max_len), jnp.float32)
+        c["ckv_scale"] = jnp.ones((num_blocks, block_size), jnp.float32)
     return c
 
 
@@ -690,7 +664,7 @@ def mla_softmax_scale(cfg) -> float:
 def latent_attend_reference(q, pages, block_tables, kv_len, q_pos, *,
                             rank, scale):
     """Absorbed MLA attention against a paged latent pool, in jnp: each
-    row's pages gathered into a contiguous view (the reference read path).
+    row's pages gathered into a per-row view (the reference read path).
 
     q: [B, Tq, H, W] — the absorbed query ``[q_nope W_uk | q_rope]``, W =
     rank + dr; pages: [NB, bs, P >= W] rows ``[c_kv | k_rope | pad]``;
@@ -746,16 +720,15 @@ def mla_apply(params, cfg, x, positions, *, cache=None, cache_pos=None,
     k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
                         yarn_cfg=cfg)[:, :, 0, :]
     compressed = jnp.concatenate([ckv, k_rope], axis=-1)     # [B,T,r_kv+dr]
-    if block_tables is not None:
-        # a paged pool row carries zero lanes past r_kv + dr
+    if cache is not None:
+        # a pool row carries zero lanes past r_kv + dr
         compressed = jnp.pad(compressed, ((0, 0), (0, 0), (
             0, mla_pool_width(cfg) - compressed.shape[-1])))
 
     from ..kernels import ops
-    if (cache is not None and block_tables is not None
-            and "ckv_scale" not in cache and tree_info is None
+    if (cache is not None and "ckv_scale" not in cache and tree_info is None
             and t <= _PAGED_KERNEL_MAX_TQ):
-        # paged decode / verify / prefill chunk: attention in absorbed form
+        # decode / verify / prefill chunk: attention in absorbed form
         # over the latent pages themselves. W_uk folds into the query and
         # W_uv applies after attention, so the scores are
         # [q_nope W_uk | q_rope] . [c_kv | k_rope] and the output
@@ -777,11 +750,11 @@ def mla_apply(params, cfg, x, positions, *, cache=None, cache_pos=None,
                                    x_axis=-2, w_axis=0)
         return ops.gather_activation(y), {"ckv": pages}
 
-    if cache is not None and block_tables is not None:
-        # paged MLA otherwise (tree verification, quantized pools, long
-        # prefill windows): the compressed KV pages gather into a per-row
-        # view, projected to full K/V below as on the other paths.
-        # Quantized pools dequantize at the gather.
+    if cache is not None:
+        # otherwise (tree verification, quantized pools, long prefill
+        # windows): the compressed KV pages gather into a per-row view,
+        # projected to full K/V below as on the uncached path. Quantized
+        # pools dequantize at the gather.
         if "ckv_scale" in cache:
             qc, sc = quantize_kv(compressed, cache["ckv"].dtype)
             pages = write_cache_paged(cache["ckv"], qc, cache_pos,
@@ -799,20 +772,6 @@ def mla_apply(params, cfg, x, positions, *, cache=None, cache_pos=None,
             new_cache = {"ckv": pages}
             kv_src = gather_pages(*layer_pages(pages, block_tables, layer))
         s = kv_src.shape[1]
-        kv_pos = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-        kv_len = cache_pos + t
-    elif cache is not None:
-        if "ckv_scale" in cache:
-            qc, sc = quantize_kv(compressed, cache["ckv"].dtype)
-            buf = _write_cache(cache["ckv"], qc, cache_pos)
-            sbuf = _write_cache(cache["ckv_scale"], sc, cache_pos)
-            new_cache = {"ckv": buf, "ckv_scale": sbuf}
-            kv_src = dequantize_kv(buf, sbuf).astype(x.dtype)
-        else:
-            buf = _write_cache(cache["ckv"], compressed, cache_pos)
-            new_cache = {"ckv": buf}
-            kv_src = buf
-        s = new_cache["ckv"].shape[1]
         kv_pos = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
         kv_len = cache_pos + t
     else:

@@ -3,13 +3,15 @@
 The layer stack is executed as ``lax.scan`` over repeating *periods* (see
 config.scan_plan) so 95-layer models lower to a small HLO. Params, caches and
 SSM states for scanned layers are stacked on a leading ``n_repeats`` axis;
-prefix layers (e.g. deepseek-v2's leading dense layer) run unrolled. Paged
-attention pools ride the scan's carry, written and read in place at the
-layer index; every other cache leaf is a scan input and output.
+prefix layers (e.g. deepseek-v2's leading dense layer) run unrolled.
+Attention pools ride the scan's carry, written and read in place at the
+layer index; SSM states are a scan input and output.
 
 Public entry points:
   init_params(key, cfg)                      -> param pytree
-  init_caches(cfg, batch, max_len, dtype)    -> cache pytree (decode)
+  init_caches(cfg, batch, num_blocks, block_size, dtype)
+                                             -> cache pytree (decode)
+  KV_BLOCK, blocks_for(n_tokens, block_size) -> the page size and arithmetic
   encode(params, cfg, frontend_embed)        -> encoder output (enc-dec only)
   forward(params, cfg, tokens, ...)          -> (logits, new_caches, aux)
 """
@@ -108,11 +110,20 @@ def init_params(key, cfg: ModelConfig):
     return params
 
 
-def _init_layer_cache(cfg, spec: LayerSpec, batch, max_len, dtype):
+KV_BLOCK = 64   # tokens per KV page: the served page size
+
+
+def blocks_for(n_tokens: int, block_size: int) -> int:
+    """Blocks covering ``n_tokens`` positions (ceiling division)."""
+    return -(-int(n_tokens) // block_size)
+
+
+def _init_layer_cache(cfg, spec: LayerSpec, batch, num_blocks, block_size,
+                      dtype):
     if spec.mixer in (ATTN_GLOBAL, ATTN_LOCAL):
-        return attn.init_gqa_cache(cfg, batch, max_len, dtype)
+        return attn.init_gqa_pool(cfg, num_blocks, block_size, dtype)
     if spec.mixer == ATTN_MLA:
-        return attn.init_mla_cache(cfg, batch, max_len, dtype)
+        return attn.init_mla_pool(cfg, num_blocks, block_size, dtype)
     if spec.mixer == SSM:
         return ssm_mod.init_mamba2_state(cfg, batch, jnp.float32)
     if spec.mixer == ATTN_CROSS:
@@ -120,21 +131,27 @@ def _init_layer_cache(cfg, spec: LayerSpec, batch, max_len, dtype):
     raise ValueError(spec.mixer)
 
 
-def init_caches(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
-    """``dtype`` accepts a kv_dtype name ("bf16"/"fp32"/"int8"/"fp8") or a
-    jnp dtype; quantized dtypes add sibling *_scale cache leaves."""
+def init_caches(cfg: ModelConfig, batch: int, num_blocks: int,
+                block_size: int, dtype=jnp.bfloat16):
+    """The cache pytree every cached forward runs on (DESIGN.md §5):
+    attention leaves are block pools ``[NB, bs, ...]`` shared by the rows
+    through their block tables (block 0 the garbage block); SSM states stay
+    per row, ``[batch, ...]``. Scanned layers carry a leading repeats dim.
+    ``dtype`` accepts a kv_dtype name ("bf16"/"fp32"/"int8"/"fp8") or a
+    jnp dtype; quantized dtypes add sibling *_scale pool leaves."""
     dtype = attn.resolve_kv_dtype(dtype)
     plan = scan_plan(cfg)
-    caches = {
-        "prefix": [_init_layer_cache(cfg, s, batch, max_len, dtype)
-                   for s in plan.prefix],
+
+    def layer(s):
+        return _init_layer_cache(cfg, s, batch, num_blocks, block_size, dtype)
+
+    return {
+        "prefix": [layer(s) for s in plan.prefix],
         "scan": [jax.tree.map(
             lambda x: jnp.broadcast_to(x, (plan.n_repeats,) + x.shape).copy()
-            if hasattr(x, "shape") else x,
-            _init_layer_cache(cfg, s, batch, max_len, dtype))
+            if hasattr(x, "shape") else x, layer(s))
             for s in plan.period],
     }
-    return caches
 
 
 # ---------------------------------------------------------------------------
@@ -233,13 +250,12 @@ def forward(params, cfg: ModelConfig, tokens: Array, positions=None, *,
     tokens:       [B, T] int32
     positions:    [B, T] absolute positions (default arange)
     caches:       pytree from init_caches (None = no-cache training/prefill
-                  path) or, with ``block_tables``, from
-                  serving.kv_pool.init_paged_caches
+                  path): attention leaves are [NB, block, ...] pools, SSM
+                  states batch-indexed
     cache_pos:    [B] int32 — write offset into the caches
-    block_tables: [B, MBS] int32 — per-row block tables selecting the paged
-                  KV layout (attention leaves are [NB, block, ...] pools);
-                  SSM states stay batch-indexed either way
-    kv_block_size: tokens per KV block (static; required with block_tables)
+    block_tables: [B, MBS] int32 — per-row block tables into the pools
+                  (required with ``caches``)
+    kv_block_size: tokens per KV block (static; required with ``caches``)
     tree_info:    optional attention.TreeAttnInfo — the tokens are a packed
                   speculative candidate tree; pass explicit depth-based
                   ``positions`` alongside (DESIGN.md §6)
@@ -250,6 +266,8 @@ def forward(params, cfg: ModelConfig, tokens: Array, positions=None, *,
     """
     plan = scan_plan(cfg)
     b, t = tokens.shape
+    assert caches is None or (block_tables is not None and kv_block_size), \
+        "a cached forward needs block_tables and kv_block_size"
     if positions is None:
         base = cache_pos[:, None] if cache_pos is not None else 0
         positions = base + jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
@@ -283,13 +301,13 @@ def forward(params, cfg: ModelConfig, tokens: Array, positions=None, *,
     # ---- scanned periods ----
     if plan.n_repeats:
         period = plan.period
-        # Paged attention pools ride the carry, stacked over the layers:
-        # each layer scatters its new KV into the pool in place and the
-        # kernels read it at the layer index. As scan outputs they would be
-        # fresh buffers, zero-filled before the loop and copied whole into
-        # the returned caches after it. SSM states (small, batch-indexed),
-        # contiguous caches and the no-cache path stay inputs and outputs.
-        carried = tuple(block_tables is not None and caches is not None
+        # Attention pools ride the carry, stacked over the layers: each
+        # layer scatters its new KV into the pool in place and the kernels
+        # read it at the layer index. As scan outputs they would be fresh
+        # buffers, zero-filled before the loop and copied whole into the
+        # returned caches after it. SSM states (small, batch-indexed) and
+        # the no-cache path stay inputs and outputs.
+        carried = tuple(caches is not None
                         and spec.mixer in (ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA)
                         for spec in period)
         scan_in = (caches["scan"] if caches is not None

@@ -17,6 +17,7 @@ import dataclasses
 from typing import Any, Optional
 
 from ..core.spec_decode import TemplateBank, TreeTemplate
+from ..models import KV_BLOCK
 from ..models.attention import KV_DTYPES
 
 
@@ -78,8 +79,7 @@ class EngineConfig:
     temperature: float = 0.0
     eos_id: Optional[int] = None
     seed: int = 0
-    kv_layout: str = "paged"
-    kv_block_size: int = 64
+    kv_block_size: int = KV_BLOCK
     kv_num_blocks: Optional[int] = None
     kv_dtype: str = "bf16"
     tree: Any = None                 # branching iterable / TreeTemplate / TemplateBank
@@ -110,7 +110,6 @@ class EngineConfig:
 
     def __post_init__(self):
         assert self.mode in ("ar", "vsd", "pard")
-        assert self.kv_layout in ("paged", "contiguous")
         assert self.kv_dtype in KV_DTYPES, \
             f"kv_dtype must be one of {sorted(KV_DTYPES)}"
         assert self.tree is None or self.mode == "pard", \
@@ -121,8 +120,6 @@ class EngineConfig:
                 self.tree = TemplateBank.default(self.k)
             assert isinstance(self.tree, TemplateBank), \
                 "adaptive_tree selects from a TemplateBank"
-        assert not (self.prefix_cache and self.kv_layout != "paged"), \
-            "prefix_cache requires the paged KV layout"
         if self.tree is not None and not isinstance(self.tree, TemplateBank):
             # canonical form: branching iterable / TreeTemplate -> a
             # one-template bank (what SpecDecoder normalises to anyway)
@@ -167,10 +164,6 @@ class EngineConfig:
                     raise ValueError(
                         f"mesh 'data' axis has {self.mesh.shape['data']} "
                         f"devices but dp={self.dp}")
-
-    @property
-    def paged(self) -> bool:
-        return self.kv_layout == "paged"
 
     @classmethod
     def from_args(cls, ns) -> "EngineConfig":

@@ -8,8 +8,7 @@ The engine is three layers with one owner each:
     adaptive tree-template control, per-request latency accounting (queue
     wait, TTFT, per-token p50/p95);
   * ``serving.executor.Executor`` (device): the ONE ``DecodeState``
-    (core.spec_decode), the cache pools in either KV layout, and the fused
-    jitted step functions;
+    (core.spec_decode), the KV pools, and the fused jitted step functions;
   * ``Engine`` (this module): construction + the run loop, preserving the
     original public API (``submit`` / ``run`` / ``stats`` / KV accounting)
     so existing callers and tests keep working.
@@ -22,10 +21,10 @@ Design (all fixed shapes, jit-once):
     style chunked prefill) — no per-request ``[1, P_bucket]`` prefill
     forwards, no jit cache over prompt buckets, and admission never stalls
     live decode rows;
-  * KV layout is either "paged" (default; serving/kv_pool.py — fixed-size
+  * KV lives in block-paged pools (serving/kv_pool.py — fixed-size
     blocks, per-slot block tables, refcounted free-list allocation, O(1)
-    release) or "contiguous" (one full-length row per slot);
-  * ``prefix_cache=True`` (paged only) reuses prompt KV across requests:
+    release);
+  * ``prefix_cache=True`` reuses prompt KV across requests:
     full prompt blocks register in a content-keyed index, admission maps
     the longest computed block-aligned prefix copy-free into the new row's
     table (refcount + 1, target and draft keyed together) and only
@@ -44,12 +43,12 @@ Design (all fixed shapes, jit-once):
     controller (DESIGN.md §7);
   * sampling is per REQUEST (``submit(..., temperature=)``), each request
     drawing from its own (seed, rid) PRNG key — deterministic per request
-    across batch compositions and KV layouts.
+    across batch compositions.
 
 SSM/hybrid targets work unchanged: chunked prefill gathers the recurrent
 state after each chunk's last real token (DESIGN.md §3), admission zeroes
-the recycled slot's state, and SSM states stay batch-indexed in both KV
-layouts.
+the recycled slot's state, and SSM states stay batch-indexed beside the
+attention pools.
 """
 from __future__ import annotations
 
@@ -91,7 +90,6 @@ class Engine:
         self.config = config
         self.adaptive = config.adaptive_tree
         self.mode = mode = config.mode
-        self.paged = config.paged
         self.k = config.k if mode != "ar" else 1
         if mode == "ar":
             # the AR baseline never reads draft caches: drop the draft model
@@ -116,7 +114,7 @@ class Engine:
         decs = [SpecDecoder(
             target_params, target_cfg, draft_params, draft_cfg, k=self.k,
             max_len=max_len, temperature=config.temperature,
-            kv_block_size=config.kv_block_size if self.paged else 0,
+            kv_block_size=config.kv_block_size,
             tree=config.tree if mode == "pard" else None,
             prefill_chunk=config.prefill_chunk, kv_dtype=config.kv_dtype,
             mesh=m, tp_ruleset=config.tp_ruleset) for m in meshes]
@@ -125,25 +123,18 @@ class Engine:
         self.bank = self.dec.tree    # TemplateBank (or None: no tree)
         self.tc, self.dc = target_cfg, draft_cfg
 
-        if self.paged:
-            # kv_num_blocks is PER REPLICA: each replica owns a full pool
-            nb = config.kv_num_blocks or kv_pool.default_num_blocks(
-                max_batch, max_len, config.kv_block_size)
-            # the shared cross-replica prefix index admission routes over;
-            # pointless (and absent) with a single replica
-            self.prefix_index = kv_pool.PrefixIndex() if dp > 1 else None
-            allocs = [kv_pool.BlockAllocator(
-                nb, config.kv_block_size, max_batch, max_len, replica=r,
-                prefix_index=self.prefix_index) for r in range(dp)]
-        else:
-            nb = None
-            allocs = [None] * dp
-            self.prefix_index = None
+        # kv_num_blocks is PER REPLICA: each replica owns a full pool
+        nb = config.kv_num_blocks or kv_pool.default_num_blocks(
+            max_batch, max_len, config.kv_block_size)
+        # the shared cross-replica prefix index admission routes over;
+        # pointless (and absent) with a single replica
+        self.prefix_index = kv_pool.PrefixIndex() if dp > 1 else None
+        allocs = [kv_pool.BlockAllocator(
+            nb, config.kv_block_size, max_batch, max_len, replica=r,
+            prefix_index=self.prefix_index) for r in range(dp)]
         self.alloc = allocs[0]
         exs = [Executor(decs[r], target_cfg, draft_cfg, mode, max_batch,
-                        max_len, self.paged, config.kv_block_size, nb,
-                        config.seed, kv_dtype=config.kv_dtype,
-                        mesh=meshes[r], replica=r,
+                        max_len, nb, config.seed, mesh=meshes[r], replica=r,
                         tp_ruleset=config.tp_ruleset) for r in range(dp)]
         self.ex = exs[0]
         ctrl = (TreeController(self.bank, max_batch * dp, config.tree_ewma)
@@ -159,10 +150,7 @@ class Engine:
             tree_reselect_every=config.tree_reselect_every,
             prefix_index=self.prefix_index)
         self.ctrl = ctrl
-        # contiguous rows are committed whole-pool up front, so their peak
-        # IS the capacity — consumers read this field for either layout
-        self.peak_kv_bytes_in_use = (0 if self.paged
-                                     else self.kv_capacity_bytes())
+        self.peak_kv_bytes_in_use = 0
 
     # ------------------------------------------------------------- public
     def submit(self, prompt, max_new: Optional[int] = None,
@@ -174,8 +162,8 @@ class Engine:
         The loose keywords still work (``temperature`` overrides the engine
         default for this request only, 0 = greedy; ``tree_idx`` pins one
         bank template) and fold into the same SamplingParams. Validation
-        happens here, with the request's OWN window slack in the paged
-        layout — see Scheduler.submit."""
+        happens here, with the request's OWN window slack — see
+        Scheduler.submit."""
         return self.sched.submit(prompt, max_new, temperature, tree_idx,
                                  params=params)
 
@@ -223,10 +211,8 @@ class Engine:
                 with spans.phase(sched.stats, "sync_tables"):
                     for rep in sched.replicas:
                         rep.ex.sync_tables(rep.alloc)
-                    if self.paged:
-                        self.peak_kv_bytes_in_use = max(
-                            self.peak_kv_bytes_in_use,
-                            self.kv_bytes_in_use())
+                    self.peak_kv_bytes_in_use = max(
+                        self.peak_kv_bytes_in_use, self.kv_bytes_in_use())
                 for rep in sched.replicas:
                     if rep.has_live():
                         inflight[rep.rep].append(sched.dispatch(rep.rep))
@@ -260,12 +246,9 @@ class Engine:
         return sum(rep.ex.kv_capacity for rep in self.sched.replicas)
 
     def kv_bytes_in_use(self) -> int:
-        """KV bytes backing live requests, summed over all replicas.
-        Contiguous rows are committed whole-pool up front; paged usage
-        counts each UNIQUE mapped block once (prefix-shared blocks are
-        the point of sharing)."""
-        if not self.paged:
-            return self.kv_capacity_bytes()
+        """KV bytes backing live requests, summed over all replicas: each
+        UNIQUE mapped block counts once (prefix-shared blocks are the point
+        of sharing)."""
         return sum(rep.alloc.blocks_in_use * rep.ex.kv_per_block
                    for rep in self.sched.replicas)
 

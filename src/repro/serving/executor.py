@@ -1,10 +1,10 @@
 """Device-side half of the serving stack (DESIGN.md §8).
 
-The ``Executor`` owns everything that lives on the accelerator: the cache
-pools (paged or contiguous), the single ``DecodeState`` pytree, and the
-jitted step functions — built from the SAME ``SpecDecoder`` step builders
-the uniform-batch ``generate_*`` paths use, but with ``chunked=True`` so
-every step advances decoding rows AND consumes prompt chunks for
+The ``Executor`` owns everything that lives on the accelerator: the KV
+pools, the single ``DecodeState`` pytree (built by the same
+``SpecDecoder.init_state`` the uniform-batch ``generate_*`` paths use),
+and the jitted step functions — the SAME ``SpecDecoder`` step builders,
+in which every step advances decoding rows AND consumes prompt chunks for
 prefilling rows in one fused forward (no standalone prefill forwards, no
 admission stall).
 
@@ -39,10 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import acceptance
-from ..core.spec_decode import DecodeState, SpecDecoder
-from ..models import init_caches
-from ..models.attention import resolve_kv_dtype
+from ..core.spec_decode import SpecDecoder
 from ..models.config import SSM, ModelConfig, scan_plan
 from . import kv_pool, spans
 
@@ -142,16 +139,13 @@ class Executor:
 
     def __init__(self, dec: SpecDecoder, target_cfg: ModelConfig,
                  draft_cfg: Optional[ModelConfig], mode: str, max_batch: int,
-                 max_len: int, paged: bool, kv_block_size: int,
-                 num_blocks: Optional[int], seed: int,
-                 kv_dtype: str = "bf16", mesh=None, replica: int = 0,
-                 tp_ruleset: str = "exact"):
+                 max_len: int, num_blocks: int, seed: int, mesh=None,
+                 replica: int = 0, tp_ruleset: str = "exact"):
         self.dec = dec
         self.mode = mode
         self.tc, self.dc = target_cfg, draft_cfg
         self.max_batch, self.max_len = max_batch, max_len
-        self.paged = paged
-        self.kv_dtype = kv_dtype
+        self.kv_dtype = dec.kv_dtype         # the pools' storage dtype
         # which serving ruleset the fused steps trace under ("exact" /
         # "throughput" — DESIGN.md §13); salts the jit keys because the two
         # rulesets bake different sharding constraints into the same step
@@ -175,48 +169,20 @@ class Executor:
         # stays non-blocking
         self._n_draft = 0 if mode == "ar" else (dec.k if mode == "vsd" else 1)
 
-        cache_dtype = resolve_kv_dtype(kv_dtype)
-        if paged:
-            tcache = kv_pool.init_paged_caches(target_cfg, max_batch,
-                                               num_blocks, kv_block_size,
-                                               dtype=cache_dtype, mesh=mesh)
-            dcache = (kv_pool.init_paged_caches(draft_cfg, max_batch,
-                                                num_blocks, kv_block_size,
-                                                dtype=cache_dtype)
-                      if draft_cfg is not None else None)
-            tables = jnp.zeros((max_batch, kv_pool.blocks_for(
-                max_len, kv_block_size)), jnp.int32)
-            self.kv_per_block = (
-                kv_pool.kv_bytes_per_block(target_cfg, tcache, num_blocks)
-                + (kv_pool.kv_bytes_per_block(draft_cfg, dcache, num_blocks)
-                   if dcache is not None else 0))
-        else:
-            tcache = init_caches(target_cfg, max_batch, max_len,
-                                 dtype=cache_dtype)
-            dcache = (init_caches(draft_cfg, max_batch, max_len,
-                                  dtype=cache_dtype)
-                      if draft_cfg is not None else None)
-            tables = None
-            self.kv_per_block = 0
+        # empty slots are frozen rows; the step's pools, tables and
+        # counters all come from the one DecodeState constructor
+        self.state = dec.init_state(max_batch, max_len, num_blocks,
+                                    seed=seed,
+                                    with_draft=draft_cfg is not None)
+        tcache, dcache = self.state.tcache, self.state.dcache
+        self.kv_per_block = (
+            kv_pool.kv_bytes_per_block(target_cfg, tcache, num_blocks)
+            + (kv_pool.kv_bytes_per_block(draft_cfg, dcache, num_blocks)
+               if dcache is not None else 0))
         self.kv_capacity = (
             kv_pool.kv_capacity_bytes(target_cfg, tcache)
             + (kv_pool.kv_capacity_bytes(draft_cfg, dcache)
                if dcache is not None else 0))
-
-        self.state = DecodeState(
-            gen=jnp.zeros((max_batch, max_len), jnp.int32),
-            n=jnp.ones((max_batch,), jnp.int32) * 2,   # dummy-safe
-            m=jnp.ones((max_batch,), jnp.int32),
-            done=jnp.ones((max_batch,), bool),         # empty slots = done
-            tcache=tcache, dcache=dcache, tables=tables,
-            temp=jnp.zeros((max_batch,), jnp.float32),
-            rngs=acceptance.make_row_keys(seed, np.arange(max_batch)),
-            tree_idx=(jnp.zeros((max_batch,), jnp.int32)
-                      if dec.tree is not None else None),
-            pf_pos=jnp.zeros((max_batch,), jnp.int32),
-            pf_len=jnp.zeros((max_batch,), jnp.int32),
-            moe_rows=(jnp.zeros((), jnp.int32) if dec.counts_moe_rows
-                      else None))
         if mesh is not None:
             self._state_sh = self._state_shardings()
             self.state = jax.device_put(self.state, self._state_sh)
@@ -226,7 +192,7 @@ class Executor:
     # ----------------------------------------------------------- sharding
     def _state_shardings(self):
         """NamedSharding pytree matching the DecodeState: target KV pools
-        shard KV heads over "model" (paged_cache_specs / cache_specs), the
+        shard KV heads over "model" (paged_cache_specs), the
         DRAFT pools and every other leaf — tokens, counters, PRNG keys,
         block tables — replicate. The draft replicates because it is small
         and its latency-critical window must not pay any cross-device
@@ -235,21 +201,17 @@ class Executor:
         from ..sharding import specs as _specs
         mesh = self.mesh
         repl = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
-        if self.paged:
-            t_specs = _specs.paged_cache_specs(self.state.tcache, mesh)
-        else:
-            t_specs = _specs.cache_specs(self.state.tcache, self.tc, mesh,
-                                         self.max_batch)
+        t_specs = _specs.paged_cache_specs(self.state.tcache, mesh)
         base = jax.tree.map(lambda _: repl, self.state)
         return dataclasses.replace(base,
                                    tcache=_specs.to_named(t_specs, mesh))
 
     # ------------------------------------------------------------- tables
-    def sync_tables(self, alloc: Optional[kv_pool.BlockAllocator]) -> None:
+    def sync_tables(self, alloc: kv_pool.BlockAllocator) -> None:
         """Push the host block tables to the device state when stale. Runs
         before any forward that could consume them, so released rows' stale
         writes always route to the garbage block (kv_pool I4)."""
-        if alloc is not None and self._tables_version != alloc.version:
+        if self._tables_version != alloc.version:
             tables = jnp.asarray(alloc.tables)
             if self.mesh is not None:
                 # every device resolves the same block indirection: the
@@ -330,10 +292,9 @@ class Executor:
         # the chunk substitution is a few jnp.where selects), so one
         # compiled step serves both pure-decode and mixed ticks
         if self.dec.tree is not None:
-            return self.dec._build_tree_step(chunked=True,
-                                             greedy_only=greedy_only)
+            return self.dec._build_tree_step(greedy_only=greedy_only)
         return self.dec._build_spec_step(
-            "pard" if self.mode == "pard" else "vsd", chunked=True,
+            "pard" if self.mode == "pard" else "vsd",
             greedy_only=greedy_only)
 
     def _build_fused(self, variant: str, apply_tree: bool,

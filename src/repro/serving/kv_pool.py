@@ -1,8 +1,7 @@
-"""Block-paged KV-cache pool — the vLLM-style layout for the serving engine.
+"""Block-paged KV-cache pool — the one KV layout (DESIGN.md §5), vLLM-style.
 
-The contiguous layout allocates ``max_batch x max_len`` cache rows up front,
-so HBM footprint is decoupled from what requests actually use. This module
-decouples them (DESIGN.md §5):
+HBM footprint follows what requests actually use, not ``max_batch x
+max_len``:
 
   * attention KV lives in a shared pool of fixed-size blocks
     ``[num_blocks, block_size, ...]`` (per layer; scanned layers carry a
@@ -45,25 +44,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import ssm as ssm_mod
-from ..models.attention import (kv_dtype_is_quantized, mla_pool_width,
-                                resolve_kv_dtype)
-from ..models.config import (ATTN_CROSS, ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA,
-                             SSM, ModelConfig, scan_plan)
+from ..models import blocks_for, init_caches
+from ..models.config import (ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA, ModelConfig,
+                             scan_plan)
 
 ATTN_MIXERS = (ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA)
-
-
-def blocks_for(n_tokens: int, block_size: int) -> int:
-    """Blocks covering ``n_tokens`` positions (ceiling division)."""
-    return -(-int(n_tokens) // block_size)
 
 
 def default_num_blocks(max_batch: int, max_len: int, block_size: int) -> int:
     """Worst-case pool size (every slot filled to max_len) + garbage block.
 
     Serving deployments pass something smaller and rely on admission
-    backpressure; this default keeps the paged engine drop-in safe.
+    backpressure; this default never refuses an admission max_len allows.
     """
     return max_batch * blocks_for(max_len, block_size) + 1
 
@@ -72,124 +64,21 @@ def default_num_blocks(max_batch: int, max_len: int, block_size: int) -> int:
 # Pool init
 # ---------------------------------------------------------------------------
 
-def _paged_layer_cache(cfg: ModelConfig, spec, num_blocks, block_size, batch,
-                       dtype):
-    quant = kv_dtype_is_quantized(dtype)
-    if spec.mixer in (ATTN_GLOBAL, ATTN_LOCAL):
-        hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-        c = {"k": jnp.zeros((num_blocks, block_size, hkv, hd), dtype),
-             "v": jnp.zeros((num_blocks, block_size, hkv, hd), dtype)}
-        if quant:
-            # per-(slot, head) dequant scales ride alongside the pool and
-            # through the same block-table indirection (DESIGN.md §10);
-            # scale 1 keeps the garbage block dequantizing to exact zeros
-            c["k_scale"] = jnp.ones((num_blocks, block_size, hkv),
-                                    jnp.float32)
-            c["v_scale"] = jnp.ones((num_blocks, block_size, hkv),
-                                    jnp.float32)
-        return c
-    if spec.mixer == ATTN_MLA:
-        c = {"ckv": jnp.zeros((num_blocks, block_size, mla_pool_width(cfg)),
-                              dtype)}
-        if quant:
-            # one scale per compressed-KV vector
-            c["ckv_scale"] = jnp.ones((num_blocks, block_size), jnp.float32)
-        return c
-    if spec.mixer == SSM:
-        return ssm_mod.init_mamba2_state(cfg, batch, jnp.float32)
-    if spec.mixer == ATTN_CROSS:
-        return {}
-    raise ValueError(spec.mixer)
-
-
 def init_paged_caches(cfg: ModelConfig, batch: int, num_blocks: int,
                       block_size: int, dtype=jnp.bfloat16, mesh=None):
-    """Cache pytree with the SAME structure as models.init_caches, but
-    attention leaves are shared block pools [NB, bs, ...] (no batch dim);
-    SSM states remain [batch, ...]. ``dtype`` accepts a kv_dtype name
-    ("bf16"/"fp32"/"int8"/"fp8") or a jnp dtype; quantized dtypes add
-    sibling *_scale pool leaves. ``mesh`` shards the pools for tensor-
-    parallel serving per sharding.specs.paged_cache_specs (KV heads over
-    the "model" axis, quant scales alongside, everything else replicated —
-    DESIGN.md §11)."""
-    dtype = resolve_kv_dtype(dtype)
-    plan = scan_plan(cfg)
-    pool = {
-        "prefix": [_paged_layer_cache(cfg, s, num_blocks, block_size, batch,
-                                      dtype)
-                   for s in plan.prefix],
-        "scan": [jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (plan.n_repeats,) + x.shape).copy()
-            if hasattr(x, "shape") else x,
-            _paged_layer_cache(cfg, s, num_blocks, block_size, batch, dtype))
-            for s in plan.period],
-    }
+    """``models.init_caches``: attention leaves are shared block pools
+    [NB, bs, ...] (no batch dim); SSM states are [batch, ...]. ``dtype``
+    accepts a kv_dtype name ("bf16"/"fp32"/"int8"/"fp8") or a jnp dtype;
+    quantized dtypes add sibling *_scale pool leaves. ``mesh`` shards the
+    pools for tensor-parallel serving per sharding.specs.paged_cache_specs
+    (KV heads over the "model" axis, quant scales alongside, everything
+    else replicated — DESIGN.md §11)."""
+    pool = init_caches(cfg, batch, num_blocks, block_size, dtype=dtype)
     if mesh is not None:
         from ..sharding import specs as _specs
         pool = jax.device_put(
             pool, _specs.to_named(_specs.paged_cache_specs(pool, mesh), mesh))
     return pool
-
-
-def prefill_cache_view(cfg: ModelConfig, pool, paged: bool):
-    """The cache tree a single-request prefill forward should run against.
-
-    Paged: attention leaves ARE the pool (the forward writes through the
-    slot's block-table row — copy-free admission), SSM leaves a fresh
-    one-row state. Contiguous: handled by the caller (init_caches(cfg, 1)).
-    """
-    assert paged
-    plan = scan_plan(cfg)
-
-    def one(spec, entry, scanned):
-        if spec.mixer != SSM:
-            return entry
-        row = ssm_mod.init_mamba2_state(cfg, 1, jnp.float32)
-        if scanned:
-            row = jax.tree.map(
-                lambda x: jnp.broadcast_to(
-                    x, (plan.n_repeats,) + x.shape).copy(), row)
-        return row
-
-    return {
-        "prefix": [one(s, pool["prefix"][i], False)
-                   for i, s in enumerate(plan.prefix)],
-        "scan": [one(s, pool["scan"][j], True)
-                 for j, s in enumerate(plan.period)],
-    }
-
-
-def scatter_row_caches(cfg: ModelConfig, pool, row, slot, paged: bool):
-    """Merge a prefill result into the engine's cache pools at ``slot``.
-
-    Paged: attention entries in ``row`` are the already-updated pools
-    (adopted as-is); only the O(1) SSM states are scattered. Contiguous:
-    every leaf is a [1, ...] row scattered at batch index ``slot`` (prefix
-    leaves carry batch at axis 0, scanned leaves at axis 1).
-    ``slot`` may be traced (dynamic_update_slice start).
-    """
-    plan = scan_plan(cfg)
-    slot = jnp.asarray(slot, jnp.int32)
-
-    def ins_axis(axis):
-        def ins(p, r):
-            idx = [jnp.zeros((), jnp.int32)] * p.ndim
-            idx[axis] = slot
-            return jax.lax.dynamic_update_slice(p, r.astype(p.dtype),
-                                                tuple(idx))
-        return ins
-
-    def merge(spec, pool_e, row_e, axis):
-        if paged and spec.mixer in ATTN_MIXERS:
-            return row_e                       # row IS the updated pool
-        return jax.tree.map(ins_axis(axis), pool_e, row_e)
-
-    return {
-        "prefix": [merge(s, pool["prefix"][i], row["prefix"][i], 0)
-                   for i, s in enumerate(plan.prefix)],
-        "scan": [merge(s, pool["scan"][j], row["scan"][j], 1)
-                 for j, s in enumerate(plan.period)],
-    }
 
 
 # ---------------------------------------------------------------------------
@@ -209,7 +98,7 @@ def _attn_leaves(cfg: ModelConfig, tree):
 
 
 def kv_capacity_bytes(cfg: ModelConfig, tree) -> int:
-    """HBM resident for the attention KV leaves (either layout)."""
+    """HBM resident for the attention KV pools."""
     return int(sum(leaf.nbytes for leaf in _attn_leaves(cfg, tree)))
 
 

@@ -331,7 +331,6 @@ class Scheduler:
         self.prefix_index = prefix_index
         self.dec = decs[0]    # shape config: templates / slack / chunking
         self.mode = mode
-        self.paged = allocs[0] is not None
         self.max_batch, self.max_len = max_batch, max_len
         self.temperature = temperature
         self.eos_id = eos_id
@@ -384,8 +383,8 @@ class Scheduler:
         return self.replicas[0].ex
 
     @property
-    def alloc(self) -> Optional[kv_pool.BlockAllocator]:
-        """Replica 0's block allocator (None in the contiguous layout)."""
+    def alloc(self) -> kv_pool.BlockAllocator:
+        """Replica 0's block allocator."""
         return self.replicas[0].alloc
 
     @property
@@ -413,11 +412,7 @@ class Scheduler:
             raise ValueError(
                 f"tree_idx={tree_idx} needs a TemplateBank with more "
                 f"than {tree_idx} templates")
-        if not self.paged or self.bank is None:
-            # contiguous rows are written batch-wide (the widest window,
-            # clamped dynamic_update_slice would corrupt committed KV past
-            # max_len), so the bank-wide slack is the real requirement
-            # whatever template the request pins
+        if self.bank is None:
             slack = self.dec.window_slack
         elif tree_idx is not None:
             slack = self.dec.row_slack(tree_idx)
@@ -460,8 +455,7 @@ class Scheduler:
     # ---------------------------------------------------------- admission
     def _feasible_templates(self, req: Request) -> List[int]:
         """Bank templates whose window slack fits ``req`` inside max_len.
-        Never empty: submit() validated the smallest slack (paged) or the
-        bank-wide one (contiguous, where every template fits by then)."""
+        Never empty: submit() validated the smallest slack."""
         budget = self.max_len - len(req.prompt) - req.max_new
         return [t for t in range(len(self.bank))
                 if self.dec.row_slack(t) <= budget]
@@ -478,8 +472,8 @@ class Scheduler:
 
     def _try_admit(self, rep: _Replica, slot: int, req: Request) -> bool:
         """Admit ``req`` into replica ``rep``'s ``slot`` if its resources
-        exist right now: KV blocks (paged; after prefix matching against
-        THIS replica's pool) and a prefill lane on this replica.
+        exist right now: KV blocks (after prefix matching against THIS
+        replica's pool) and a prefill lane on this replica.
         Returns False without side effects when they don't."""
         p = len(req.prompt)
         tmpl = self._pick_template(req)
@@ -489,50 +483,45 @@ class Scheduler:
 
         keys: List[bytes] = []
         hit: List[int] = []
-        if self.paged:
-            if self.prefix_cache:
-                keys = kv_pool.prefix_block_keys(
-                    req.prompt, rep.alloc.block_size,
-                    kv_dtype=rep.ex.kv_dtype)
-                hit = rep.alloc.match_prefix(keys)
+        if self.prefix_cache:
+            keys = kv_pool.prefix_block_keys(
+                req.prompt, rep.alloc.block_size, kv_dtype=rep.ex.kv_dtype)
+            hit = rep.alloc.match_prefix(keys)
+        nb = rep.alloc.blocks_needed(need)
+        if not rep.alloc.can_allocate(nb - len(hit), hit) \
+                and self.bank is not None and req.tree_idx is None:
+            # the controller's pick outgrows the pool: serve the request on
+            # the narrowest feasible template instead of head-of-line
+            # blocking (reshaping can widen it later as completions free
+            # blocks); pinned requests keep their shape and wait
+            tmpl = min(self._feasible_templates(req), key=self.dec.row_slack)
+            need = p + req.max_new + self.dec.row_slack(tmpl)
             nb = rep.alloc.blocks_needed(need)
-            if not rep.alloc.can_allocate(nb - len(hit), hit) \
-                    and self.bank is not None and req.tree_idx is None:
-                # the controller's pick outgrows the pool: serve the
-                # request on the narrowest feasible template instead of
-                # head-of-line blocking (reshaping can widen it later as
-                # completions free blocks); pinned requests keep their
-                # shape and wait
-                tmpl = min(self._feasible_templates(req),
-                           key=self.dec.row_slack)
-                need = p + req.max_new + self.dec.row_slack(tmpl)
-                nb = rep.alloc.blocks_needed(need)
-            if not rep.alloc.can_allocate(nb - len(hit), hit):
-                return False                       # memory backpressure
-        pf_start = len(hit) * (rep.alloc.block_size if self.paged else 0)
+        if not rep.alloc.can_allocate(nb - len(hit), hit):
+            return False                           # memory backpressure
+        pf_start = len(hit) * rep.alloc.block_size
         if pf_start < p - 1 and self.prefill_lanes is not None \
                 and rep.prefilling_count() >= self.prefill_lanes:
             return False                           # prefill budget exhausted
 
         now = time.perf_counter()
-        if self.paged:
-            if self.prefix_cache:
-                rep.alloc.allocate(slot, need, prefix=hit, keys=keys)
-            else:
-                # plain positional call — tests spy on allocate(slot, n)
-                rep.alloc.allocate(slot, need)
-            self.stats["prefix_lookup_blocks"] += len(keys)
-            self.stats["prefix_hit_blocks"] += len(hit)
-            # defensive COW (kv_pool I2): with block-aligned matching the
-            # first writable position always lands past the shared prefix,
-            # but if a future matching policy maps the boundary block this
-            # is what keeps shared KV immutable
-            first_write_block = min(pf_start, p - 1) // rep.alloc.block_size
-            for i in sorted(rep.alloc.read_only.get(slot, ())):
-                if i >= first_write_block:
-                    pair = rep.alloc.copy_on_write(slot, i)
-                    if pair is not None:
-                        rep.ex.copy_block(*pair)
+        if self.prefix_cache:
+            rep.alloc.allocate(slot, need, prefix=hit, keys=keys)
+        else:
+            # plain positional call — tests spy on allocate(slot, n)
+            rep.alloc.allocate(slot, need)
+        self.stats["prefix_lookup_blocks"] += len(keys)
+        self.stats["prefix_hit_blocks"] += len(hit)
+        # defensive COW (kv_pool I2): with block-aligned matching the
+        # first writable position always lands past the shared prefix,
+        # but if a future matching policy maps the boundary block this
+        # is what keeps shared KV immutable
+        first_write_block = min(pf_start, p - 1) // rep.alloc.block_size
+        for i in sorted(rep.alloc.read_only.get(slot, ())):
+            if i >= first_write_block:
+                pair = rep.alloc.copy_on_write(slot, i)
+                if pair is not None:
+                    rep.ex.copy_block(*pair)
         t = self.temperature if req.temperature is None else req.temperature
         rep.ex.admit_row(slot, req.prompt, float(t), req.rid, int(tmpl),
                          pf_start, seed=req.seed)
@@ -569,7 +558,7 @@ class Scheduler:
         request falls through to the next candidate instead of stalling.
         Returns ``(replica, hit_blocks)`` pairs."""
         reps = sorted(self.replicas, key=lambda r: (r.occupancy(), r.rep))
-        if self.dp == 1 or not (self.paged and self.prefix_cache):
+        if self.dp == 1 or not self.prefix_cache:
             return [(r, 0) for r in reps]
         keys = kv_pool.prefix_block_keys(
             req.prompt, self.replicas[0].alloc.block_size,
@@ -668,8 +657,7 @@ class Scheduler:
             self.stats["replica_steps"][replica] += 1
             self.stats["target_forwards"] += 1
             self.stats["draft_forwards"] += handle.n_draft
-            if self.paged:
-                self._count_kv_pages(rep, occ, any_prefilling)
+            self._count_kv_pages(rep, occ, any_prefilling)
             # advance the host prefill mirrors in lockstep with the device
             for slot in np.nonzero(occ)[0]:
                 pf, pfl = rep.slot_pf[slot], rep.slot_pf_len[slot]
@@ -678,7 +666,7 @@ class Scheduler:
                     rep.slot_pf[slot] = pf + cl
                     self.stats["prefill_chunks"] += 1
                     self.stats["prefill_tokens"] += cl
-                    if self.paged and self.prefix_cache:
+                    if self.prefix_cache:
                         # the blocks become readable once THIS step
                         # completes on device — before any later-dispatched
                         # step could read them through a prefix match
@@ -787,8 +775,8 @@ class Scheduler:
         """Between-windows template re-selection (the adaptive controller).
         Every ``tree_reselect_every`` live steps a slot re-scores the bank
         under its own EWMA statistics and switches when a different
-        template wins AND the slot can hold it: within max_len, and — paged
-        — growable in place (``BlockAllocator.grow``; when the pool is too
+        template wins AND the slot can hold it: within max_len, and
+        growable in place (``BlockAllocator.grow``; when the pool is too
         tight the slot just keeps its current shape). Greedy losslessness
         is shape-independent, so reshaping mid-request never changes
         committed tokens' correctness, only how many arrive per step."""
@@ -803,7 +791,7 @@ class Scheduler:
             if best == int(rep.slot_tree[slot]):
                 continue
             need = len(req.prompt) + req.max_new + self.dec.row_slack(best)
-            if self.paged and not rep.alloc.grow(int(slot), need):
+            if not rep.alloc.grow(int(slot), need):
                 continue            # pool too tight: keep the old shape
             # STAGED: the mirror update is picked up by the next dispatch's
             # tree_sel (no eager device scatter); growing the block table
@@ -881,8 +869,7 @@ class Scheduler:
                 rep.pending_retire[slot] = True
                 if self.ctrl is not None:
                     self.ctrl.retire_slot(rep.base + slot)
-                if self.paged:
-                    rep.alloc.release(slot)  # O(1); blocks reusable at once
+                rep.alloc.release(slot)      # O(1); blocks reusable at once
 
     # ------------------------------------------------------------ summary
     def mean_accepted(self) -> float:

@@ -290,65 +290,9 @@ def data_spec(mesh: Mesh, batch: int, ndim: int) -> P:
     return P(*([None] * ndim))
 
 
-def cache_specs(caches, cfg, mesh: Mesh, batch: int,
-                seq_model_shard: bool = False):
-    """KV cache / SSM state sharding for serving.
-
-    Batch shards over (pod, data) when divisible; otherwise (batch=1
-    long-context) the cache *sequence* dim shards over the data axes and the
-    attention computes a distributed softmax (GSPMD inserts the combine).
-    KV heads / MLA latent / SSM heads shard over model when divisible.
-    """
-    mesh_shape = dict(zip(mesh.axis_names, mesh.devices.shape))
-    axes = _batch_axes(mesh)
-    dsize = int(np.prod([mesh_shape[a] for a in axes]))
-    batch_ok = batch % dsize == 0
-    lead = (axes if len(axes) > 1 else axes[0]) if batch_ok else None
-    seq_ax = None if batch_ok else (axes if len(axes) > 1 else axes[0])
-    if seq_model_shard:
-        # §Perf variant: KV sequence over the model axis (batch keeps its
-        # data sharding); kv-head replication is replaced by a distributed
-        # softmax over sequence shards
-        seq_ax = ("model",) if batch_ok else tuple(axes) + ("model",)
-
-    def leaf_spec(path, leaf):
-        shape = leaf.shape
-        nd = len(shape)
-        name = path.rsplit("/", 1)[-1]
-        scanned = "/scan/" in path or path.startswith("/scan")
-        off = 1 if scanned else 0        # leading repeats dim -> None
-        spec = [None] * nd
-        if name in ("k", "v"):           # [.., B, S, Hkv, hd]
-            spec[off] = lead
-            spec[off + 1] = seq_ax
-            if shape[off + 2] % mesh_shape.get("model", 1) == 0:
-                spec[off + 2] = "model"
-        elif name in ("k_scale", "v_scale"):   # [.., B, S, Hkv] (quant)
-            spec[off] = lead
-            spec[off + 1] = seq_ax
-            if shape[off + 2] % mesh_shape.get("model", 1) == 0:
-                spec[off + 2] = "model"
-        elif name == "ckv":              # [.., B, S, width]
-            spec[off] = lead
-            spec[off + 1] = seq_ax
-        elif name == "ckv_scale":        # [.., B, S] (quant MLA)
-            spec[off] = lead
-            spec[off + 1] = seq_ax
-        elif name == "conv":             # [.., B, W-1, C]
-            spec[off] = lead
-            if shape[off + 2] % mesh_shape.get("model", 1) == 0:
-                spec[off + 2] = "model"
-        elif name == "ssm":              # [.., B, H, P, N]
-            spec[off] = lead
-            if shape[off + 1] % mesh_shape.get("model", 1) == 0:
-                spec[off + 1] = "model"
-        return P(*spec)
-
-    return _map_with_path(caches, leaf_spec)
-
-
 def paged_cache_specs(caches, mesh: Mesh):
-    """PartitionSpec tree for the PAGED pool layout (serving/kv_pool.py).
+    """PartitionSpec tree for the KV pools (models.init_caches,
+    serving/kv_pool.py).
 
     Attention leaves are shared block pools with no batch dim —
     ``[.., NB, bs, Hkv, hd]`` (scanned layers carry a leading repeats dim)

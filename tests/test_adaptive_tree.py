@@ -1,7 +1,7 @@
 """Per-request tree templates and acceptance-driven reshaping (DESIGN.md
 §7): TemplateBank construction, mixed-template batches staying lossless
-(greedy rows token-identical to AR, contiguous == paged), per-request paged
-allocation sizing (no over/under-allocation when a wide and a chain request
+(greedy rows token-identical to AR, sampled rows to generate_spec on the
+same row key), per-request paged allocation sizing (no over/under-allocation when a wide and a chain request
 share one batch), the submit() feasibility error path, allocator growth,
 the EWMA controller's selection policy, and per-row win_len parity of the
 tree-attention kernels against their oracles."""
@@ -16,6 +16,8 @@ from repro.kernels import ops, ref
 from repro.models import init_params
 from repro.serving.engine import Engine, TreeController
 from repro.serving.kv_pool import BlockAllocator
+
+from decode_refs import greedy_reference, sampled_reference
 
 
 @pytest.fixture(scope="module")
@@ -114,7 +116,7 @@ def test_engine_mixed_templates_match_ar(tiny):
         dec = SpecDecoder(tp, tc, tp, tc, k=4, max_len=256)
         refs[i] = np.asarray(dec.generate_ar(jnp.asarray(p)[None], 12)[0][0])
     eng = Engine(tp, tc, tp, tc, mode="pard", max_batch=2, max_len=256,
-                 kv_layout="paged", kv_block_size=32,
+                 kv_block_size=32,
                  tree=TemplateBank.from_templates(BANK))
     rids = {eng.submit(p, 12, tree_idx=i % 3): i
             for i, p in enumerate(prompts)}
@@ -126,40 +128,49 @@ def test_engine_mixed_templates_match_ar(tiny):
 
 
 def test_engine_mixed_templates_layouts_agree(tiny):
-    """Mixed per-request templates must commit identical tokens under the
-    contiguous and the block-paged KV layout."""
+    """Mixed per-request templates with a separate draft commit each
+    request's greedy AR tokens through the KV pool."""
     tc, tp, dc, dp = tiny
     prompts = _ragged_prompts(4, seed=22)
-    results = {}
-    for layout in ("contiguous", "paged"):
-        eng = Engine(tp, tc, dp, dc, mode="pard", max_batch=2, max_len=256,
-                     kv_layout=layout, kv_block_size=32,
-                     tree=TemplateBank.from_templates(BANK))
-        rids = {eng.submit(p, 12, tree_idx=(i * 2) % 3): i
-                for i, p in enumerate(prompts)}
-        results[layout] = {rids[c.rid]: c.tokens for c in eng.run()}
-    for i in range(len(prompts)):
-        assert np.array_equal(results["contiguous"][i], results["paged"][i])
+    eng = Engine(tp, tc, dp, dc, mode="pard", max_batch=2, max_len=256,
+                 kv_block_size=32, tree=TemplateBank.from_templates(BANK))
+    rids = {eng.submit(p, 12, tree_idx=(i * 2) % 3): i
+            for i, p in enumerate(prompts)}
+    comps = eng.run()
+    assert len(comps) == len(prompts)
+    dec = SpecDecoder(tp, tc, dp, dc, k=4, max_len=256)
+    for c in comps:
+        assert np.array_equal(
+            greedy_reference(dec, prompts[rids[c.rid]], 12), c.tokens)
 
 
 def test_engine_mixed_templates_sampled_layouts_agree(tiny):
-    """Per-request templates + per-request temperature: sampled rows keep
-    seeded determinism across KV layouts with mixed tree shapes."""
+    """Per-request templates + per-request temperature: greedy rows commit
+    their AR tokens, and sampled rows the tokens of ``generate_spec`` on
+    the same (seed, rid) key and template — seeded determinism with mixed
+    tree shapes in one batch."""
     tc, tp, dc, dp = tiny
     prompts = _ragged_prompts(4, seed=23)
-    results = {}
-    for layout in ("contiguous", "paged"):
-        eng = Engine(tp, tc, tp, tc, mode="pard", max_batch=2, max_len=256,
-                     temperature=0.8, seed=5, kv_layout=layout,
-                     kv_block_size=32,
-                     tree=TemplateBank.from_templates(BANK))
-        rids = {}
-        for i, p in enumerate(prompts):
-            t = 0.0 if i % 2 == 0 else None
-            rids[eng.submit(p, 12, temperature=t, tree_idx=i % 3)] = i
-        results[layout] = {rids[c.rid]: c.tokens for c in eng.run()}
-    for i in range(len(prompts)):
-        assert np.array_equal(results["contiguous"][i], results["paged"][i])
+    bank = TemplateBank.from_templates(BANK)
+    eng = Engine(tp, tc, tp, tc, mode="pard", max_batch=2, max_len=256,
+                 temperature=0.8, seed=5, kv_block_size=32, tree=bank)
+    rids = {}
+    for i, p in enumerate(prompts):
+        t = 0.0 if i % 2 == 0 else None
+        rids[eng.submit(p, 12, temperature=t, tree_idx=i % 3)] = i
+    comps = eng.run()
+    assert len(comps) == len(prompts)
+    ar = SpecDecoder(tp, tc, tp, tc, k=4, max_len=256)
+    sampler = SpecDecoder(tp, tc, tp, tc, max_len=256, temperature=0.8,
+                          kv_block_size=32, tree=bank)
+    for c in comps:
+        i = rids[c.rid]
+        if i % 2 == 0:
+            want = greedy_reference(ar, prompts[i], 12)
+        else:
+            want = sampled_reference(sampler, prompts[i], 12, c.rid, 5,
+                                     tree_idx=i % 3)
+        assert np.array_equal(want, c.tokens)
 
 
 # ------------------------------------------------- per-request sizing
@@ -175,7 +186,7 @@ def test_per_request_block_allocation(tiny):
     prompts = [rng.integers(0, 512, size=p_len).astype(np.int32)
                for _ in range(2)]
     eng = Engine(tp, tc, tp, tc, mode="pard", max_batch=2, max_len=256,
-                 kv_layout="paged", kv_block_size=bs, tree=bank)
+                 kv_block_size=bs, tree=bank)
     allocs = {}
 
     def spy(slot, n, _orig=eng.alloc.allocate):
@@ -208,7 +219,7 @@ def test_submit_feasibility_uses_per_request_slack(tiny):
     tc, tp, dc, dp = tiny
     bank = TemplateBank.from_templates(BANK)
     eng = Engine(tp, tc, tp, tc, mode="pard", max_batch=1, max_len=64,
-                 kv_layout="paged", kv_block_size=32, tree=bank)
+                 kv_block_size=32, tree=bank)
     prompt = np.arange(10, dtype=np.int32) % 512
     # 10 + 32 + 31 (wide) = 73 > 64, but + 10 (chain) = 52 fits
     with pytest.raises(ValueError, match="cache positions"):
@@ -221,13 +232,6 @@ def test_submit_feasibility_uses_per_request_slack(tiny):
     # invalid template index fails loudly too
     with pytest.raises(ValueError, match="tree_idx"):
         eng.submit(prompt, 8, tree_idx=7)
-    # contiguous rows are written batch-wide (the bank's widest window):
-    # pinning the chain must NOT shrink the requirement there, or the
-    # clamped cache write would silently corrupt committed KV near max_len
-    cont = Engine(tp, tc, tp, tc, mode="pard", max_batch=1, max_len=64,
-                  kv_layout="contiguous", tree=bank)
-    with pytest.raises(ValueError, match="cache positions"):
-        cont.submit(prompt, 32, tree_idx=0)
 
 
 def test_block_allocator_grow():
@@ -288,7 +292,7 @@ def test_adaptive_admission_falls_back_to_pool_sized_template(tiny):
     prompt = rng.integers(0, 512, size=8).astype(np.int32)
     # chain need = 8+16+10 = 34 -> 5 blocks of 8; wide needs 7 of 6 usable
     eng = Engine(tp, tc, tp, tc, mode="pard", max_batch=1, max_len=128,
-                 kv_layout="paged", kv_block_size=8, kv_num_blocks=7,
+                 kv_block_size=8, kv_num_blocks=7,
                  adaptive_tree=True, tree=TemplateBank.from_templates(BANK))
     eng.submit(prompt, 16)
     comps = eng.run()
@@ -311,7 +315,7 @@ def test_adaptive_engine_lossless_and_accounted(tiny):
         dec = SpecDecoder(tp, tc, tp, tc, k=4, max_len=256)
         refs[i] = np.asarray(dec.generate_ar(jnp.asarray(p)[None], 12)[0][0])
     eng = Engine(tp, tc, tp, tc, mode="pard", k=4, max_batch=2, max_len=256,
-                 kv_layout="paged", kv_block_size=32, adaptive_tree=True,
+                 kv_block_size=32, adaptive_tree=True,
                  tree_reselect_every=2)
     rids = {eng.submit(p, 12): i for i, p in enumerate(prompts)}
     comps = eng.run()

@@ -1,7 +1,9 @@
 """Per-architecture smoke tests (deliverable f): a REDUCED variant of each
 assigned family runs one forward and one train step on CPU; output shapes and
 finiteness asserted. Also checks prefill+decode consistency against a single
-cached forward (the property speculative decoding relies on)."""
+cached forward over the KV pool (the property speculative decoding relies
+on): every architecture's mixers — GQA, sliding-window, MLA, SSM, cross
+attention — run on the one cache layout."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -62,20 +64,21 @@ def test_train_step(name):
 def test_prefill_decode_consistency(name):
     cfg, params, tokens, enc_out, _ = _setup(name)
     T = tokens.shape[1]
-    caches = init_caches(cfg, 2, 64, dtype=jnp.float32)
+    # two rows of 3 pages of 8 slots each, interleaved; block 0 = garbage
+    bs, tables = 8, jnp.asarray([[1, 3, 5], [2, 4, 6]], jnp.int32)
+    kw = dict(enc_out=enc_out, dtype=jnp.float32, block_tables=tables,
+              kv_block_size=bs)
+    caches = init_caches(cfg, 2, 7, bs, dtype=jnp.float32)
     full, _, _ = forward(params, cfg, tokens, caches=caches,
-                         cache_pos=jnp.zeros(2, jnp.int32), enc_out=enc_out,
-                         dtype=jnp.float32)
-    caches = init_caches(cfg, 2, 64, dtype=jnp.float32)
+                         cache_pos=jnp.zeros(2, jnp.int32), **kw)
+    caches = init_caches(cfg, 2, 7, bs, dtype=jnp.float32)
     lg, caches, _ = forward(params, cfg, tokens[:, :10], caches=caches,
-                            cache_pos=jnp.zeros(2, jnp.int32),
-                            enc_out=enc_out, dtype=jnp.float32)
+                            cache_pos=jnp.zeros(2, jnp.int32), **kw)
     outs = [lg]
     for t in range(10, T):
         lg, caches, _ = forward(params, cfg, tokens[:, t:t + 1],
                                 caches=caches,
-                                cache_pos=jnp.full(2, t, jnp.int32),
-                                enc_out=enc_out, dtype=jnp.float32)
+                                cache_pos=jnp.full(2, t, jnp.int32), **kw)
         outs.append(lg)
     stepped = jnp.concatenate(outs, axis=1)
     np.testing.assert_allclose(np.asarray(full), np.asarray(stepped),

@@ -33,20 +33,25 @@ def test_forward_backend_equivalence(arch):
 
 
 def test_decode_backend_equivalence():
+    """A window wider than the paged kernel's (a 40-token prefill) reads
+    the pool through the gathered per-row view and the contiguous-operand
+    decode kernel; the decode after it through the paged kernel. Both must
+    match the XLA path."""
     cfg = get_config("tiny-target")
     params = init_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 40), 0,
                                 cfg.vocab_size)
+    tables = jnp.asarray([[1, 3, 5, 7, 9, 11], [2, 4, 6, 8, 10, 12]],
+                         jnp.int32)
+    kw = dict(block_tables=tables, kv_block_size=8, dtype=jnp.float32)
 
     def run():
-        caches = init_caches(cfg, 2, 64, dtype=jnp.float32)
-        _, caches, _ = forward(params, cfg, tokens, caches=caches,
-                               cache_pos=jnp.zeros(2, jnp.int32),
-                               dtype=jnp.float32)
+        caches = init_caches(cfg, 2, 13, 8, dtype=jnp.float32)
+        pre, caches, _ = forward(params, cfg, tokens, caches=caches,
+                                 cache_pos=jnp.zeros(2, jnp.int32), **kw)
         lg, _, _ = forward(params, cfg, tokens[:, :1], caches=caches,
-                           cache_pos=jnp.full(2, 16, jnp.int32),
-                           dtype=jnp.float32)
-        return lg
+                           cache_pos=jnp.full(2, 40, jnp.int32), **kw)
+        return jnp.concatenate([pre, lg], axis=1)
 
     set_attention_backend("xla")
     ref = run()
@@ -141,11 +146,14 @@ def test_platform_default_runs_kernels_on_cached_paths_only(monkeypatch):
     params = init_params(jax.random.PRNGKey(0), cfg)
     tokens = jnp.zeros((2, 16), jnp.int32)
 
+    tables = jnp.asarray([[1, 3], [2, 4]], jnp.int32)
+
     def decode(params, caches):
         return forward(params, cfg, tokens[:, :1], caches=caches,
-                       cache_pos=jnp.full(2, 15, jnp.int32))[0]
+                       cache_pos=jnp.full(2, 15, jnp.int32),
+                       block_tables=tables, kv_block_size=8)[0]
 
-    caches = init_caches(cfg, 2, 64)
+    caches = init_caches(cfg, 2, 5, 8)
     assert _has_pallas_call(decode, params, caches)
     assert not _has_pallas_call(
         lambda p: forward(p, cfg, tokens)[0], params)

@@ -5,7 +5,8 @@ scheduler's prefix-affinity-then-least-loaded ``_route_order`` exercised
 directly against real BlockAllocators (no devices, no models).
 
 Multi-device portion (CI dp-gate: REPRO_HOST_DEVICES=4): dp=2 engines are
-token-set-identical to dp=1 for the same request set in both KV layouts,
+token-set-identical to dp=1 for the same request set over bf16 and int8
+pools,
 same-prefix requests route to the replica owning the cached blocks, and a
 full owner replica overflows to the other replica instead of stalling.
 """
@@ -190,19 +191,20 @@ def _run_tokens(models, reqs, **cfg_kw):
 
 
 @needs2
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_dp2_token_set_identical_to_dp1(models, layout):
+@pytest.mark.parametrize("kv_dtype,prefix_cache",
+                         [("bf16", True), ("int8", False)])
+def test_dp2_token_set_identical_to_dp1(models, kv_dtype, prefix_cache):
     """The acceptance gate: dp=2 commits exactly the token set of dp=1
-    for the same mixed greedy + pinned-seed sampled request set, in both
-    KV layouts (routing must never leak into the tokens)."""
+    for the same mixed greedy + pinned-seed sampled request set, with
+    prefix-affinity routing over bf16 pools and least-loaded routing over
+    int8 ones (routing must never leak into the tokens)."""
     rng = np.random.default_rng(5)
     sys_p = rng.integers(0, 512, size=64).astype(np.int32)
     reqs = [np.concatenate([sys_p,
                             rng.integers(0, 512, size=6).astype(np.int32)])
             for _ in range(6)]
-    kw = dict(kv_layout=layout, kv_block_size=64, pipelined=True)
-    if layout == "paged":
-        kw["prefix_cache"] = True
+    kw = dict(kv_dtype=kv_dtype, kv_block_size=64, pipelined=True,
+              prefix_cache=prefix_cache)
     base, _ = _run_tokens(models, reqs, dp=1, **kw)
     out, eng = _run_tokens(models, reqs, dp=2, **kw)
     assert set(base) == set(out)
@@ -225,7 +227,7 @@ def test_dp2_same_prefix_requests_route_to_owner(models):
             for _ in range(4)]
     eng = Engine(tp, tc, dp, dc, config=EngineConfig(
         mode="pard", k=4, max_batch=2, max_len=256, seed=7, dp=2,
-        kv_layout="paged", kv_block_size=64, prefix_cache=True))
+        kv_block_size=64, prefix_cache=True))
     _mixed_submit(eng, reqs)
     eng.run()                                    # cold: seeds one replica
     eng.stats.update(affinity_routed=0, prefix_lookup_blocks=0,
@@ -251,7 +253,7 @@ def test_dp2_full_owner_overflows_not_stalls(models):
             for _ in range(6)]
     eng = Engine(tp, tc, dp, dc, config=EngineConfig(
         mode="pard", k=4, max_batch=2, max_len=256, seed=7, dp=2,
-        kv_layout="paged", kv_block_size=64, prefix_cache=True))
+        kv_block_size=64, prefix_cache=True))
     _mixed_submit(eng, reqs)
     eng.run()                                    # warm one replica's cache
     _mixed_submit(eng, reqs)                     # 6 warm same-prefix reqs

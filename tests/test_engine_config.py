@@ -32,12 +32,10 @@ def _prompts(rng, n, vocab=512):
 def test_config_validation():
     with pytest.raises(AssertionError):
         EngineConfig(mode="beam")
-    with pytest.raises(AssertionError):
-        EngineConfig(kv_layout="ragged")
+    with pytest.raises(TypeError):
+        EngineConfig(kv_layout="paged")     # one KV layout: no such field
     with pytest.raises(AssertionError, match="kv_dtype"):
         EngineConfig(kv_dtype="int4")
-    with pytest.raises(AssertionError, match="paged"):
-        EngineConfig(prefix_cache=True, kv_layout="contiguous")
     with pytest.raises(AssertionError, match="PARD"):
         EngineConfig(mode="ar", tree=(2, 2, 1))
     with pytest.raises(ValueError, match="max_batch"):
@@ -69,10 +67,10 @@ def test_config_dp_device_validation():
     assert EngineConfig(dp=1, mesh=mesh11).dp == 1
 
 
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_dp1_identical_to_no_dp(models, layout):
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_dp1_identical_to_no_dp(models, kv_dtype):
     """dp=1 (explicit single-replica mesh) is the historical single-engine
-    path bit-for-bit, in both KV layouts."""
+    path bit-for-bit, over bf16 and int8 pools."""
     import jax
 
     tc, tp, dc, dp = models
@@ -83,7 +81,7 @@ def test_dp1_identical_to_no_dp(models, layout):
     out = {}
     for name, mesh_arg in (("no_dp", None), ("dp1", mesh)):
         cfg = EngineConfig(mode="pard", k=4, max_batch=2, max_len=256,
-                           kv_layout=layout, kv_block_size=16, seed=5,
+                           kv_dtype=kv_dtype, kv_block_size=16, seed=5,
                            dp=1, mesh=mesh_arg)
         eng = Engine(tp, tc, dp, dc, config=cfg)
         rids = {eng.submit(p, 12): i for i, p in enumerate(prompts)}
@@ -117,13 +115,13 @@ def test_from_args_round_trip():
     direct construction; string trees normalise to TreeTemplate."""
     ns = argparse.Namespace(
         mode="pard", k=4, max_batch=2, max_len=256, temperature=0.7,
-        seed=3, kv_layout="contiguous", kv_block_size=32, kv_dtype="bf16",
+        seed=3, kv_block_size=32, kv_dtype="bf16",
         tree="2,2,1", adaptive_tree=False, prefix_cache=False,
         pipelined=True)
     cfg = EngineConfig.from_args(ns)
     ref = EngineConfig(mode="pard", k=4, max_batch=2, max_len=256,
-                       temperature=0.7, seed=3, kv_layout="contiguous",
-                       kv_block_size=32, tree=(2, 2, 1), pipelined=True)
+                       temperature=0.7, seed=3, kv_block_size=32,
+                       tree=(2, 2, 1), pipelined=True)
     assert cfg.max_batch == ref.max_batch and cfg.pipelined
     assert cfg.temperature == ref.temperature and cfg.seed == ref.seed
     # both normalise to a one-template bank of the same shape

@@ -1,13 +1,18 @@
 """Paged KV pool unit tests: allocator invariants (DESIGN.md §5 I1-I4),
-paged write/gather semantics, and pool bytes accounting."""
+paged write/gather semantics, the pool's per-layer leaves, forwards over
+the pool against the cache-free forward, and pool bytes accounting."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.models import forward, init_caches, init_params
-from repro.models.attention import gather_pages, write_cache_paged
+from repro.models import forward, init_params
+from repro.models import ssm as ssm_mod
+from repro.models.attention import (gather_pages, mla_pool_width,
+                                    resolve_kv_dtype, write_cache_paged)
+from repro.models.config import (ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA, SSM,
+                                 scan_plan)
 from repro.serving import kv_pool
 
 from hlo_text import pool_shapes, whole_buffer_fills_and_copies
@@ -90,113 +95,147 @@ def test_write_past_allocation_lands_in_garbage_block():
     assert np.all(np.asarray(far[1:]) == 0.0)
 
 
-@pytest.mark.parametrize("arch", ["tiny-target", "jamba-1.5-large-398b-smoke",
-                                  "deepseek-v2-lite-16b-smoke"])
-def test_forward_layout_equivalence(arch):
-    """Prefill + decode logits must be identical (up to numerics) between
-    contiguous caches and a paged pool with scrambled block ownership —
-    covers the GQA, MLA and SSM-hybrid cache paths."""
-    cfg = get_config(arch)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0,
-                                cfg.vocab_size)
-    bs, mbs = 8, 4
+def _chunked_pool_logits(params, cfg, tokens, dtype, chunks):
+    """Logits of ``tokens`` [2, T] fed through a KV pool in consecutive
+    windows ``chunks`` ((start, end) pairs): a prefill chunk, a second one
+    and a decode step, each writing and reading through scrambled block
+    tables (the rows own interleaved, non-monotone pages)."""
+    bs = 8
     tables = jnp.asarray(
         np.random.default_rng(0).permutation(np.arange(1, 9)).reshape(2, 4),
         jnp.int32)
+    pool = kv_pool.init_paged_caches(cfg, 2, num_blocks=9, block_size=bs,
+                                     dtype=dtype)
+    outs = []
+    for a, b in chunks:
+        lg, pool, _ = forward(params, cfg, tokens[:, a:b], caches=pool,
+                              cache_pos=jnp.full(2, a, jnp.int32),
+                              block_tables=tables, kv_block_size=bs,
+                              dtype=jnp.float32)
+        outs.append(lg)
+    return jnp.concatenate(outs, axis=1)
 
-    cont = init_caches(cfg, 2, bs * mbs, dtype=jnp.float32)
-    _, cont, _ = forward(params, cfg, tokens, caches=cont,
-                         cache_pos=jnp.zeros(2, jnp.int32), dtype=jnp.float32)
-    want, _, _ = forward(params, cfg, tokens[:, -1:], caches=cont,
-                         cache_pos=jnp.full(2, 12, jnp.int32),
-                         dtype=jnp.float32)
 
-    paged = kv_pool.init_paged_caches(cfg, 2, num_blocks=9, block_size=bs,
-                                      dtype=jnp.float32)
-    _, paged, _ = forward(params, cfg, tokens, caches=paged,
-                          cache_pos=jnp.zeros(2, jnp.int32),
-                          block_tables=tables, kv_block_size=bs,
-                          dtype=jnp.float32)
-    out, _, _ = forward(params, cfg, tokens[:, -1:], caches=paged,
-                        cache_pos=jnp.full(2, 12, jnp.int32),
-                        block_tables=tables, kv_block_size=bs,
-                        dtype=jnp.float32)
+def _cache_free_logits(params, cfg, tokens):
+    """The uncached forward over the whole sequence: attention over the
+    forward's own keys, SSM scans from the zero state. ``cache_pos`` (no
+    caches) selects the dropless MoE routing every cached forward uses."""
+    logits, _, _ = forward(params, cfg, tokens,
+                           cache_pos=jnp.zeros(2, jnp.int32),
+                           dtype=jnp.float32)
+    return logits
+
+
+CHUNKS = ((0, 8), (8, 12), (12, 13))
+
+
+@pytest.mark.parametrize("arch", ["tiny-target", "jamba-1.5-large-398b-smoke",
+                                  "deepseek-v2-lite-16b-smoke"])
+def test_forward_layout_equivalence(arch):
+    """Prefill chunks + decode through a pool with scrambled block
+    ownership give the logits of the cache-free forward (up to numerics) —
+    covers the GQA, MLA and SSM-hybrid cache paths."""
+    cfg = get_config(arch)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 13), 0,
+                                cfg.vocab_size)
+    out = _chunked_pool_logits(params, cfg, tokens, jnp.float32, CHUNKS)
+    want = _cache_free_logits(params, cfg, tokens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-4, rtol=2e-4)
 
 
+def _leaf_shapes(cfg, spec, batch, nb, bs, quant):
+    """The documented per-layer pool leaves (models.init_caches)."""
+    if spec.mixer in (ATTN_GLOBAL, ATTN_LOCAL):
+        hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+        out = {"k": (nb, bs, hkv, hd), "v": (nb, bs, hkv, hd)}
+        if quant:
+            out.update(k_scale=(nb, bs, hkv), v_scale=(nb, bs, hkv))
+        return out
+    if spec.mixer == ATTN_MLA:
+        # the latent row [c_kv | k_rope], zero-padded to whole 128 lanes
+        width = mla_pool_width(cfg)
+        assert width % 128 == 0
+        assert 0 <= width - cfg.kv_lora_rank - cfg.qk_rope_head_dim < 128
+        out = {"ckv": (nb, bs, width)}
+        if quant:
+            out["ckv_scale"] = (nb, bs)
+        return out
+    if spec.mixer == SSM:        # per row, [batch, ...]
+        return {k: v.shape for k, v in
+                ssm_mod.init_mamba2_state(cfg, batch, jnp.float32).items()}
+    return {}                    # cross attention keeps no pool
+
+
+def _assert_pool_follows_scan_plan(cfg, dtype, quant):
+    nb, bs, batch = 9, 8, 2
+    pool = kv_pool.init_paged_caches(cfg, batch, num_blocks=nb,
+                                     block_size=bs, dtype=dtype)
+    plan = scan_plan(cfg)
+    assert len(pool["prefix"]) == len(plan.prefix)
+    assert len(pool["scan"]) == len(plan.period)
+    for entries, specs, lead in ((pool["prefix"], plan.prefix, ()),
+                                 (pool["scan"], plan.period,
+                                  (plan.n_repeats,))):
+        for entry, spec in zip(entries, specs):
+            want = _leaf_shapes(cfg, spec, batch, nb, bs, quant)
+            assert {k: v.shape for k, v in entry.items()} == {
+                k: lead + v for k, v in want.items()}, spec
+            for name, leaf in entry.items():
+                if name in ("k", "v", "ckv"):
+                    assert leaf.dtype == resolve_kv_dtype(dtype)
+                else:            # quant scales and SSM states
+                    assert leaf.dtype == jnp.float32
+    cap = kv_pool.kv_capacity_bytes(cfg, pool)
+    assert cap == kv_pool.kv_bytes_per_block(cfg, pool, nb) * nb > 0
+    return cap
+
+
 @pytest.mark.parametrize("arch", ["tiny-target", "jamba-1.5-large-398b-smoke",
                                   "deepseek-v2-lite-16b-smoke"])
-def test_paged_cache_structure_matches_contiguous(arch):
-    """Same pytree structure as init_caches (the engine swaps layouts
-    without touching any consumer); attention leaves paged, SSM unchanged."""
-    cfg = get_config(arch)
-    cont = init_caches(cfg, 2, 64, dtype=jnp.float32)
-    paged = kv_pool.init_paged_caches(cfg, 2, num_blocks=9, block_size=8,
-                                      dtype=jnp.float32)
-    assert (jax.tree.structure(cont) == jax.tree.structure(paged))
-    cap = kv_pool.kv_capacity_bytes(cfg, paged)
-    per = kv_pool.kv_bytes_per_block(cfg, paged, 9)
-    assert cap == per * 9 > 0
+def test_pool_leaves_follow_scan_plan(arch):
+    """Every layer of ``scan_plan`` gets its documented cache: GQA K/V and
+    MLA latent pools [(R,) NB, bs, ...] shared through the block tables,
+    SSM states per row; byte accounting covers exactly the pools."""
+    _assert_pool_follows_scan_plan(get_config(arch), jnp.float32, False)
 
 
 # ------------------------------------------------------------ quantized pools
 @pytest.mark.parametrize("arch", ["tiny-target",
                                   "deepseek-v2-lite-16b-smoke"])
 def test_forward_layout_equivalence_int8(arch):
-    """The layout contract survives quantization: contiguous int8 caches
-    and a scrambled int8 paged pool see the SAME appended encodings
-    (quantization is deterministic per write), so decode logits agree to
-    the usual layout tolerance — covers GQA (fused-kernel dequant) and
-    MLA (dequant-at-gather) cache paths."""
+    """The pool contract survives quantization, covering GQA (fused-kernel
+    dequant) and MLA (dequant-at-gather). Quantization is deterministic
+    per write, so the chunk schedule changes nothing: chunked prefill +
+    decode give the logits of one window over the whole sequence. Against
+    the cache-free forward the int8 K/V error (at most amax/254 an element)
+    stays within 5% of the largest logit; a misread encoding or scale
+    errs by the logits' own size."""
     cfg = get_config(arch)
     params = init_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0,
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 13), 0,
                                 cfg.vocab_size)
-    bs, mbs = 8, 4
-    tables = jnp.asarray(
-        np.random.default_rng(0).permutation(np.arange(1, 9)).reshape(2, 4),
-        jnp.int32)
-
-    cont = init_caches(cfg, 2, bs * mbs, dtype="int8")
-    _, cont, _ = forward(params, cfg, tokens, caches=cont,
-                         cache_pos=jnp.zeros(2, jnp.int32), dtype=jnp.float32)
-    want, _, _ = forward(params, cfg, tokens[:, -1:], caches=cont,
-                         cache_pos=jnp.full(2, 12, jnp.int32),
-                         dtype=jnp.float32)
-
-    paged = kv_pool.init_paged_caches(cfg, 2, num_blocks=9, block_size=bs,
-                                      dtype="int8")
-    _, paged, _ = forward(params, cfg, tokens, caches=paged,
-                          cache_pos=jnp.zeros(2, jnp.int32),
-                          block_tables=tables, kv_block_size=bs,
-                          dtype=jnp.float32)
-    out, _, _ = forward(params, cfg, tokens[:, -1:], caches=paged,
-                        cache_pos=jnp.full(2, 12, jnp.int32),
-                        block_tables=tables, kv_block_size=bs,
-                        dtype=jnp.float32)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+    out = _chunked_pool_logits(params, cfg, tokens, "int8", CHUNKS)
+    whole = _chunked_pool_logits(params, cfg, tokens, "int8", ((0, 13),))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(whole),
                                atol=2e-4, rtol=2e-4)
+    want = np.asarray(_cache_free_logits(params, cfg, tokens))
+    v = cfg.vocab_size                      # padded ids hold -inf-like fill
+    scale = np.abs(want[..., :v]).max()
+    err = np.abs(np.asarray(out)[..., :v] - want[..., :v]).max()
+    assert 0 < err <= 0.05 * scale, (err, scale)
 
 
 @pytest.mark.parametrize("arch", ["tiny-target",
                                   "deepseek-v2-lite-16b-smoke"])
-def test_quant_paged_cache_structure_matches_contiguous(arch):
-    """The layout swap stays transparent under quantization: contiguous
-    int8 caches (with their *_scale leaves) and the int8 paged pool share
-    one pytree structure, and byte accounting covers values AND scales."""
+def test_quant_pool_leaves_follow_scan_plan(arch):
+    """Quantized pools keep the same per-layer plan, with float32 dequant
+    scales beside the values, and hold at most half the fp32 pool's
+    bytes."""
     cfg = get_config(arch)
-    cont = init_caches(cfg, 2, 64, dtype="int8")
-    paged = kv_pool.init_paged_caches(cfg, 2, num_blocks=9, block_size=8,
-                                      dtype="int8")
-    assert (jax.tree.structure(cont) == jax.tree.structure(paged))
-    cap = kv_pool.kv_capacity_bytes(cfg, paged)
-    per = kv_pool.kv_bytes_per_block(cfg, paged, 9)
-    assert cap == per * 9 > 0
-    fp32 = kv_pool.init_paged_caches(cfg, 2, num_blocks=9, block_size=8,
-                                     dtype="fp32")
-    assert cap * 2 <= kv_pool.kv_capacity_bytes(cfg, fp32)
+    cap = _assert_pool_follows_scan_plan(cfg, "int8", True)
+    assert cap * 2 <= _assert_pool_follows_scan_plan(cfg, "fp32", False)
 
 
 def test_quant_write_past_allocation_lands_in_garbage_block():
@@ -241,3 +280,36 @@ def test_fused_step_updates_stacked_pools_in_place(kind, kv):
     assert len(shapes) == (2 if kv == "bf16" else 4)
     text = eng.ex.step_hlo(tree=kind == "tree")
     assert whole_buffer_fills_and_copies(text, shapes) == []
+
+
+def test_row_tables_give_each_row_its_own_pages():
+    """The uniform-batch block tables of ``generate_*`` are the ones the
+    serving allocator hands out: every row owns the pages covering its positions,
+    no page twice, none of them the garbage block; the rest of each row's
+    table points at the garbage block (I1, I2)."""
+    from repro.core.spec_decode import row_tables
+    nb, tables = row_tables(3, 40, 128, 16)
+    tables = np.asarray(tables)
+    assert tables.shape == (3, 8)                    # blocks_for(128, 16)
+    assert nb == 3 * 3 + 1                           # 40 tokens: 3 pages
+    owned = tables[:, :3]
+    assert 0 not in owned
+    assert len(set(owned.ravel())) == owned.size
+    assert set(owned.ravel()) == set(range(1, nb))
+    assert np.all(tables[:, 3:] == 0)
+    # exactly the tables a fresh serving allocator hands out row after row
+    alloc = kv_pool.BlockAllocator(nb, 16, 3, 128)
+    for row in range(3):
+        alloc.allocate(row, 40)
+    np.testing.assert_array_equal(tables, alloc.tables)
+
+
+def test_cached_forward_requires_block_tables():
+    """The pool is the only cache: a cached forward without block tables
+    and a page size is refused, not read as some other layout."""
+    cfg = get_config("tiny-target")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    pool = kv_pool.init_paged_caches(cfg, 1, 3, 8)
+    with pytest.raises(AssertionError, match="block_tables"):
+        forward(params, cfg, jnp.zeros((1, 4), jnp.int32), caches=pool,
+                cache_pos=jnp.zeros(1, jnp.int32))

@@ -4,17 +4,17 @@ Four layers of coverage, cheapest first:
 
   * quantize→dequantize roundtrip error bounds (pure property sweeps, plus
     hypothesis when available);
-  * kernel-vs-oracle parity for every Pallas streaming variant (contiguous
-    + paged, decode + tree) with quantized pools and fused dequant,
-    including garbage-block poisoning;
+  * kernel-vs-oracle parity for every Pallas streaming variant (paged, and
+    the contiguous-operand wrappers over a gathered view; decode + tree)
+    with quantized pools and fused dequant, including garbage-block
+    poisoning;
   * pool-level invariants: scale leaves exist with the right shapes, byte
-    accounting shows the ≥2x int8 reduction, quantized pools keep the
-    pytree-structure contract with contiguous caches;
+    accounting shows the ≥2x int8 reduction;
   * committed-token quality bounds end-to-end: int8 spec == int8 AR
     (greedy losslessness is dtype-internal — quantization is deterministic
     per append, so the verifier and the baseline see identical caches),
-    paged == contiguous under int8, and int8-vs-fp32 greedy disagreement
-    stays under a calibrated bound on a fixed workload.
+    and int8-vs-fp32 greedy disagreement stays under a calibrated bound on
+    a fixed workload.
 """
 import jax
 import jax.numpy as jnp
@@ -275,8 +275,7 @@ def test_kv_dtype_registry():
 # committed-token quality bounds (end-to-end engine runs)
 # ---------------------------------------------------------------------------
 
-def _serve_tokens(kv_dtype, mode="pard", layout="paged", tree=None,
-                  n_req=4, max_new=24):
+def _serve_tokens(kv_dtype, mode="pard", tree=None, n_req=4, max_new=24):
     tc = get_config("tiny-target")
     dc = get_config("tiny-draft")
     tp = init_params(jax.random.PRNGKey(0), tc)
@@ -285,8 +284,7 @@ def _serve_tokens(kv_dtype, mode="pard", layout="paged", tree=None,
     rng = np.random.default_rng(0)
     eng = Engine(tp, tc, dp if mode != "ar" else None,
                  dc if mode != "ar" else None, mode=mode, k=4,
-                 max_batch=2, max_len=256, kv_layout=layout,
-                 kv_dtype=kv_dtype, tree=tree)
+                 max_batch=2, max_len=256, kv_dtype=kv_dtype, tree=tree)
     for _ in range(n_req):
         eng.submit(corpus.prompts(rng, 1, 16)[0], max_new)
     return {c.rid: list(c.tokens) for c in eng.run()}
@@ -299,11 +297,6 @@ def test_greedy_spec_matches_ar_under_int8():
     values unchanged), so spec-vs-AR must stay bit-exact under int8."""
     assert _serve_tokens("int8", mode="pard") == _serve_tokens("int8",
                                                                mode="ar")
-
-
-def test_int8_paged_matches_contiguous():
-    assert _serve_tokens("int8", layout="paged") == \
-        _serve_tokens("int8", layout="contiguous")
 
 
 def test_tree_mode_lossless_under_int8():

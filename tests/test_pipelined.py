@@ -1,6 +1,6 @@
 """Overlap-pipelined serve loop (DESIGN.md §9): the depth-2 dispatch/
 harvest pipeline must be invisible in the tokens — byte-identical
-completions vs the synchronous loop — across layouts, sampling modes,
+completions vs the synchronous loop — across KV dtypes, sampling modes,
 EOS truncation, slot churn (re-admission while a step is in flight) and
 adaptive reshaping. Plus the observability contract: per-step host
 overhead lands in latency_summary."""
@@ -58,24 +58,24 @@ def _assert_identical(sync, pipe):
 
 
 # ------------------------------------------------------- token identity
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_pipelined_greedy_identical(models, layout):
-    """Greedy batches: the pipeline is invisible in the tokens in both
-    KV layouts, including mid-flight admission churn (6 requests through
-    2 slots means every retirement re-admits while a step is in
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_pipelined_greedy_identical(models, kv_dtype):
+    """Greedy batches: the pipeline is invisible in the tokens over bf16
+    and int8 pools, including mid-flight admission churn (6 requests
+    through 2 slots means every retirement re-admits while a step is in
     flight)."""
-    kw = dict(kv_layout=layout, kv_block_size=32)
+    kw = dict(kv_dtype=kv_dtype, kv_block_size=32)
     sync = _run(models, False, engine_kw=kw)
     pipe = _run(models, True, engine_kw=kw)
     _assert_identical(sync, pipe)
 
 
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_pipelined_sampled_mixed_identical(models, layout):
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_pipelined_sampled_mixed_identical(models, kv_dtype):
     """Seeded-sampled rows mixed with greedy rows: per-request (seed,
     rid) PRNG keys advance only on a row's own live steps, so the
     pipeline shifts nothing."""
-    kw = dict(kv_layout=layout, kv_block_size=32)
+    kw = dict(kv_dtype=kv_dtype, kv_block_size=32)
     temps = (0.0, 0.8, 0.0, 1.2)
     sync = _run(models, False, temps=temps, engine_kw=kw)
     pipe = _run(models, True, temps=temps, engine_kw=kw)

@@ -270,20 +270,18 @@ def _shared_prompts(rng, n, sys_len=33, tail=5):
 @pytest.mark.parametrize("temps", [[0.0] * 5, [0.0, 0.8, 0.0, 0.7, 0.8]],
                          ids=["greedy", "mixed-sampled"])
 def test_cache_hit_completions_identical_to_cold(models, temps):
-    """Cache-hit completions must be token-identical to cold ones in BOTH
-    layouts: contiguous (no cache, the reference), paged cold, paged warm.
-    Greedy rows are deterministic; sampled rows are seeded per (seed, rid),
-    so their trajectories must also be invariant to the KV source."""
+    """Cache-hit completions must be token-identical to cold ones: the
+    same requests with the prefix cache off (cold) and on (warm). Greedy
+    rows are deterministic; sampled rows are seeded per (seed, rid), so
+    their trajectories must also be invariant to the KV source."""
     from repro.serving.engine import Engine
     tc, tp, dc, dp = models
     rng = np.random.default_rng(7)
     prompts = _shared_prompts(rng, 5)
     results = {}
-    for name, layout, cache in [("cont", "contiguous", False),
-                                ("cold", "paged", False),
-                                ("warm", "paged", True)]:
+    for name, cache in [("cold", False), ("warm", True)]:
         eng = Engine(tp, tc, dp, dc, mode="pard", k=4, max_batch=2,
-                     max_len=256, kv_layout=layout, kv_block_size=16,
+                     max_len=256, kv_block_size=16,
                      prefix_cache=cache, seed=0)
         rids = {eng.submit(p, 12, temperature=t): i
                 for i, (p, t) in enumerate(zip(prompts, temps))}
@@ -292,8 +290,7 @@ def test_cache_hit_completions_identical_to_cold(models, temps):
             assert eng.prefix_hit_rate() > 0.5
             assert eng.alloc.blocks_in_use == 0
     for i in range(len(prompts)):
-        assert np.array_equal(results["cont"][i], results["cold"][i])
-        assert np.array_equal(results["cont"][i], results["warm"][i])
+        assert np.array_equal(results["cold"][i], results["warm"][i])
 
 
 def test_live_sharing_refcounts_and_block_savings(models):
@@ -305,7 +302,7 @@ def test_live_sharing_refcounts_and_block_savings(models):
     rng = np.random.default_rng(8)
     prompts = _shared_prompts(rng, 3)
     eng = Engine(tp, tc, dp, dc, mode="pard", k=4, max_batch=2, max_len=256,
-                 kv_layout="paged", kv_block_size=16, prefix_cache=True)
+                 kv_block_size=16, prefix_cache=True)
     eng.submit(prompts[0], 8)
     eng.run()
     free_before = len(eng.alloc.free) + len(eng.alloc.lru)
@@ -323,14 +320,6 @@ def test_live_sharing_refcounts_and_block_savings(models):
     assert taken < cold_need
     comps = eng.run()
     assert len(comps) == 3 and eng.alloc.blocks_in_use == 0
-
-
-def test_prefix_cache_rejects_contiguous_layout(models):
-    from repro.serving.engine import Engine
-    tc, tp, dc, dp = models
-    with pytest.raises(AssertionError, match="paged"):
-        Engine(tp, tc, dp, dc, mode="pard", kv_layout="contiguous",
-               prefix_cache=True)
 
 
 def test_prefix_keys_are_content_exact():
@@ -380,7 +369,7 @@ def test_warm_cache_identical_to_cold_under_int8(models):
     results = {}
     for name, cache in [("cold", False), ("warm", True)]:
         eng = Engine(tp, tc, dp, dc, mode="pard", k=4, max_batch=2,
-                     max_len=256, kv_layout="paged", kv_block_size=16,
+                     max_len=256, kv_block_size=16,
                      prefix_cache=cache, kv_dtype="int8", seed=0)
         rids = {eng.submit(p, 12, temperature=t): i
                 for i, (p, t) in enumerate(zip(prompts, temps))}
@@ -403,7 +392,7 @@ def test_live_sharing_and_cow_invariants_under_int8(models):
     rng = np.random.default_rng(12)
     prompts = _shared_prompts(rng, 3)
     eng = Engine(tp, tc, dp, dc, mode="pard", k=4, max_batch=2, max_len=256,
-                 kv_layout="paged", kv_block_size=16, prefix_cache=True,
+                 kv_block_size=16, prefix_cache=True,
                  kv_dtype="int8")
     eng.submit(prompts[0], 8)
     eng.run()

@@ -14,6 +14,8 @@ from repro.core.spec_decode import SpecDecoder, TreeTemplate
 from repro.models import forward, init_params
 from repro.serving.engine import Engine
 
+from decode_refs import sampled_reference
+
 TEMP = 0.8
 
 
@@ -85,21 +87,23 @@ def test_sampled_tree_seeded_determinism(tiny):
 
 
 def test_sampled_tree_layouts_agree(tiny):
-    """Sampled tree decoding commits identical tokens under the contiguous
-    and block-paged KV layouts: per-request (seed, rid) keys make the
-    sampling trajectory independent of the cache layout."""
+    """Sampled tree decoding through the engine's KV pool commits exactly
+    the tokens of ``generate_spec`` on the same per-request (seed, rid)
+    key: the sampling trajectory depends on the request's own key, never
+    on batch composition or how its prompt was prefilled."""
     tc, tp, dc, dp = tiny
     prompts = _ragged_prompts(4)
-    results = {}
-    for layout in ("contiguous", "paged"):
-        eng = Engine(tp, tc, tp, tc, mode="pard", max_batch=2, max_len=256,
-                     temperature=TEMP, seed=7, kv_layout=layout,
-                     kv_block_size=32,
-                     tree=TreeTemplate.from_branching((2, 2, 2, 1)))
-        rids = {eng.submit(p, 12): i for i, p in enumerate(prompts)}
-        results[layout] = {rids[c.rid]: c.tokens for c in eng.run()}
-    for i in range(len(prompts)):
-        assert np.array_equal(results["contiguous"][i], results["paged"][i])
+    tree = TreeTemplate.from_branching((2, 2, 2, 1))
+    eng = Engine(tp, tc, tp, tc, mode="pard", max_batch=2, max_len=256,
+                 temperature=TEMP, seed=7, kv_block_size=32, tree=tree)
+    rids = {eng.submit(p, 12): i for i, p in enumerate(prompts)}
+    comps = eng.run()
+    assert len(comps) == len(prompts)
+    dec = SpecDecoder(tp, tc, tp, tc, max_len=256, temperature=TEMP,
+                      kv_block_size=32, tree=tree)
+    for c in comps:
+        want = sampled_reference(dec, prompts[rids[c.rid]], 12, c.rid, 7)
+        assert np.array_equal(want, c.tokens)
 
 
 def test_mixed_batch_greedy_rows_exact(tiny):
@@ -114,7 +118,7 @@ def test_mixed_batch_greedy_rows_exact(tiny):
         dec = SpecDecoder(tp, tc, tp, tc, k=4, max_len=256)
         refs[i] = np.asarray(dec.generate_ar(jnp.asarray(p)[None], 12)[0][0])
     eng = Engine(tp, tc, tp, tc, mode="pard", max_batch=2, max_len=256,
-                 temperature=TEMP, seed=7, kv_layout="paged",
+                 temperature=TEMP, seed=7,
                  kv_block_size=32,
                  tree=TreeTemplate.from_branching((2, 2, 2, 1)))
     rids = {}
@@ -141,7 +145,7 @@ def test_flat_spec_per_request_temperature(tiny):
     def run():
         eng = Engine(tp, tc, tp, tc, mode="pard", k=4, max_batch=2,
                      max_len=256, temperature=TEMP, seed=9,
-                     kv_layout="paged", kv_block_size=32)
+                     kv_block_size=32)
         rids = {}
         for i, p in enumerate(prompts):
             t = 0.0 if i == 0 else None

@@ -5,8 +5,8 @@ serving ruleset, paged-pool / quant-scale / draft shardings, mesh helpers,
 and mesh-of-1 == no-mesh token identity.
 
 Multi-device portion (CI shard-gate: REPRO_HOST_DEVICES=4): token identity
-across mesh shapes 1/2/4 for mixed greedy + seeded-sampled batches in both
-KV layouts, and through the pipelined loop.
+across mesh shapes 1/2/4 for mixed greedy + seeded-sampled batches over
+bf16 and int8 pools, and through the pipelined loop.
 """
 import os
 
@@ -143,10 +143,11 @@ def test_config_builds_mesh_from_tp():
 
 
 # ------------------------------------------------------------ token identity
-def _serve(models, mesh, layout, pipelined=False, n_req=4, max_new=12):
+def _serve(models, mesh, kv_dtype="bf16", pipelined=False, n_req=4,
+           max_new=12):
     tc, tp, dc, dp = models
     cfg = EngineConfig(mode="pard", k=4, max_batch=2, max_len=256,
-                      kv_layout=layout, kv_block_size=16, seed=3,
+                      kv_dtype=kv_dtype, kv_block_size=16, seed=3,
                       pipelined=pipelined, mesh=mesh)
     eng = Engine(tp, tc, dp, dc, config=cfg)
     rng = np.random.default_rng(7)
@@ -162,27 +163,29 @@ def _serve(models, mesh, layout, pipelined=False, n_req=4, max_new=12):
     return {out_rids[c.rid]: c.tokens for c in eng.run()}
 
 
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_mesh_of_one_matches_no_mesh(models, layout):
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_mesh_of_one_matches_no_mesh(models, kv_dtype):
     """A (1, 1) mesh engine — full sharded code path: serving rules,
     state shardings, pinned jit shardings — is token-identical to the
-    meshless engine."""
-    base = _serve(models, None, layout)
-    one = _serve(models, _mesh1(), layout)
+    meshless engine, over bf16 and int8 pools."""
+    base = _serve(models, None, kv_dtype)
+    one = _serve(models, _mesh1(), kv_dtype)
     assert base.keys() == one.keys()
     for i in base:
         assert np.array_equal(base[i], one[i]), f"request {i} diverged"
 
 
 @needs4
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_token_identity_across_mesh_shapes(models, layout):
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_token_identity_across_mesh_shapes(models, kv_dtype):
     """THE tentpole gate: meshes of 1, 2 and 4 devices produce bitwise-
-    identical tokens for a mixed greedy + seeded-sampled batch."""
-    base = _serve(models, mesh_mod.make_host_mesh(model=1, data=1), layout)
+    identical tokens for a mixed greedy + seeded-sampled batch, over bf16
+    and int8 pools."""
+    base = _serve(models, mesh_mod.make_host_mesh(model=1, data=1),
+                  kv_dtype)
     for n in (2, 4):
         got = _serve(models, mesh_mod.make_host_mesh(model=n, data=1),
-                     layout)
+                     kv_dtype)
         assert base.keys() == got.keys()
         for i in base:
             assert np.array_equal(base[i], got[i]), \
@@ -193,8 +196,8 @@ def test_token_identity_across_mesh_shapes(models, layout):
 def test_sharded_pipelined_loop_identity(models):
     """The depth-2 dispatch/harvest pipeline (DESIGN.md §9) composes with
     tensor-parallel serving: same tokens as the synchronous tp=1 loop."""
-    base = _serve(models, None, "paged", pipelined=False)
-    got = _serve(models, mesh_mod.make_host_mesh(model=2, data=1), "paged",
+    base = _serve(models, None, pipelined=False)
+    got = _serve(models, mesh_mod.make_host_mesh(model=2, data=1),
                  pipelined=True)
     assert base.keys() == got.keys()
     for i in base:
@@ -211,9 +214,8 @@ def test_kernel_path_token_identity_across_meshes(models, n):
     from repro.models.attention import set_attention_backend
     set_attention_backend("pallas")
     try:
-        base = _serve(models, None, "paged")
-        got = _serve(models, mesh_mod.make_host_mesh(model=n, data=1),
-                     "paged")
+        base = _serve(models, None)
+        got = _serve(models, mesh_mod.make_host_mesh(model=n, data=1))
     finally:
         set_attention_backend(None)
     assert base.keys() == got.keys()

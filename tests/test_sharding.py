@@ -12,8 +12,9 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
 from repro.launch.mesh import make_host_mesh
-from repro.launch.steps import cache_shapes, param_shapes
-from repro.sharding.specs import cache_specs, data_spec, param_specs
+from repro.launch.steps import SHAPES, cache_shapes, input_specs, param_shapes
+from repro.serving.kv_pool import kernel_pools
+from repro.sharding.specs import data_spec, paged_cache_specs, param_specs
 
 
 class FakeMesh:
@@ -92,13 +93,36 @@ def test_data_spec_fallbacks():
 
 
 def test_cache_specs_long_context_seq_sharding():
-    """batch=1: KV cache must shard its sequence dim over data."""
-    cfg = get_config("gemma2-27b")
+    """batch=1 long context over the dry-run's pool: the pool has no batch
+    or sequence axis — its pages are shared through the block tables, which
+    every device resolves — so its KV heads shard over model and nothing
+    else does, in every attention leaf."""
+    cfg = get_config("gemma2-27b")                # 16 kv heads
     caches = cache_shapes(cfg, 1, 8192)
-    specs = cache_specs(caches, cfg, MESH, 1)
-    k_spec = tuple(specs["scan"][0]["k"])
-    # [R, B, S, Hkv, hd] -> S (index 2) on data
-    assert k_spec[2] == "data"
+    k = caches["scan"][0]["k"]
+    # [R, NB, bs, Hkv, hd]: 8192 positions of 64-slot pages + garbage
+    assert k.shape[1:] == (8192 // 64 + 1, 64, cfg.n_kv_heads,
+                           cfg.resolved_head_dim)
+    specs = paged_cache_specs(caches, MESH)
+    for path, spec in _leaves_with_paths(specs):
+        if path.endswith("/k") or path.endswith("/v"):
+            assert tuple(spec) == (None, None, None, "model", None), path
+
+
+def test_serving_specs_hold_one_replicas_rows():
+    """The dry-run lowers a serving step as one data-parallel replica runs
+    it: that replica's share of the global batch, rounded up, and a pool of
+    64-slot pages for those rows alone (plus the garbage block)."""
+    cfg = get_config("tiny-target")
+    for shape, replicas, rows in (("decode_32k", 16, 8),
+                                  ("prefill_32k", 32, 1),
+                                  ("long_500k", 16, 1)):
+        ins = input_specs(cfg, shape, replicas=replicas)
+        pages = SHAPES[shape]["seq_len"] // 64
+        assert ins["batch"]["tokens"].shape[0] == rows, shape
+        assert ins["batch"]["tables"].shape == (rows, pages), shape
+        pool = kernel_pools(cfg, ins["caches"])[0]
+        assert pool.shape[:2] == (rows * pages + 1, 64), shape
 
 
 def test_sharded_train_step_runs_on_host_mesh():

@@ -116,3 +116,40 @@ def test_acceptance_histogram_monotone(tiny):
     _, stats = dec.generate_spec(prompt, 40, mode="pard")
     h = list(stats.accept_hist)
     assert all(h[i] >= h[i + 1] for i in range(len(h) - 1))
+
+
+def test_one_decode_state_constructor(tiny, monkeypatch):
+    """``generate_*`` and the serving ``Executor`` both get their
+    ``DecodeState`` from ``SpecDecoder.init_state``; sized alike (rows,
+    token buffer, pool pages), the two states have one tree structure and
+    one leaf shape and dtype apiece — a new state field lands in both."""
+    from repro.serving.engine import Engine, EngineConfig
+    tc, tp, dc, dp = tiny
+    built = []
+    orig = SpecDecoder.init_state
+
+    def spy(self, *a, **kw):
+        state = orig(self, *a, **kw)
+        built.append(state)
+        return state
+
+    monkeypatch.setattr(SpecDecoder, "init_state", spy)
+    prompt = _prompt(tc.vocab_size)
+    b, p, max_new = prompt.shape[0], prompt.shape[1], 8
+    # generate_spec's token buffer: prompt + max_new + the window slack
+    gen_len = p + max_new + SpecDecoder(tp, tc, dp, dc, k=4).window_slack
+    dec = SpecDecoder(tp, tc, dp, dc, k=4, max_len=gen_len,
+                      kv_block_size=16)
+    dec.generate_spec(prompt, max_new, mode="pard")
+    assert len(built) == 1
+    nb = built[0].tcache["scan"][0]["k"].shape[1]
+    eng = Engine(tp, tc, dp, dc, config=EngineConfig(
+        mode="pard", k=4, max_batch=b, max_len=gen_len, kv_block_size=16,
+        kv_num_blocks=nb))
+    assert len(built) == 2 and eng.ex.state is built[1]
+    gen_state, ex_state = built
+    assert jax.tree.structure(gen_state) == jax.tree.structure(ex_state)
+    for x, y in zip(jax.tree.leaves(gen_state), jax.tree.leaves(ex_state)):
+        assert (x.shape, x.dtype) == (y.shape, y.dtype)
+    dec.generate_ar(prompt, max_new)
+    assert len(built) == 3 and built[2].dcache is None   # AR: no draft pool

@@ -11,7 +11,7 @@ Multi-device portion (CI shard-gate throughput leg,
 REPRO_HOST_DEVICES=4): the empirical psum law — XLA CPU's bf16
 all-reduce equals f32-upcast-sum-round-once — against a real 4-way psum,
 and bitwise tp2/tp4-vs-tp1 greedy/sampled token identity of the
-throughput ruleset in both KV layouts.
+throughput ruleset over bf16 and int8 pools.
 """
 import jax
 import jax.numpy as jnp
@@ -228,10 +228,10 @@ def test_psum_bf16_is_f32_upcast_sum_rounded_once():
 
 
 # -------------------------------------------------------- cross-mesh identity
-def _serve(models, mesh, layout, ruleset, n_req=4, max_new=12):
+def _serve(models, mesh, kv_dtype, ruleset, n_req=4, max_new=12):
     tc, tp, dc, dp = models
     cfg = EngineConfig(mode="pard", k=4, max_batch=2, max_len=256,
-                       kv_layout=layout, kv_block_size=16, seed=3,
+                       kv_dtype=kv_dtype, kv_block_size=16, seed=3,
                        mesh=mesh, tp_ruleset=ruleset)
     eng = Engine(tp, tc, dp, dc, config=cfg)
     rng = np.random.default_rng(7)
@@ -247,17 +247,18 @@ def _serve(models, mesh, layout, ruleset, n_req=4, max_new=12):
 
 
 @needs4
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_throughput_identity_across_mesh_shapes(models, layout):
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_throughput_identity_across_mesh_shapes(models, kv_dtype):
     """The throughput ruleset's canonical-chunk numerics make every mesh
     size round the same f32 partial sum once — greedy AND seeded-sampled
     completions at tp2/tp4 match the throughput-tp1 reference at >= 0.99
-    positional exact-match (bitwise in practice), in both KV layouts."""
+    positional exact-match (bitwise in practice), over bf16 and int8
+    pools."""
     base = _serve(models, mesh_mod.make_host_mesh(model=1, data=1),
-                  layout, "throughput")
+                  kv_dtype, "throughput")
     for n in (2, 4):
         got = _serve(models, mesh_mod.make_host_mesh(model=n, data=1),
-                     layout, "throughput")
+                     kv_dtype, "throughput")
         assert base.keys() == got.keys()
         match = total = 0
         for i in base:
@@ -267,4 +268,4 @@ def test_throughput_identity_across_mesh_shapes(models, layout):
             total += max(len(a), len(b))
         rate = match / max(1, total)
         assert rate >= 0.99, \
-            f"tp{n}/{layout}: exact-match rate {rate:.4f} < 0.99"
+            f"tp{n}/{kv_dtype}: exact-match rate {rate:.4f} < 0.99"

@@ -210,8 +210,8 @@ def main() -> int:
     out = {}
     for ruleset in args.rulesets.split(","):
         eng = Engine(tparams, tc, dparams, dc, config=EngineConfig(
-            mode="pard", k=4, max_batch=2, max_len=256, kv_layout="paged",
-            kv_block_size=16, mesh=make_host_mesh(model=args.tp, data=1),
+            mode="pard", k=4, max_batch=2, max_len=256, kv_block_size=16,
+            mesh=make_host_mesh(model=args.tp, data=1),
             tp=args.tp, tp_ruleset=ruleset))
         out[ruleset] = audit_engine(eng)
     print(json.dumps(out, indent=2, sort_keys=True))
