@@ -38,7 +38,12 @@ costs a grid step. The tree kernel (tree_attention.py) sweeps that way too.
 The queries of one row are regrouped to ``[Hkv, Tq*G, D]`` (kv head major;
 row ``i*G + j`` = query position ``i``, group member ``j``) and stay
 resident in VMEM across the sweep; a static loop over the kv heads runs the
-online softmax per head against its ``[P * block, D]`` slice. Per-row
+online softmax per head against its ``[P * block, D]`` slice. The operand
+dtype of its two matmuls follows the inputs (``sweep_block``): bf16 queries
+against a bf16 pool go to the MXU as bf16 with float32 accumulation (PV on
+the bf16 high part and remainder of the float32 probabilities), every other
+input (float32 queries or pools, a quantized pool) is cast to float32
+first; the softmax is float32 either way. Per-row
 scalars (``kv_len``, and the block table) are scalar-prefetched into SMEM;
 per-query-row vectors (``q_pos``) arrive pre-expanded to ``[B, Tq*G, 1]``
 so their VMEM block spans the full minor dims.
@@ -141,15 +146,63 @@ def init_scratch(m_s, l_s, acc_s):
     acc_s[...] = jnp.zeros_like(acc_s)
 
 
+def word_view(ref):
+    """A ``[1, n, Hkv, D]`` bf16 block as ``[n, Hkv/2 * D]`` uint32, kv
+    heads ``2j`` and ``2j + 1`` in the low and high halves of the words of
+    columns ``j*D .. j*D + D - 1``: the block's own bytes, loaded in whole
+    32-bit tiles."""
+    _, n, hkv, d = ref.shape
+    return ref.bitcast(jnp.uint32).reshape(n, hkv // 2 * d)
+
+
+def unpack_head(words, h, d):
+    """Kv head ``h``'s bf16 ``[n, d]`` values out of ``word_view``: a shift
+    or a mask a word, and a conversion. Sliced straight out of the
+    ``[n, Hkv, d]`` bf16 block, a head's values are gathered one slot at a
+    time, which made the kernel ten times slower on a v5e (PERF.md)."""
+    j = h // 2 * d
+    w = words[:, j:j + d]
+    bits = w << 16 if h % 2 == 0 else w & jnp.uint32(0xFFFF0000)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32).astype(
+        jnp.bfloat16)
+
+
+def split_dot(p, v):
+    """``p @ v`` for float32 ``p`` [rows, n] and bf16 ``v`` [n, d] on bf16
+    operands with float32 accumulation: ``p``'s bf16 high part and its bf16
+    remainder keep about 16 bits of its mantissa, the products a float32
+    dot forms where ``v``'s low half is zero."""
+    hi = p.astype(jnp.bfloat16)
+    lo = (p - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    return (jnp.dot(hi, v, preferred_element_type=jnp.float32)
+            + jnp.dot(lo, v, preferred_element_type=jnp.float32))
+
+
 def sweep_block(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_s, l_s, acc_s, mask,
                 *, scale, softcap):
     """One KV block ``[1, n, Hkv, D]`` (a page, or a group of pages) of the
     online softmax for every kv head. ``mask`` is the [Tq*G, n] validity of
-    this block (the same for every head)."""
+    this block (the same for every head).
+
+    Operands: bf16 queries against a bf16 pool with an even number of kv
+    heads (``word_view``) feed the MXU as bf16 (K and V unpacked from
+    ``word_view``), QK in one bf16 pass and PV as ``split_dot``, both
+    accumulated in float32; every other input (float32 queries or pools,
+    quantized pools and their scales, an odd number of kv heads) is cast to
+    float32 first. The scale, softcap, mask and online softmax are float32
+    either way."""
+    bf16 = (ks_ref is None and k_ref.shape[2] % 2 == 0
+            and all(r.dtype == jnp.bfloat16 for r in (q_ref, k_ref, v_ref)))
+    if bf16:
+        d = k_ref.shape[3]
+        kw, vw = word_view(k_ref), word_view(v_ref)
     for h in range(q_ref.shape[1]):
-        q = q_ref[0, h].astype(jnp.float32)                 # [rows, d]
-        k = k_ref[0, :, h, :].astype(jnp.float32)           # [n, d]
-        v = v_ref[0, :, h, :].astype(jnp.float32)
+        q = q_ref[0, h]                                     # [rows, d]
+        if bf16:
+            k, v = unpack_head(kw, h, d), unpack_head(vw, h, d)  # [n, d]
+        else:
+            q, k, v = (x.astype(jnp.float32)
+                       for x in (q, k_ref[0, :, h, :], v_ref[0, :, h, :]))
         if ks_ref is not None:
             # dequant fused into the sweep: the block expands against its
             # per-slot scales right after the DMA, still inside VMEM
@@ -165,8 +218,9 @@ def sweep_block(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_s, l_s, acc_s, mask,
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_s[h] = l_s[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_s[h] = acc_s[h] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        pv = (split_dot(p, v) if bf16
+              else jnp.dot(p, v, preferred_element_type=jnp.float32))
+        acc_s[h] = acc_s[h] * alpha + pv
         m_s[h] = m_new
 
 

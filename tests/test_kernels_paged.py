@@ -1,6 +1,8 @@
 """Paged GQA decode and tree kernels against the ref.py oracles
 (interpret mode on the CPU): shuffled tables, the garbage block, the page
 sweep over every edge fill, and pools stacked over layers."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -201,3 +203,98 @@ def test_paged_kernels_read_stacked_pool_at_layer(kernel, kv, layer):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
+def _paged_call(kernel, q, k_pages, v_pages, tables, kv_len, q_pos, **kw):
+    """The decode or tree kernel (a chain: each window slot sees the ones
+    before it) over the same pools and queries."""
+    if kernel == "tree":
+        tq = q.shape[1]
+        anc = jnp.broadcast_to(jnp.asarray(
+            [(1 << (s + 1)) - 1 for s in range(tq)], jnp.uint32),
+            (q.shape[0], tq))
+        return ops.tree_attention_paged(q, k_pages, v_pages, tables, kv_len,
+                                        q_pos, kv_len - tq, anc, **kw)
+    return ops.decode_attention_paged(q, k_pages, v_pages, tables, kv_len,
+                                      q_pos, **kw)
+
+
+@pytest.mark.parametrize("kernel,tq,hq,d,bs,mbs", [
+    ("decode", 9, 12, 128, 64, 5),    # swept, the cell's verify window
+    ("decode", 16, 12, 128, 64, 5),   # swept, the cell's draft window
+    ("decode", 9, 4, 64, 32, 4),      # one page a grid step (head dim 64)
+    ("tree", 9, 12, 128, 64, 3),      # tree kernel, one page a grid step
+])
+def test_bf16_operands_match_float32_path(kernel, tq, hq, d, bs, mbs):
+    """bf16 queries against bf16 pools are scored on bf16 MXU operands
+    (QK in one pass, PV on p's bf16 high part and remainder). The same
+    values with the pools upcast to float32 take the float32 path. The two
+    compute the same products up to summation order and p's mantissa past
+    16 bits, so the bf16 outputs agree within one bf16 ulp: a relative
+    tolerance of bfloat16's eps (the ulp of a value is at most eps times
+    it), and an absolute one of eps**2 for outputs near zero, where the
+    float32 paths' difference (about 2**-17 of the largest value) is all
+    that is left."""
+    b, hkv = 3, 2
+    eps = float(jnp.finfo(jnp.bfloat16).eps)
+    k_pages, v_pages, tables, _, _ = paged_setup(b, hkv, d, bs, mbs, key=70)
+    k_pages, v_pages = (x.astype(jnp.bfloat16) for x in (k_pages, v_pages))
+    q = rand(b, tq, hq, d, k=73, dtype=jnp.bfloat16)
+    kv_len = jnp.asarray([bs * mbs, bs * mbs // 2 + 5, tq], jnp.int32)
+    q_pos = (kv_len - tq)[:, None] + jnp.arange(tq)[None, :]
+    out = _paged_call(kernel, q, k_pages, v_pages, tables, kv_len, q_pos)
+    want = _paged_call(kernel, q, k_pages.astype(jnp.float32),
+                       v_pages.astype(jnp.float32), tables, kv_len, q_pos)
+    assert out.dtype == want.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), rtol=eps,
+                               atol=eps ** 2)
+
+
+def _dot_operand_dtypes(jaxpr):
+    """The operand dtypes of every ``dot_general`` in ``jaxpr`` and the
+    jaxprs nested in its equations (loops, conditionals, kernels)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(tuple(str(v.aval.dtype) for v in eqn.invars))
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else [p]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found += _dot_operand_dtypes(inner)
+    return found
+
+
+@pytest.mark.parametrize("kernel", ["decode", "tree"])
+@pytest.mark.parametrize("pool,query,operands", [
+    ("bf16", "bf16", "bfloat16"),
+    ("f32", "bf16", "float32"),
+    ("bf16", "f32", "float32"),
+    ("int8", "bf16", "float32"),
+    ("fp8", "bf16", "float32"),
+])
+def test_kernel_matmul_operands_follow_pool(kernel, pool, query, operands):
+    """Inside the pallas_call, every QK and PV matmul takes bf16 operands
+    for bf16 queries against a bf16 pool, and float32 operands for any
+    float32 input and for quantized pools (their dequant scales are
+    float32): a quantized pool never moves onto bf16 operands."""
+    from repro.models.attention import quantize_kv
+    b, tq, hq, hkv, d, bs, mbs = 2, 9, 12, 2, 128, 64, 3
+    dt = {"f32": jnp.float32, "bf16": jnp.bfloat16}
+    k_pages, v_pages, tables, _, _ = paged_setup(b, hkv, d, bs, mbs, key=80)
+    kw = {}
+    if pool in ("int8", "fp8"):
+        qd = jnp.int8 if pool == "int8" else jnp.float8_e4m3fn
+        k_pages, kw["k_scale"] = quantize_kv(k_pages, qd)
+        v_pages, kw["v_scale"] = quantize_kv(v_pages, qd)
+    else:
+        k_pages, v_pages = (x.astype(dt[pool]) for x in (k_pages, v_pages))
+    q = rand(b, tq, hq, d, k=81, dtype=dt[query])
+    kv_len = jnp.asarray([bs * mbs, tq], jnp.int32)
+    q_pos = (kv_len - tq)[:, None] + jnp.arange(tq)[None, :]
+    jaxpr = jax.make_jaxpr(functools.partial(_paged_call, kernel, **kw))(
+        q, k_pages, v_pages, tables, kv_len, q_pos).jaxpr
+    kernels = [e.params["jaxpr"] for e in jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 1
+    dots = _dot_operand_dtypes(kernels[0])
+    assert dots and set(dots) == {(operands, operands)}, dots
